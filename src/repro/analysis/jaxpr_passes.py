@@ -115,8 +115,11 @@ register(Pass(
 
 # primitives that punch through to the host from inside a jitted body —
 # any of these inside a phase chain serializes the dispatch pipeline
+# (``jax.debug.print`` lowers to ``debug_print``, ``jax.debug.callback`` to
+# ``debug_callback``)
 _HOST_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "outside_call", "host_callback_call", "infeed", "outfeed",
 })
 

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import bmf as BMF
 from repro.core import gibbs as GIBBS
@@ -169,8 +168,8 @@ def make_distributed_sweep(mesh: Mesh, cfg: BMF.BMFConfig, N: int, D: int,
                 P(None, None) if has_v_prior else P(None),
                 P(None, None, None) if has_v_prior else P(None))
     out_specs = (P(), P("data", None), P(None, None))
-    return shard_map(sweep, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(sweep, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _sample_nw_from_moments(key, s1, s2, n, nw: NormalWishart):
@@ -309,6 +308,11 @@ def run_gibbs_distributed(key, csr_rows: PaddedCSR, csr_cols: PaddedCSR,
 
     U, V = U0, V0
     predict_j = jax.jit(BMF.predict)
+    # the kept-sample consumers (the test-entry gather, the accumulators
+    # and their summaries) read a replicated copy of U: on an explicit-axis
+    # mesh a 'data'-sharded operand leaves the gather's output sharding
+    # ambiguous and the summaries' batched solves mismatched
+    replicated = NamedSharding(mesh, P())
     for it in range(cfg.n_samples):
         key, U, V = sweep(
             key, U, V, csr.idx, csr.val, csr.mask,
@@ -318,12 +322,13 @@ def run_gibbs_distributed(key, csr_rows: PaddedCSR, csr_cols: PaddedCSR,
             V_prior.eta if has_v else dummy_eta,
             V_prior.Lambda if has_v else dummy_eta)
         if it >= cfg.burnin:
-            pred = predict_j(U, V, test_rows, test_cols)
+            U_rep = jax.device_put(U, replicated)
+            pred = predict_j(U_rep, V, test_rows, test_cols)
             acc = GIBBS.GibbsAccumulators(
                 pred_sum=acc.pred_sum + pred,
                 pred_cnt=acc.pred_cnt + 1.0,
-                U_sum=acc.U_sum + U,
-                U_outer=acc.U_outer + jnp.einsum("nk,nl->nkl", U, U),
+                U_sum=acc.U_sum + U_rep,
+                U_outer=acc.U_outer + jnp.einsum("nk,nl->nkl", U_rep, U_rep),
                 V_sum=acc.V_sum + V,
                 V_outer=acc.V_outer + jnp.einsum("dk,dl->dkl", V, V))
 
@@ -519,9 +524,6 @@ def _run_gibbs_2d_dispatch(key_data, csr_rows_arrs, csr_cols_arrs,
     runs on the 'data' axis; nothing ever reduces over 'block'
     (``bmf_dryrun --pp-engine`` asserts that from the compiled HLO).
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     n_shards = mesh.shape[DATA_AXIS]
     N, D = n_rows, n_cols
     N_pad = csr_rows_arrs[0].shape[1]
@@ -550,8 +552,8 @@ def _run_gibbs_2d_dispatch(key_data, csr_rows_arrs, csr_cols_arrs,
     blk, blkdata = P(BLOCK_AXIS), P(BLOCK_AXIS, DATA_AXIS)
     in_specs = (blk, blkdata, blk, blkdata, blk, blk, P(), P(),
                 blk, blk, blk, blk, blk, blk)
-    fsh = shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                    out_specs=blk, check_rep=False)
+    fsh = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                        out_specs=blk, check_vma=False)
     return fsh(key_data, csr_rows_arrs, csr_cols_arrs, csrt_arrs,
                test_rows, test_cols, n_samples, burnin,
                U_prior, V_prior, U0, V0, u_use, v_use)

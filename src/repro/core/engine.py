@@ -147,11 +147,7 @@ class _InjectedDispatchFailure(RuntimeError):
 # dispatch-time failures the engine treats as block faults rather than
 # bugs: the injected seam plus JAX's runtime-side errors (OOM, dead
 # device). Anything else propagates — a TypeError is a bug, not a fault.
-try:
-    _DISPATCH_ERRORS: tuple = (_InjectedDispatchFailure,
-                               jax.errors.JaxRuntimeError)
-except AttributeError:  # pragma: no cover - older jax without JaxRuntimeError
-    _DISPATCH_ERRORS = (_InjectedDispatchFailure,)
+_DISPATCH_ERRORS = (_InjectedDispatchFailure, jax.errors.JaxRuntimeError)
 
 
 @dataclass(frozen=True)

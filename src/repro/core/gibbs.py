@@ -200,13 +200,12 @@ def _run_gibbs_stacked_dispatch(key_data, csr_rows_arrs, csr_cols_arrs,
         return batched(key_data, csr_rows_arrs, csr_cols_arrs, test_rows,
                        test_cols, n_samples, burnin, U_prior, V_prior, U0, V0,
                        u_use, v_use)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     blk = P("block")
-    fsh = shard_map(batched, mesh=mesh,
-                    in_specs=(blk, blk, blk, blk, blk, P(), P(),
-                              blk, blk, blk, blk, blk, blk),
-                    out_specs=blk, check_rep=False)
+    fsh = jax.shard_map(batched, mesh=mesh,
+                        in_specs=(blk, blk, blk, blk, blk, P(), P(),
+                                  blk, blk, blk, blk, blk, blk),
+                        out_specs=blk, check_vma=False)
     return fsh(key_data, csr_rows_arrs, csr_cols_arrs, test_rows, test_cols,
                n_samples, burnin, U_prior, V_prior, U0, V0, u_use, v_use)
 

@@ -91,10 +91,12 @@ def _fused_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, other_ref,
         # batched (K, TM) x (TM, K) matmuls on the MXU
         lam_ref[...] += tau * jax.lax.dot_general(
             vm, v, (((1,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-        # fused η accumulation — same pass, same gathered rows
-        eta_ref[...] += tau * jnp.einsum(
-            "nm,nmk->nk", r * w, v, preferred_element_type=jnp.float32)
+        # fused η accumulation — same pass, same gathered rows; a masked
+        # sum, since Mosaic rejects the batched mat-vec dot (no LHS
+        # non-contracting dim)
+        eta_ref[...] += tau * jnp.sum((r * w)[..., None] * v, axis=1)
 
 
 def precision_accum_fused_padded(idx, ntiles, val, mask, other, tau: float, *,
@@ -119,7 +121,7 @@ def precision_accum_fused_padded(idx, ntiles, val, mask, other, tau: float, *,
         in_specs=[
             pl.BlockSpec((TN, tm), live_block),     # val
             pl.BlockSpec((TN, tm), live_block),     # mask
-            pl.BlockSpec(memory_space=pltpu.ANY),   # other: stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),      # other: stays in HBM
         ],
         out_specs=[
             pl.BlockSpec((TN, K, K), lambda n, m, *_: (n, 0, 0)),

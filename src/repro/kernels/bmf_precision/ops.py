@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.kernels.bmf_precision.kernel import (
     LANES, TM, TN, precision_accum_fused_padded)
 from repro.kernels.bmf_precision.ref import precision_accum_ref
+from repro.kernels.route import check_lane_width, pallas_route
 from repro.data.sparse import tile_occupancy
 
 
@@ -58,7 +59,7 @@ def _pad_to(x, n, axis):
 def precision_accum(idx, val, mask, other, tau: float):
     """idx/val/mask: padded CSR (N, M); other: (D, K) factor matrix.
     Returns (Lam (N, K, K), eta (N, K)) likelihood contributions."""
-    if _on_tpu():
+    if pallas_route("precision", other.shape[-1]):
         return precision_accum_fused(idx, val, mask, other, tau,
                                      interpret=False)
     return precision_accum_chunked(idx, val, mask, other, tau)
@@ -82,6 +83,8 @@ def precision_accum_fused(idx, val, mask, other, tau: float, *,
         interpret = not _on_tpu()
     N, M = idx.shape
     D, K = other.shape
+    if not interpret:
+        check_lane_width(K)
     Kp = ((K + LANES - 1) // LANES) * LANES
     Mp = ((M + tm - 1) // tm) * tm
     ns = max(TN, (smem_idx_budget // (Mp * 4)) // TN * TN)
@@ -138,9 +141,12 @@ def _sym_tile(ix, vl, mk, other, tau):
     operands (the two-operand ``einsum(Vm, V)`` form makes XLA keep a
     second gathered buffer live and is measurably slower)."""
     Vm = other[ix] * mk[..., None]
+    # full f32 on TPU too, where this path is the kernel's on-chip check
+    hi = jax.lax.Precision.HIGHEST
     lam = tau * jax.lax.dot_general(Vm, Vm, (((1,), (1,)), ((0,), (0,))),
+                                    precision=hi,
                                     preferred_element_type=jnp.float32)
-    eta = tau * jnp.einsum("nm,nmk->nk", vl, Vm,
+    eta = tau * jnp.einsum("nm,nmk->nk", vl, Vm, precision=hi,
                            preferred_element_type=jnp.float32)
     return lam, eta
 

@@ -28,11 +28,11 @@ draw ``posterior.sample_rows`` makes — so the chain's random stream is
 bitwise-preserved no matter which path (kernel / fallback / legacy
 unfused) executes the sweep.
 
-Mixed precision: the gather scratch and the Λ accumulate run in the
-factor's dtype (bf16 in mixed mode) with f32 MXU accumulation
-(``preferred_element_type``); the Λ/η scratches, priors, Cholesky, and
-solves are f32 ALWAYS — bf16 never reaches the factorization (the
-bmf_lint dtype pass proves this over the lowered jaxpr).
+Mixed precision: the Λ accumulate runs in bf16 in mixed mode with f32
+MXU accumulation (``preferred_element_type``); the gather scratch, the
+Λ/η scratches, priors, Cholesky, and solves are f32 ALWAYS — bf16 never
+reaches the factorization (the bmf_lint dtype pass proves this over the
+lowered jaxpr).
 
 Bitwise parity with the off-TPU fallback is BY CONSTRUCTION: ref.py runs
 ``accum_tile``/``sample_tile`` — the same helpers below — over the same
@@ -62,19 +62,34 @@ __all__ = ["accum_tile", "sample_tile", "chol_tile", "solve_lower_tile",
 # batched into tiles — the property the bitwise parity tests rely on.
 # ---------------------------------------------------------------------------
 
+# Matmuls here are pinned to full f32: XLA's default TPU precision runs an
+# f32 dot as one bf16 pass, which would make the striped-XLA path on TPU
+# (K > SWEEP_K_MAX, and the on-chip parity check) a different sampler from
+# the kernel's.  No effect on the CPU backend.
+F32_DOT = jax.lax.Precision.HIGHEST
 
-def accum_tile(lam, eta, v, w, r, tau):
+
+def accum_tile(lam, eta, v, w, r, tau, dtype=jnp.float32):
     """Fold one M-tile of gathered factor rows into the (Λ, η) accumulators.
 
-    lam (B, K, K) f32, eta (B, K) f32; v (B, tm, K) in the gather dtype
-    (f32 or bf16); w/r (B, tm) f32 mask/value planes.  The Λ matmul runs on
-    the gather dtype with f32 accumulation — the mixed-precision contract."""
-    vm = v * w.astype(v.dtype)[..., None]
+    lam (B, K, K) f32, eta (B, K) f32; v (B, tm, K) f32 gathered rows;
+    w/r (B, tm) f32 mask/value planes.  The Λ matmul runs on ``dtype``
+    (f32, or bf16 in mixed mode) with f32 accumulation — the
+    mixed-precision contract; η stays f32.
+
+    Mosaic constraints shape three lines: rows are gathered in f32 and
+    cast here, because a one-row DMA of a packed bf16 tile does not lower;
+    the mask is broadcast in f32 and the product cast back (w is 0/1, so
+    exact), because a bf16 (B, tm) -> (B, tm, 1) reshape does not lower;
+    and η is a masked sum over the slot axis, because the batched mat-vec
+    einsum has no LHS non-contracting dim, which Mosaic's dot rejects."""
+    vc = v.astype(dtype)
+    vm = (vc * w[..., None]).astype(dtype)
     lam = lam + tau * jax.lax.dot_general(
-        vm, v, (((1,), (1,)), ((0,), (0,))),
+        vm, vc, (((1,), (1,)), ((0,), (0,))),
+        precision=F32_DOT if vc.dtype == jnp.float32 else None,
         preferred_element_type=jnp.float32)
-    eta = eta + tau * jnp.einsum(
-        "nm,nmk->nk", r * w, v, preferred_element_type=jnp.float32)
+    eta = eta + tau * jnp.sum((r * w)[..., None] * v, axis=1)
     return lam, eta
 
 
@@ -103,7 +118,8 @@ def chol_tile(A):
         l_row = jnp.sum(L * rowsel[None], axis=1)       # (B, K) = L[:, j, :]
         # s_i = Σ_p L[i, p] · L[j, p]; at i = j this is Σ L[j, p]²
         s = jax.lax.dot_general(L, l_row,
-                                (((2,), (1,)), ((0,), (0,))))
+                                (((2,), (1,)), ((0,), (0,))),
+                                precision=F32_DOT)
         a_jj = jnp.sum(a_col * (lane == j).astype(A.dtype), axis=1)
         sq = jnp.sum(l_row * l_row, axis=1)
         ljj = jnp.sqrt(a_jj - sq)                       # (B,)
@@ -177,7 +193,7 @@ def sample_tile(lam, eta, prior_lam, prior_eta, z, jitter):
 
 def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
                   z_ref, other_ref, u_ref, lam_ref, eta_ref, vg_ref, sem, *,
-                  tau: float, tm: int, jitter: float):
+                  tau: float, tm: int, jitter: float, dtype):
     n = pl.program_id(0)
     m = pl.program_id(1)
 
@@ -214,9 +230,9 @@ def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
 
         jax.lax.fori_loop(0, G, pump, None)
 
-        v = vg_ref[...].reshape(TN, tm, -1)             # gather dtype
+        v = vg_ref[...].reshape(TN, tm, -1)
         lam, eta = accum_tile(lam_ref[...], eta_ref[...], v,
-                              mask_ref[...], val_ref[...], tau)
+                              mask_ref[...], val_ref[...], tau, dtype)
         lam_ref[...] = lam
         eta_ref[...] = eta
 
@@ -231,10 +247,12 @@ def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
 
 def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
                        other, tau: float, *, tm: int = TM,
-                       jitter: float = 1e-6, interpret: bool = False):
+                       jitter: float = 1e-6, dtype=jnp.float32,
+                       interpret: bool = False):
     """idx/val/mask: (N, M) with N % TN == 0, M % tm == 0; ntiles: (N/TN,)
     live-M-tile counts; prior_eta/z: (N, K), prior_lam: (N, K, K) f32 with
-    pad lanes carrying an identity diagonal; other: (D, K), HBM-resident.
+    pad lanes carrying an identity diagonal; other: (D, K) f32,
+    HBM-resident; dtype: the Λ matmul operand dtype (``accum_tile``).
     Returns the sampled factor U (N, K) — no (N, K, K) HBM intermediate."""
     N, M = idx.shape
     D, K = other.shape
@@ -258,7 +276,7 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
             pl.BlockSpec((TN, K), row_block),               # prior eta
             pl.BlockSpec((TN, K, K), lambda n, m, *_: (n, 0, 0)),
             pl.BlockSpec((TN, K), row_block),               # noise z
-            pl.BlockSpec(memory_space=pltpu.ANY),           # other: HBM
+            pl.BlockSpec(memory_space=pl.ANY),              # other: HBM
         ],
         out_specs=pl.BlockSpec((TN, K), row_block),
         scratch_shapes=[
@@ -268,7 +286,8 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
             pltpu.SemaphoreType.DMA,
         ],
     )
-    kernel = functools.partial(_sweep_kernel, tau=tau, tm=tm, jitter=jitter)
+    kernel = functools.partial(_sweep_kernel, tau=tau, tm=tm, jitter=jitter,
+                               dtype=dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

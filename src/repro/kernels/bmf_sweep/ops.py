@@ -4,9 +4,10 @@
 ``core.gibbs`` when ``BMFConfig.sweep_fused`` is set: it pads the CSR
 planes / priors / noise to tile shapes and routes to
 
-  - the Pallas kernel (kernel.py) on TPU for K ≤ ``SWEEP_K_MAX`` — the
-    in-register Cholesky is a column loop, so beyond small K its O(K²)
-    masked-lane overhead stops paying for the saved HBM round-trips;
+  - the Pallas kernel (kernel.py) where ``route.pallas_route('sweep', K)``
+    holds: on TPU for K ≤ ``SWEEP_K_MAX`` — the in-register Cholesky is a
+    column loop, so beyond small K its O(K²) masked-lane overhead stops
+    paying for the saved HBM round-trips;
   - the striped-XLA fallback (ref.py) everywhere else — same tile math,
     same padded operands, same M-tile order (bitwise-identical in the
     single-stripe regime; a few ulps once XLA fuses the striped body —
@@ -25,6 +26,7 @@ random stream.
 """
 from __future__ import annotations
 
+import warnings
 from functools import partial
 
 import jax
@@ -35,14 +37,9 @@ from repro.kernels.bmf_precision.ops import SMEM_IDX_BUDGET, _on_tpu, _pad_to
 from repro.kernels.bmf_sweep.kernel import (
     LANES, TM, TN, fused_sweep_padded)
 from repro.kernels.bmf_sweep.ref import sweep_ref_padded
+from repro.kernels.route import check_lane_width, pallas_route
 
 SWEEP_DTYPES = ("fp32", "bf16")
-
-# Pallas cutoff: the masked-lane Cholesky/solve epilogue is O(K²) vector
-# ops per column on top of the O(K³) MXU work — fine for the paper's
-# K ≤ 32 regime, wasteful beyond it (and (TN, K, K) solver temporaries
-# start crowding VMEM once K pads to multiple LANES widths)
-SWEEP_K_MAX = 32
 
 # host-side lane padding granularity (f32 sublane count); TPU uses LANES
 HOST_LANES = 8
@@ -68,9 +65,9 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
     params (N, K)/(N, K, K), the caller's noise draw z (N, K), and the
     other factor (D, K).
 
-    dtype: 'fp32', or 'bf16' for the mixed-precision mode (bf16 gather +
-    Λ accumulate with f32 MXU accumulation; priors, Cholesky, and solves
-    stay f32).  force: 'pallas' / 'ref' pins the path, and n_stripe pins
+    dtype: 'fp32', or 'bf16' for the mixed-precision mode (bf16 Λ
+    accumulate with f32 MXU accumulation; the gather, η, priors, Cholesky,
+    and solves stay f32).  force: 'pallas' / 'ref' pins the path, and n_stripe pins
     the N-stripe width, for the parity tests (a stripe covering all of N
     keeps both paths in the single-dispatch regime where agreement is
     bitwise, not just ulp-level — see ref.py)."""
@@ -80,9 +77,15 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
     N, M = idx.shape
     K = other.shape[-1]
     use_pallas = force == "pallas" or (
-        force is None and _on_tpu() and K <= SWEEP_K_MAX)
+        force is None and pallas_route("sweep", K))
     if interpret is None:
         interpret = not _on_tpu()
+    if use_pallas and not interpret:
+        check_lane_width(K)
+    if force is None and not use_pallas and _on_tpu():
+        warnings.warn(f"fused_sweep: K={K} takes the striped-XLA path on "
+                      f"TPU (route.pallas_route('sweep', K) is False)",
+                      stacklevel=2)
     tm_eff = tm or min(TM, _ceil_to(max(M, 1), LANES))
     lanes = LANES if _on_tpu() else HOST_LANES
     Kp = _ceil_to(K, lanes)
@@ -110,20 +113,21 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
         pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
         pL = pL + jnp.diag(pad_diag)[None]
     zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
-    otherp = _pad_to(other, Kp, 1)
-    if dtype == "bf16":
-        otherp = otherp.astype(jnp.bfloat16)
+    otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
+    mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
 
     if not use_pallas:
         U = sweep_ref_padded(idxp, valp, maskp, pe, pL, zp, otherp, tau,
-                             tm=tm_eff, jitter=jitter, n_stripe=ns)
+                             tm=tm_eff, jitter=jitter, dtype=mm_dtype,
+                             n_stripe=ns)
         return U[:N, :K]
 
     def stripe(args):
         ix, vl, mk, pe1, pL1, zz = args
         return fused_sweep_padded(
             ix, tile_occupancy(mk, TN, tm_eff), vl, mk, pe1, pL1, zz,
-            otherp, tau, tm=tm_eff, jitter=jitter, interpret=interpret)
+            otherp, tau, tm=tm_eff, jitter=jitter, dtype=mm_dtype,
+            interpret=interpret)
 
     if Np == ns:
         U = stripe((idxp, valp, maskp, pe, pL, zp))

@@ -31,7 +31,7 @@ from repro.kernels.bmf_sweep.kernel import accum_tile, sample_tile
 
 def sweep_ref_padded(idx, val, mask, prior_eta, prior_lam, z, other,
                      tau: float, *, tm: int, jitter: float = 1e-6,
-                     n_stripe: int):
+                     dtype=jnp.float32, n_stripe: int):
     """Same contract as ``kernel.fused_sweep_padded`` (minus the occupancy
     counts — all tiles are processed; dead ones add exact zeros).  N must
     be a multiple of ``n_stripe``; M a multiple of ``tm``."""
@@ -48,7 +48,7 @@ def sweep_ref_padded(idx, val, mask, prior_eta, prior_lam, z, other,
         for lo in range(0, M, tm):
             v = other[ix[:, lo:lo + tm]]                # (ns, tm, K) gather
             lam, eta = accum_tile(lam, eta, v, mk[:, lo:lo + tm],
-                                  vl[:, lo:lo + tm], tau)
+                                  vl[:, lo:lo + tm], tau, dtype)
         # (no optimization_barrier between the phases even though the
         # kernel has a hard VMEM-scratch boundary there: the stacked
         # executors vmap this whole chain and the barrier primitive has

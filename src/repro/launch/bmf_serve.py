@@ -29,22 +29,25 @@ from repro.core import pp as PP
 from repro.core.partition import partition, suggest_grid
 from repro.data import synthetic as SYN
 from repro.data.sparse import train_test_split
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import MicroBatchRouter, PosteriorStore, Request
 from repro.serving.scoring import MODES
 
 
 def build_requests(train, n_requests: int, max_seen: int, seed: int):
-    """One request per (cycled) user: mask the user's training items
-    (truncated to the router's seen cap)."""
-    by_user = {}
-    for r, c in zip(train.row, train.col):
-        by_user.setdefault(int(r), []).append(int(c))
-    users = sorted(by_user)
+    """One request per (cycled) user: mask the user's training items, in
+    training order (truncated to the router's seen cap)."""
+    order = np.argsort(train.row, kind="stable")
+    rows, cols = train.row[order], train.col[order]
+    users, starts = np.unique(rows, return_index=True)
+    ends = np.append(starts[1:], len(rows))
     rng = np.random.default_rng(seed)
     out = []
-    for i in range(n_requests):
-        u = users[int(rng.integers(len(users)))]
-        out.append(Request(user_id=u, seen=by_user[u][:max_seen]))
+    for _ in range(n_requests):
+        i = int(rng.integers(len(users)))
+        seen = cols[starts[i]:min(ends[i], starts[i] + max_seen)]
+        out.append(Request(user_id=int(users[i]),
+                           seen=[int(c) for c in seen]))
     return out
 
 
@@ -89,6 +92,7 @@ def main():
                     help="verify served top-K against a dense numpy "
                          "brute-force ranking (mean mode)")
     args = ap.parse_args()
+    use_compile_cache()
 
     coo, p = SYN.generate(args.dataset, seed=args.seed)
     train, test = train_test_split(coo, 0.1, seed=args.seed + 1)

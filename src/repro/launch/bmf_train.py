@@ -46,6 +46,7 @@ from repro.core import pp as PP
 from repro.core.partition import nnz_balance_stats, partition, suggest_grid
 from repro.data import synthetic as SYN
 from repro.data.sparse import train_test_split
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -104,6 +105,7 @@ def main():
     if args.resume and not args.ckpt_dir:
         raise SystemExit("--resume needs --ckpt-dir (the directory the "
                          "interrupted run checkpointed into)")
+    use_compile_cache()
 
     coo, p = SYN.generate(args.dataset, seed=args.seed)
     train, test = train_test_split(coo, 0.1, seed=args.seed + 1)

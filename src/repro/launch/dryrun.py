@@ -43,16 +43,6 @@ def _named(mesh, spec_tree):
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def _mesh_ctx(mesh):
-    """jax.set_mesh appeared after 0.4.x; shardings here are explicit
-    NamedShardings, so on older jax no ambient mesh is needed."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    import contextlib
-    return contextlib.nullcontext()
-
-
 def lower_one(arch_id: str, shape_name: str, multi_pod: bool,
               tcfg=None, verbose=True, extra_tags=None):
     cfg = get_config(arch_id)
@@ -72,7 +62,7 @@ def lower_one(arch_id: str, shape_name: str, multi_pod: bool,
     p_sh = _named(mesh, PART.param_specs(params_s, cfg, mesh))
     win = STEPS.long_context_window(cfg, shape)
 
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch_s = STEPS.batch_specs(cfg, shape)
             opt_s = STEPS.opt_specs(cfg)

@@ -164,7 +164,7 @@ def iter_eqns(jaxpr):
     hiding in eqn params (scan/while bodies, cond branches, pjit
     sub-jaxprs, pallas kernel jaxprs) — the traversal the dtype and
     host-callback lint passes run on."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr
@@ -180,7 +180,7 @@ def iter_avals(jaxpr):
     """Yield every aval appearing anywhere in a (closed) jaxpr — eqn
     in/outvars plus all sub-jaxprs hiding in eqn params (scan bodies,
     pallas kernel jaxprs, cond branches, ...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr

@@ -49,6 +49,11 @@ from repro.serving.store import PosteriorStore, _posterior_mean
 
 MODES = ("mean", "thompson")
 
+# Scores and the fold-in are full f32 products: XLA's default TPU precision
+# runs an f32 dot as one bf16 pass (~1e-3 relative error), which reorders
+# near-tied items and breaks the exact mean-mode ranking. No effect on CPU.
+F32_DOT = jax.lax.Precision.HIGHEST
+
 
 class RequestBatch(NamedTuple):
     """One fixed-shape scoring batch. Pad rows with user_id = -1 and
@@ -72,8 +77,10 @@ def _fold_in(g: RowGaussians, batch: RequestBatch, V_mean, tau):
     """Conjugate per-request conditional update against fixed item means."""
     v = V_mean[batch.fold_idx]                               # (B, F, K)
     m = batch.fold_mask
-    Lam = g.Lambda + tau * jnp.einsum("bf,bfk,bfl->bkl", m, v, v)
-    eta = g.eta + tau * jnp.einsum("bf,bf,bfk->bk", m, batch.fold_val, v)
+    Lam = g.Lambda + tau * jnp.einsum("bf,bfk,bfl->bkl", m, v, v,
+                                      precision=F32_DOT)
+    eta = g.eta + tau * jnp.einsum("bf,bf,bfk->bk", m, batch.fold_val, v,
+                                   precision=F32_DOT)
     return RowGaussians(eta=eta, Lambda=Lam)
 
 
@@ -95,7 +102,8 @@ def score_topk(store: PosteriorStore, batch: RequestBatch, k: int,
 
     if mode == "mean":
         mu = _posterior_mean(g, jitter)                      # (B, K)
-        scores = mu @ store.V_mean.T                         # (B, M)
+        scores = jnp.matmul(mu, store.V_mean.T,
+                            precision=F32_DOT)               # (B, M)
     else:
         keys = jax.random.wrap_key_data(batch.key_data)      # (B,) keys
         kz = jax.vmap(jax.random.fold_in, (0, None))(keys, 0)
@@ -104,7 +112,8 @@ def score_topk(store: PosteriorStore, batch: RequestBatch, k: int,
         u = POST.sample_rows_noise(g, z, jitter=jitter)      # (B, K)
         slot = jax.vmap(lambda kk: jax.random.randint(
             kk, (), 0, store.n_slots))(ks)                   # (B,)
-        scores = jnp.einsum("bk,bmk->bm", u, store.V_samples[slot])
+        scores = jnp.einsum("bk,bmk->bm", u, store.V_samples[slot],
+                            precision=F32_DOT)
 
     # seen masking: padded slots redirect to out-of-bounds column M, which
     # scatter mode="drop" discards — no (B, M) one-hot intermediate

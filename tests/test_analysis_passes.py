@@ -132,7 +132,7 @@ def test_materialization_skipped_without_budget():
 
 
 def test_dtype_promotion_fires_on_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.jit(lambda x: x * np.float64(2.0)).trace(
             S((4,), jnp.float64)).jaxpr
     art = REG.JaxprArtifact(label="x64", jaxpr=jx)
@@ -162,7 +162,7 @@ def test_host_callback_fires_inside_jit():
     jx = jax.jit(f).trace(S((4,), f32)).jaxpr
     vs = violations_of(REG.JaxprArtifact(label="cb", jaxpr=jx),
                        "host-callback")
-    assert vs and "debug_callback" in vs[0].message
+    assert vs and "debug_print" in vs[0].message
     jx_clean = jax.jit(lambda x: x * 2).trace(S((4,), f32)).jaxpr
     assert not violations_of(REG.JaxprArtifact(label="ok", jaxpr=jx_clean),
                              "host-callback")

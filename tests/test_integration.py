@@ -37,9 +37,7 @@ MINI_DRYRUN = textwrap.dedent("""
     b_sh = named(PART.batch_specs(batch_s, cfg, shape, mesh))
     o_sh = named(PART.opt_specs(opt_s, params_s, cfg, mesh))
     step = STEPS.make_train_step(cfg, TrainConfig(microbatches=2))
-    import contextlib
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+    with jax.set_mesh(mesh):
         tr = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                      out_shardings=(p_sh, o_sh, None),
                      donate_argnums=(0, 1)).trace(params_s, opt_s, batch_s)

@@ -282,3 +282,41 @@ def test_dtype_pass_catches_bf16_sqrt():
     art = JaxprArtifact(label="planted", jaxpr=bad)
     vs = get_pass("dtype-promotion").run(art)
     assert any("sqrt" in v.message for v in vs), vs
+
+
+# ---------------------------------------------------------------------------
+# route predicate and the TPU lane-width limit
+# ---------------------------------------------------------------------------
+
+
+def test_pallas_route_predicate():
+    """One predicate of (platform, K) decides both kernels' routes: XLA off
+    TPU; on TPU the precision kernel always, the sweep up to SWEEP_K_MAX."""
+    from repro.kernels.route import SWEEP_K_MAX, pallas_route
+    for kernel in ("precision", "sweep"):
+        assert not pallas_route(kernel, 10, platform="cpu")
+        assert pallas_route(kernel, 10, platform="tpu")
+    assert pallas_route("precision", 100, platform="tpu")
+    assert pallas_route("sweep", SWEEP_K_MAX, platform="tpu")
+    assert not pallas_route("sweep", SWEEP_K_MAX + 1, platform="tpu")
+    assert pallas_route("sweep", 10) == (jax.default_backend() == "tpu")
+    with pytest.raises(ValueError, match="kernel must be one of"):
+        pallas_route("gather", 10, platform="tpu")
+
+
+def test_compiled_kernels_reject_k_beyond_one_lane_tile():
+    """K > 128 pads past one lane tile, whose one-row DMA Mosaic cannot
+    lower: the compiled (non-interpret) route refuses it up front, naming
+    the limit, while interpret mode still runs it."""
+    from repro.kernels.bmf_precision import ops as PREC
+    rng = np.random.default_rng(7)
+    idx, val, mask, pe, pL, z, other = _case(rng, 8, 16, 11, 130)
+    with pytest.raises(ValueError, match="K <= 128"):
+        SWEEP.fused_sweep(z, idx, val, mask, pe, pL, other, 2.0,
+                          force="pallas", interpret=False)
+    with pytest.raises(ValueError, match="K <= 128"):
+        PREC.precision_accum_fused(idx, val, mask, other, 2.0,
+                                   interpret=False)
+    U = SWEEP.fused_sweep(z, idx, val, mask, pe, pL, other, 2.0,
+                          force="pallas", interpret=True, n_stripe=8)
+    assert U.shape == (8, 130) and bool(jnp.all(jnp.isfinite(U)))

@@ -1,0 +1,83 @@
+"""Ahead-of-time compiles of the BMF Pallas kernels for a described TPU v5e.
+
+Interpret-mode parity cannot see what Mosaic refuses (unaligned slices,
+dot forms it cannot lower, VMEM limits), so both kernels are compiled here
+for a v5e chip that is described, not attached, at the stripe shapes the
+ops wrappers produce for the full-size MovieLens blocks: (8, 6656) is an
+item-side stripe of phase a on an 8x8 grid, (24, 2304) a user-side one,
+(8, 13312) the item side of phase a on the 4x4 grid ``chip_smoke.py``
+runs (the widest index plane), plus (256, 256).  K pads to 128 lanes.
+
+The topology is described inside a fixture (never at import), so every
+pytest-xdist worker collects the same tests and only the worker that runs
+this file loads the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bmf_precision.kernel import (
+    LANES, TN, precision_accum_fused_padded)
+from repro.kernels.bmf_sweep.kernel import fused_sweep_padded
+
+STRIPES = [(8, 6656), (24, 2304), (8, 13312), (256, 256)]
+D = 3410          # item rows of a full-size 8x8 block (the gathered factor)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache off meanwhile
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("N,M", STRIPES)
+def test_precision_kernel_compiles_for_v5e(one_chip, N, M):
+    S = lambda shape, dt: _sds(one_chip, shape, dt)
+    f = jax.jit(lambda ix, nt, vl, mk, ot: precision_accum_fused_padded(
+        ix, nt, vl, mk, ot, 2.0))
+    compiled = f.lower(S((N, M), jnp.int32), S((N // TN,), jnp.int32),
+                       S((N, M), jnp.float32), S((N, M), jnp.float32),
+                       S((D, LANES), jnp.float32)).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("N,M", STRIPES)
+def test_sweep_kernel_compiles_for_v5e(one_chip, N, M, dtype):
+    S = lambda shape, dt: _sds(one_chip, shape, dt)
+    f = jax.jit(lambda ix, nt, vl, mk, pe, pL, z, ot: fused_sweep_padded(
+        ix, nt, vl, mk, pe, pL, z, ot, 2.0, dtype=dtype))
+    compiled = f.lower(S((N, M), jnp.int32), S((N // TN,), jnp.int32),
+                       S((N, M), jnp.float32), S((N, M), jnp.float32),
+                       S((N, LANES), jnp.float32),
+                       S((N, LANES, LANES), jnp.float32),
+                       S((N, LANES), jnp.float32),
+                       S((D, LANES), jnp.float32)).compile()
+    _assert_kernel(compiled)
