@@ -58,6 +58,7 @@ from repro.kernels.route import pallas_route  # noqa: E402
 from repro.launch.bmf_serve import build_requests, check_parity  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.serving import MicroBatchRouter, PosteriorStore  # noqa: E402
+from bench.compile_stats import CompileStats  # noqa: E402
 
 # paper Table 1, MovieLens-20M, at full size
 MOVIELENS_20M = SYN.DatasetPreset("movielens-20m", n_rows=138_493,
@@ -85,30 +86,6 @@ FOUR_CHIP_TOL = 1e-4
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-class CompileStats:
-    """Backend compile time and persistent-cache hits, from JAX's own
-    monitoring events (a cache hit still records the compile event, with
-    the time it took to load the executable)."""
-
-    def __init__(self):
-        self.seconds, self.compiles, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-            self.compiles += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def __str__(self) -> str:
-        return (f"{self.compiles} backend compile(s) in {self.seconds:.1f}s, "
-                f"{self.cache_hits} persistent-cache hit(s)")
 
 
 def require_tpu(n_devices: int):
@@ -286,7 +263,9 @@ def main() -> None:
     compiles = CompileStats()
     t0 = time.time()
     (four_chips if args.four_chips else one_chip)(args)
-    log(f"compile: {compiles}")
+    secs, n, hits = compiles.snapshot()
+    log(f"compile: {n} backend compile(s) in {secs:.1f}s, {hits} "
+        f"persistent-cache hit(s)")
     log(f"chip_smoke: all phases passed in {time.time() - t0:.1f}s")
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,

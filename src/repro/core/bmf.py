@@ -55,15 +55,19 @@ def sufficient_stats(csr: PaddedCSR, other: jnp.ndarray, tau: float,
     hot path (repro/kernels/bmf_precision): the fused-gather Pallas kernel
     on TPU, an N-striped symmetric matmul elsewhere — neither builds the
     (N, M, K) gathered tensor the jnp path below materializes.
+
+    Runs under the ``bmf_stats`` named scope (see ``gibbs._run_gibbs_impl``).
     """
-    if use_kernel:
-        from repro.kernels.bmf_precision import ops as KOPS
-        return KOPS.precision_accum(csr.idx, csr.val, csr.mask, other, tau)
-    V = other[csr.idx]                                  # (N, M, K)
-    Vm = V * csr.mask[..., None]
-    Lam = tau * jnp.einsum("nmk,nml->nkl", Vm, V)
-    eta = tau * jnp.einsum("nm,nmk->nk", csr.val * csr.mask, V)
-    return Lam, eta
+    with jax.named_scope("bmf_stats"):
+        if use_kernel:
+            from repro.kernels.bmf_precision import ops as KOPS
+            return KOPS.precision_accum(csr.idx, csr.val, csr.mask, other,
+                                        tau)
+        V = other[csr.idx]                                  # (N, M, K)
+        Vm = V * csr.mask[..., None]
+        Lam = tau * jnp.einsum("nmk,nml->nkl", Vm, V)
+        eta = tau * jnp.einsum("nm,nmk->nk", csr.val * csr.mask, V)
+        return Lam, eta
 
 
 def sample_factor(key, csr: PaddedCSR, other: jnp.ndarray, tau: float,

@@ -291,7 +291,14 @@ def _run_gibbs_impl(key, csr_rows, csr_cols, test_rows, test_cols, cfg,
     summaries) is THIS code, so the composed chains share the reference
     semantics by construction. ``n_rows`` / ``n_cols`` override the
     factor sizes when ``csr_rows`` / ``csr_cols`` hold only a device's
-    local shard (the carry factors stay full-size and replicated)."""
+    local shard (the carry factors stay full-size and replicated).
+
+    Each layer of a sweep runs under a ``jax.named_scope`` — ``bmf_prior``,
+    ``bmf_u_step`` / ``bmf_v_step`` (whatever sampler the seam holds),
+    ``bmf_accumulate`` (with ``bmf_predict`` inside), and after the loop
+    ``bmf_summarize`` — so every executor path carries them. Scopes are op
+    metadata only: the compiled program is the same, and a profiler trace
+    names each device op by its scope path."""
     N = csr_rows.n_rows if n_rows is None else n_rows
     D = csr_cols.n_rows if n_cols is None else n_cols
     K = cfg.K
@@ -339,30 +346,36 @@ def _run_gibbs_impl(key, csr_rows, csr_cols, test_rows, test_cols, cfg,
         key, U, V, acc = carry
         key, kh1, kh2, ku, kv = jax.random.split(key, 5)
 
-        u_prior = pick_prior(U_prior, u_use, kh1, U, N)
-        v_prior = pick_prior(V_prior, v_use, kh2, V, D)
+        with jax.named_scope("bmf_prior"):
+            u_prior = pick_prior(U_prior, u_use, kh1, U, N)
+            v_prior = pick_prior(V_prior, v_use, kh2, V, D)
 
-        U = u_sampler(ku, csr_rows, V, u_prior)
-        V = v_sampler(kv, csr_cols, U, v_prior)
+        with jax.named_scope("bmf_u_step"):
+            U = u_sampler(ku, csr_rows, V, u_prior)
+        with jax.named_scope("bmf_v_step"):
+            V = v_sampler(kv, csr_cols, U, v_prior)
 
-        keep = (i >= burnin).astype(jnp.float32)
-        pred = BMF.predict(U, V, test_rows, test_cols)
-        acc = GibbsAccumulators(
-            pred_sum=acc.pred_sum + keep * pred,
-            pred_cnt=acc.pred_cnt + keep,
-            U_sum=acc.U_sum + keep * U,
-            U_outer=acc.U_outer + keep * jnp.einsum("nk,nl->nkl", U, U),
-            V_sum=acc.V_sum + keep * V,
-            V_outer=acc.V_outer + keep * jnp.einsum("nk,nl->nkl", V, V))
+        with jax.named_scope("bmf_accumulate"):
+            keep = (i >= burnin).astype(jnp.float32)
+            with jax.named_scope("bmf_predict"):
+                pred = BMF.predict(U, V, test_rows, test_cols)
+            acc = GibbsAccumulators(
+                pred_sum=acc.pred_sum + keep * pred,
+                pred_cnt=acc.pred_cnt + keep,
+                U_sum=acc.U_sum + keep * U,
+                U_outer=acc.U_outer + keep * jnp.einsum("nk,nl->nkl", U, U),
+                V_sum=acc.V_sum + keep * V,
+                V_outer=acc.V_outer + keep * jnp.einsum("nk,nl->nkl", V, V))
         return (key, U, V, acc)
 
     key, U, V, acc = jax.lax.fori_loop(
         0, n_samples, sweep, (key, U0, V0, acc0))
 
-    cnt = jnp.maximum(acc.pred_cnt, 1.0)
-    U_post = _summarize(acc.U_sum, acc.U_outer, cnt)
-    V_post = _summarize(acc.V_sum, acc.V_outer, cnt)
-    health = chain_health(U, V, U_post, V_post, acc.pred_sum)
+    with jax.named_scope("bmf_summarize"):
+        cnt = jnp.maximum(acc.pred_cnt, 1.0)
+        U_post = _summarize(acc.U_sum, acc.U_outer, cnt)
+        V_post = _summarize(acc.V_sum, acc.V_outer, cnt)
+        health = chain_health(U, V, U_post, V_post, acc.pred_sum)
     return GibbsResult(U=U, V=V, acc=acc, U_post=U_post, V_post=V_post,
                        health=health)
 

@@ -70,77 +70,82 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
     and solves stay f32).  force: 'pallas' / 'ref' pins the path, and n_stripe pins
     the N-stripe width, for the parity tests (a stripe covering all of N
     keeps both paths in the single-dispatch regime where agreement is
-    bitwise, not just ulp-level — see ref.py)."""
-    if dtype not in SWEEP_DTYPES:
-        raise ValueError(
-            f"sweep dtype must be one of {SWEEP_DTYPES}, got {dtype!r}")
-    N, M = idx.shape
-    K = other.shape[-1]
-    use_pallas = force == "pallas" or (
-        force is None and pallas_route("sweep", K))
-    if interpret is None:
-        interpret = not _on_tpu()
-    if use_pallas and not interpret:
-        check_lane_width(K)
-    if force is None and not use_pallas and _on_tpu():
-        warnings.warn(f"fused_sweep: K={K} takes the striped-XLA path on "
-                      f"TPU (route.pallas_route('sweep', K) is False)",
-                      stacklevel=2)
-    tm_eff = tm or min(TM, _ceil_to(max(M, 1), LANES))
-    lanes = LANES if _on_tpu() else HOST_LANES
-    Kp = _ceil_to(K, lanes)
-    Mp = _ceil_to(M, tm_eff)
-    if n_stripe is not None:
-        ns = _ceil_to(n_stripe, TN)
-    elif use_pallas:
-        # the scalar-prefetched index plane lives in SMEM: stripe N under it
-        ns = max(TN, (smem_idx_budget // (Mp * 4)) // TN * TN)
-    else:
-        raw = min(max(N * M // tm_eff, 1),
-                  max(tile_elems // (tm_eff * Kp), 1))
-        ns = max(TN, raw // TN * TN)
-    Np = _ceil_to(N, ns)
+    bitwise, not just ulp-level — see ref.py).
 
-    idxp = _pad_to(_pad_to(idx, Mp, 1), Np, 0)      # pad slots gather row 0
-    valp = _pad_to(_pad_to(val, Mp, 1), Np, 0)      # ... but are masked out
-    maskp = _pad_to(_pad_to(mask, Mp, 1), Np, 0)
-    pe = _pad_to(_pad_to(prior_eta.astype(jnp.float32), Kp, 1), Np, 0)
-    pL = prior_lam.astype(jnp.float32)
-    pL = _pad_to(_pad_to(_pad_to(pL, Kp, 1), Kp, 2), Np, 0)
-    if Kp > K:
-        # identity on the pad diagonal -> block-diagonal factor; pad-lane
-        # η/z are zero, so pad-lane samples are exactly zero
-        pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
-        pL = pL + jnp.diag(pad_diag)[None]
-    zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
-    otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
-    mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    Runs under the ``bmf_sweep`` named scope, on every caller's path (the
+    chain's seam and the data-sharded sweep of ``core.distributed``)."""
+    with jax.named_scope("bmf_sweep"):
+        if dtype not in SWEEP_DTYPES:
+            raise ValueError(
+                f"sweep dtype must be one of {SWEEP_DTYPES}, got {dtype!r}")
+        N, M = idx.shape
+        K = other.shape[-1]
+        use_pallas = force == "pallas" or (
+            force is None and pallas_route("sweep", K))
+        if interpret is None:
+            interpret = not _on_tpu()
+        if use_pallas and not interpret:
+            check_lane_width(K)
+        if force is None and not use_pallas and _on_tpu():
+            warnings.warn(f"fused_sweep: K={K} takes the striped-XLA path on "
+                          f"TPU (route.pallas_route('sweep', K) is False)",
+                          stacklevel=2)
+        tm_eff = tm or min(TM, _ceil_to(max(M, 1), LANES))
+        lanes = LANES if _on_tpu() else HOST_LANES
+        Kp = _ceil_to(K, lanes)
+        Mp = _ceil_to(M, tm_eff)
+        if n_stripe is not None:
+            ns = _ceil_to(n_stripe, TN)
+        elif use_pallas:
+            # the scalar-prefetched index plane lives in SMEM: stripe N
+            # under it
+            ns = max(TN, (smem_idx_budget // (Mp * 4)) // TN * TN)
+        else:
+            raw = min(max(N * M // tm_eff, 1),
+                      max(tile_elems // (tm_eff * Kp), 1))
+            ns = max(TN, raw // TN * TN)
+        Np = _ceil_to(N, ns)
 
-    if not use_pallas:
-        U = sweep_ref_padded(idxp, valp, maskp, pe, pL, zp, otherp, tau,
-                             tm=tm_eff, jitter=jitter, dtype=mm_dtype,
-                             n_stripe=ns)
+        idxp = _pad_to(_pad_to(idx, Mp, 1), Np, 0)   # pad slots gather row 0
+        valp = _pad_to(_pad_to(val, Mp, 1), Np, 0)   # ... but are masked out
+        maskp = _pad_to(_pad_to(mask, Mp, 1), Np, 0)
+        pe = _pad_to(_pad_to(prior_eta.astype(jnp.float32), Kp, 1), Np, 0)
+        pL = prior_lam.astype(jnp.float32)
+        pL = _pad_to(_pad_to(_pad_to(pL, Kp, 1), Kp, 2), Np, 0)
+        if Kp > K:
+            # identity on the pad diagonal -> block-diagonal factor; pad-lane
+            # η/z are zero, so pad-lane samples are exactly zero
+            pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
+            pL = pL + jnp.diag(pad_diag)[None]
+        zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
+        otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
+        mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+
+        if not use_pallas:
+            U = sweep_ref_padded(idxp, valp, maskp, pe, pL, zp, otherp, tau,
+                                 tm=tm_eff, jitter=jitter, dtype=mm_dtype,
+                                 n_stripe=ns)
+            return U[:N, :K]
+
+        def stripe(args):
+            ix, vl, mk, pe1, pL1, zz = args
+            return fused_sweep_padded(
+                ix, tile_occupancy(mk, TN, tm_eff), vl, mk, pe1, pL1, zz,
+                otherp, tau, tm=tm_eff, jitter=jitter, dtype=mm_dtype,
+                interpret=interpret)
+
+        if Np == ns:
+            U = stripe((idxp, valp, maskp, pe, pL, zp))
+        else:
+            nsp = Np // ns
+            U = jax.lax.map(stripe, (idxp.reshape(nsp, ns, Mp),
+                                     valp.reshape(nsp, ns, Mp),
+                                     maskp.reshape(nsp, ns, Mp),
+                                     pe.reshape(nsp, ns, Kp),
+                                     pL.reshape(nsp, ns, Kp, Kp),
+                                     zp.reshape(nsp, ns, Kp)))
+            U = U.reshape(Np, Kp)
         return U[:N, :K]
-
-    def stripe(args):
-        ix, vl, mk, pe1, pL1, zz = args
-        return fused_sweep_padded(
-            ix, tile_occupancy(mk, TN, tm_eff), vl, mk, pe1, pL1, zz,
-            otherp, tau, tm=tm_eff, jitter=jitter, dtype=mm_dtype,
-            interpret=interpret)
-
-    if Np == ns:
-        U = stripe((idxp, valp, maskp, pe, pL, zp))
-    else:
-        nsp = Np // ns
-        U = jax.lax.map(stripe, (idxp.reshape(nsp, ns, Mp),
-                                 valp.reshape(nsp, ns, Mp),
-                                 maskp.reshape(nsp, ns, Mp),
-                                 pe.reshape(nsp, ns, Kp),
-                                 pL.reshape(nsp, ns, Kp, Kp),
-                                 zp.reshape(nsp, ns, Kp)))
-        U = U.reshape(Np, Kp)
-    return U[:N, :K]
 
 
 def sample_factor_fused(key, csr, other, tau: float, prior, *,
