@@ -201,7 +201,7 @@ def chain_config(K: int) -> BMF.BMFConfig:
 
 def one_chip(args) -> None:
     K = MOVIELENS_20M.K
-    route = {k: pallas_route(k, K) for k in ("precision", "sweep")}
+    route = {k: pallas_route(k, K) for k in ("precision", "sweep", "sample")}
     log(f"route at K={K}: " + ", ".join(
         f"{k}={'pallas' if v else 'xla'}" for k, v in route.items()))
     if not all(route.values()):

@@ -95,18 +95,13 @@ def sample_rows_noise(g: RowGaussians, z: jnp.ndarray,
     """``sample_rows`` with the standard-normal draw ``z`` (N, K) supplied
     by the caller. Row-local math — the data-sharded intra-block sweep
     feeds each shard the SLICE of the full replicated draw so its local
-    rows match the single-device sample bit-for-bit. Runs under the
-    ``bmf_sample`` named scope (see ``gibbs._run_gibbs_impl``)."""
+    rows match the single-device sample bit-for-bit. The Pallas kernel or
+    XLA's batched Cholesky and solves, chosen from the platform, K and N
+    (``kernels/bmf_sample``). Runs under the ``bmf_sample`` named scope
+    (see ``gibbs._run_gibbs_impl``)."""
+    from repro.kernels.bmf_sample import ops as SAMPLE   # imports Pallas
     with jax.named_scope("bmf_sample"):
-        K = g.eta.shape[-1]
-        Lam = g.Lambda + jitter * jnp.eye(K)
-        chol = jnp.linalg.cholesky(Lam)
-        mu = jax.scipy.linalg.cho_solve((chol, True),
-                                        g.eta[..., None])[..., 0]
-        # x = mu + L^-T z has covariance Λ⁻¹
-        delta = jax.scipy.linalg.solve_triangular(
-            jnp.swapaxes(chol, -1, -2), z[..., None], lower=False)[..., 0]
-        return mu + delta
+        return SAMPLE.sample_rows_noise(g.Lambda, g.eta, z, jitter)
 
 
 def sample_rows(key, g: RowGaussians, jitter: float = 1e-6):

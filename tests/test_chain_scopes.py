@@ -28,9 +28,10 @@ ROUTE_CFG = {"kernel": dict(use_kernel=True, sweep_fused=False),
 def _components(text: str) -> set:
     """Every path component of every op name or location in ``text``; a
     component a transform wraps (``vmap(bmf_summarize)`` on the stacked
-    path) counts as the scope it wraps."""
+    path) counts as the scope it wraps. A file location (``loc("<path>":
+    line:col)``) is no scope: ``kernels/bmf_sample/`` is a directory."""
     names = re.findall(r'op_name="([^"]*)"', text)
-    names += re.findall(r'loc\("([^"]*)"', text)
+    names += re.findall(r'loc\("([^"]*)"(?!:)', text)
     return {re.sub(r"^\w+\((.*)\)$", r"\1", c)
             for n in names for c in n.split("/")}
 

@@ -1,12 +1,15 @@
 """Ahead-of-time compiles of the BMF Pallas kernels for a described TPU v5e.
 
 Interpret-mode parity cannot see what Mosaic refuses (unaligned slices,
-dot forms it cannot lower, VMEM limits), so both kernels are compiled here
+dot forms it cannot lower, VMEM limits), so the kernels are compiled here
 for a v5e chip that is described, not attached, at the stripe shapes the
 ops wrappers produce for the full-size MovieLens blocks: (8, 6656) is an
 item-side stripe of phase a on an 8x8 grid, (24, 2304) a user-side one,
 (8, 13312) the item side of phase a on the 4x4 grid ``chip_smoke.py``
 runs (the widest index plane), plus (256, 256).  K pads to 128 lanes.
+The row sampler kernel compiles at the block shapes
+of the benchmark's Netflix K=100 chain (15,006 user rows, 8,885 item
+rows) and at the MovieLens user side of a 4x4 grid at K=10.
 
 The topology is described inside a fixture (never at import), so every
 pytest-xdist worker collects the same tests and only the worker that runs
@@ -19,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bmf_precision.kernel import (
     LANES, TN, precision_accum_fused_padded)
+from repro.kernels.bmf_sample.kernel import sample_rows_kernel
 from repro.kernels.bmf_sweep.kernel import fused_sweep_padded
 
 STRIPES = [(8, 6656), (24, 2304), (8, 13312), (256, 256)]
@@ -81,3 +85,20 @@ def test_sweep_kernel_compiles_for_v5e(one_chip, N, M, dtype):
                        S((N, LANES), jnp.float32),
                        S((D, LANES), jnp.float32)).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("N,K", [(15006, 100), (8885, 100), (34623, 10)])
+def test_sample_kernel_compiles_for_v5e(one_chip, N, K):
+    S = lambda shape: _sds(one_chip, shape, jnp.float32)
+    f = jax.jit(sample_rows_kernel)
+    _assert_kernel(f.lower(S((N, K, K)), S((N, K)), S((N, K))).compile())
+
+
+def test_sample_kernel_vmap_compiles_for_v5e(one_chip):
+    """The store's Thompson draws: 8 slots over MovieLens-20M's 27,278
+    item rows at K=10, one Λ and a batch of noise draws."""
+    S = lambda shape: _sds(one_chip, shape, jnp.float32)
+    f = jax.jit(jax.vmap(sample_rows_kernel, in_axes=(None, None, 0)))
+    N, K = 27278, 10
+    _assert_kernel(f.lower(S((N, K, K)), S((N, K)),
+                           S((8, N, K))).compile())
