@@ -16,6 +16,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+# The NW hyperprior's products run in full f32: at default precision a TPU
+# runs an f32 dot as one bf16 pass, and a Wishart draw from a scatter
+# matrix rounded to bf16 takes the chain elsewhere within a few sweeps.
+HI = jax.lax.Precision.HIGHEST
+
 
 class RowGaussians(NamedTuple):
     """Per-row Gaussian beliefs over factor rows. eta = Λ μ."""
@@ -60,7 +65,8 @@ def from_moments_cov(mu, cov, ridge: float = 0.0) -> RowGaussians:
 def broadcast_prior(mu, Lambda, n_rows: int) -> RowGaussians:
     """Shared prior (mu (K,), Lambda (K,K)) -> per-row natural params."""
     K = mu.shape[-1]
-    eta = (Lambda @ mu)[None, :].repeat(n_rows, axis=0)
+    eta = jnp.matmul(Lambda, mu, precision=HI)
+    eta = jnp.repeat(eta[None, :], n_rows, axis=0)
     Lam = jnp.broadcast_to(Lambda, (n_rows, K, K))
     return RowGaussians(eta=eta, Lambda=Lam)
 
@@ -145,21 +151,23 @@ def sample_wishart(key, W: jnp.ndarray, nu, dtype=None):
     lower = jnp.tril(jax.random.normal(kn, (K, K), dtype=dtype), -1)
     A = A + lower
     L = jnp.linalg.cholesky(W + 1e-6 * jnp.eye(K, dtype=dtype))
-    LA = L @ A
-    return LA @ LA.T
+    LA = jnp.matmul(L, A, precision=HI)
+    return jnp.matmul(LA, LA.T, precision=HI)
 
 
 def nw_posterior(prior: NormalWishart, X: jnp.ndarray) -> NormalWishart:
     """Conjugate NW update given rows X (N, K)."""
     N, K = X.shape
     xbar = X.mean(0)
-    S = jnp.einsum("nk,nl->kl", X - xbar, X - xbar)      # N * sample cov
+    S = jnp.einsum("nk,nl->kl", X - xbar, X - xbar,
+                   precision=HI)                        # N * sample cov
     beta_n = prior.beta0 + N
     nu_n = prior.nu0 + N
     mu_n = (prior.beta0 * prior.mu0 + N * xbar) / beta_n
     d = (xbar - prior.mu0)[:, None]
     W0_inv = _chol_inverse(jnp.linalg.cholesky(prior.W0))
-    Wn_inv = W0_inv + S + (prior.beta0 * N / beta_n) * (d @ d.T)
+    Wn_inv = W0_inv + S + (prior.beta0 * N / beta_n) * jnp.matmul(
+        d, d.T, precision=HI)
     Wn = _chol_inverse(jnp.linalg.cholesky(Wn_inv))
     return NormalWishart(mu0=mu_n, beta0=beta_n, W0=Wn, nu0=nu_n)
 

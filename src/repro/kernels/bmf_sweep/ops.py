@@ -73,7 +73,9 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
     bitwise, not just ulp-level — see ref.py).
 
     Runs under the ``bmf_sweep`` named scope, on every caller's path (the
-    chain's seam and the data-sharded sweep of ``core.distributed``)."""
+    chain's seam and the data-sharded sweep of ``core.distributed``); the
+    padding of the operands to tile shapes under ``bmf_sweep_layout``
+    inside it."""
     with jax.named_scope("bmf_sweep"):
         if dtype not in SWEEP_DTYPES:
             raise ValueError(
@@ -106,19 +108,23 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
             ns = max(TN, raw // TN * TN)
         Np = _ceil_to(N, ns)
 
-        idxp = _pad_to(_pad_to(idx, Mp, 1), Np, 0)   # pad slots gather row 0
-        valp = _pad_to(_pad_to(val, Mp, 1), Np, 0)   # ... but are masked out
-        maskp = _pad_to(_pad_to(mask, Mp, 1), Np, 0)
-        pe = _pad_to(_pad_to(prior_eta.astype(jnp.float32), Kp, 1), Np, 0)
-        pL = prior_lam.astype(jnp.float32)
-        pL = _pad_to(_pad_to(_pad_to(pL, Kp, 1), Kp, 2), Np, 0)
-        if Kp > K:
-            # identity on the pad diagonal -> block-diagonal factor; pad-lane
-            # η/z are zero, so pad-lane samples are exactly zero
-            pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
-            pL = pL + jnp.diag(pad_diag)[None]
-        zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
-        otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
+        # the operands laid out in tiles, scoped apart from the kernel's own
+        # loop; pad slots gather row 0 but are masked out
+        with jax.named_scope("bmf_sweep_layout"):
+            idxp = _pad_to(_pad_to(idx, Mp, 1), Np, 0)
+            valp = _pad_to(_pad_to(val, Mp, 1), Np, 0)
+            maskp = _pad_to(_pad_to(mask, Mp, 1), Np, 0)
+            pe = _pad_to(_pad_to(prior_eta.astype(jnp.float32), Kp, 1), Np,
+                         0)
+            pL = prior_lam.astype(jnp.float32)
+            pL = _pad_to(_pad_to(_pad_to(pL, Kp, 1), Kp, 2), Np, 0)
+            if Kp > K:
+                # identity on the pad diagonal -> block-diagonal factor;
+                # pad-lane η/z are zero, so pad-lane samples are exactly zero
+                pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
+                pL = pL + jnp.diag(pad_diag)[None]
+            zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
+            otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
         mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
 
         if not use_pallas:

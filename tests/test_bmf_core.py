@@ -202,6 +202,28 @@ def test_sample_nw_moments_match_analytic():
                                atol=0.15 * np.abs(cov_analytic).max())
 
 
+def test_nw_hyperprior_products_are_pinned_to_highest():
+    """On a TPU an f32 dot at default precision runs as one bf16 pass, and
+    a Wishart draw from a scatter matrix rounded to bf16 moves the chain.
+    Every product of the NW hyperprior draw and of its broadcast to rows
+    (the chain's ``bmf_prior`` layer) is pinned to HIGHEST at the op."""
+    from repro.roofline.jaxpr_cost import iter_eqns
+    K, N = 10, 64
+    hi = jax.lax.Precision.HIGHEST
+
+    def prior(key, X):
+        mu, Lam = BMF.sample_hyper(key, X, POST.default_nw(K))
+        return POST.broadcast_prior(mu, Lam, N)
+
+    jx = jax.make_jaxpr(prior)(jax.random.key(0), jnp.zeros((N, K)))
+    dots = [e for e in iter_eqns(jx) if e.primitive.name == "dot_general"]
+    # the scatter matrix, d d^T, L A, (L A)(L A)^T and Lambda mu
+    assert len(dots) >= 5
+    loose = [str(e) for e in dots
+             if e.params["precision"] not in ((hi, hi), hi)]
+    assert loose == []
+
+
 def test_from_moments_cov_matches_inverse():
     """Cholesky factor/solve summarization == explicit-inverse natural
     params (the path it replaced)."""

@@ -20,7 +20,7 @@ CHAIN_SCOPES = ("bmf_prior", "bmf_u_step", "bmf_v_step", "bmf_accumulate",
                 "bmf_predict", "bmf_summarize")
 # scopes of the factor step, by route
 ROUTE_SCOPES = {"kernel": ("bmf_stats", "bmf_sample"),
-                "fused": ("bmf_sweep",)}
+                "fused": ("bmf_sweep", "bmf_sweep_layout")}
 ROUTE_CFG = {"kernel": dict(use_kernel=True, sweep_fused=False),
              "fused": dict(sweep_fused=True)}
 
