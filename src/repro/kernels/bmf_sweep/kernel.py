@@ -19,9 +19,15 @@ Small-K linear algebra without dynamic lane indexing: TPU vector layouts
 forbid addressing individual lanes, so the Cholesky and the triangular
 solves are written as fori_loops over columns where every "element access"
 is a masked broadcasted-iota reduction and every "element write" is a
-masked add into a zero lane.  That costs O(K) vector ops per column —
-O(K²) total per row on top of the O(K³) multiply work — which is cheap
-for the K ≤ 32 regime this kernel targets (ops.py falls back above it).
+masked add into a zero lane.  Each column step costs a few such ops over
+the whole tile it is given, so the epilogue is sized by the true rank k,
+not by the lane padding: its loops run k steps, on the live (TN, ks, ks)
+corner of the 128-lane tile (ks = k rounded up to 8 sublanes,
+``live_width``), and the sample is written into the first ks lanes of the
+output block, whose other lanes stay zero.  At K=10 that is 10 steps on
+16 vregs per tile instead of 128 steps on 128.  The K ≤ 32 route
+(ops.py falls back above it) is where this pays for the saved HBM
+round-trips.
 
 Noise contract: the caller supplies z = normal(key, (N, K)) — the SAME
 draw ``posterior.sample_rows`` makes — so the chain's random stream is
@@ -51,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.bmf_precision.kernel import DMA_LOOKAHEAD, LANES, TM, TN
 
 __all__ = ["accum_tile", "sample_tile", "chol_tile", "solve_lower_tile",
-           "solve_upper_tile", "fused_sweep_padded",
+           "solve_upper_tile", "live_width", "fused_sweep_padded",
            "TN", "TM", "LANES", "DMA_LOOKAHEAD"]
 
 
@@ -99,15 +105,25 @@ def _kk_iota(K, dtype=jnp.float32):
     return rows, cols
 
 
-def chol_tile(A):
-    """Batched left-looking Cholesky of (B, K, K) SPD tiles.
+def live_width(k: int, K: int) -> int:
+    """Width of the corner that loops bounded by ``k`` read: ``k`` rounded up
+    to the 8-row sublane tile, at most the operands' own width ``K``."""
+    return min(-(-k // 8) * 8, K)
+
+
+def chol_tile(A, k=None):
+    """Batched left-looking Cholesky of (B, K, K) SPD tiles, over the
+    leading ``k`` columns (default all K).
 
     Column j of L needs only columns < j — which are the only nonzeros of
     the running factor — so the cross-term Σ_{p<j} L[i,p]·L[j,p] is the
     FULL-K contraction against row j (zeros beyond p<j contribute exactly
-    nothing).  Element reads/writes are masked-iota reductions/adds: no
-    dynamic lane indexing anywhere."""
+    nothing).  For the same reason the leading k columns do not depend on
+    the rest: where A is zero off the diagonal past k, stopping at k gives
+    them unchanged and leaves columns ≥ k zero.  Element reads/writes are
+    masked-iota reductions/adds: no dynamic lane indexing anywhere."""
     B, K, _ = A.shape
+    k = K if k is None else k
     rows, cols = _kk_iota(K)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
@@ -128,12 +144,14 @@ def chol_tile(A):
         newcol = (a_col - s) / ljj[:, None] * below + ljj[:, None] * at_j
         return L + newcol[:, :, None] * colsel[None]    # write column j
 
-    return jax.lax.fori_loop(0, K, col, jnp.zeros_like(A))
+    return jax.lax.fori_loop(0, k, col, jnp.zeros_like(A))
 
 
-def solve_lower_tile(L, b):
-    """Forward substitution y = L⁻¹ b for (B, K, K) lower tiles."""
+def solve_lower_tile(L, b, k=None):
+    """Forward substitution y = L⁻¹ b for (B, K, K) lower tiles, over the
+    leading ``k`` lanes (default all K); lanes ≥ k stay zero."""
     B, K = b.shape
+    k = K if k is None else k
     rows, _ = _kk_iota(K)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
@@ -146,18 +164,20 @@ def solve_lower_tile(L, b):
         ljj = jnp.sum(l_row * at_j, axis=1)
         return y + ((bj - s) / ljj)[:, None] * at_j
 
-    return jax.lax.fori_loop(0, K, step, jnp.zeros_like(b))
+    return jax.lax.fori_loop(0, k, step, jnp.zeros_like(b))
 
 
-def solve_upper_tile(L, b):
+def solve_upper_tile(L, b, k=None):
     """Backward substitution x = L⁻ᵀ b (solve against the TRANSPOSE of the
-    lower factor — the covariance half of the ``sample_rows_noise`` split)."""
+    lower factor — the covariance half of the ``sample_rows_noise`` split),
+    from lane k - 1 down (default k = K); lanes ≥ k stay zero."""
     B, K = b.shape
+    k = K if k is None else k
     _, cols = _kk_iota(K)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
     def step(t, x):
-        j = K - 1 - t
+        j = k - 1 - t
         colsel = (cols == j).astype(L.dtype)
         l_col = jnp.sum(L * colsel[None], axis=2)       # (B, K) = L[:, :, j]
         s = jnp.sum(l_col * x, axis=1)                  # x zeroed for p ≤ j
@@ -166,23 +186,25 @@ def solve_upper_tile(L, b):
         ljj = jnp.sum(l_col * at_j, axis=1)
         return x + ((bj - s) / ljj)[:, None] * at_j
 
-    return jax.lax.fori_loop(0, K, step, jnp.zeros_like(b))
+    return jax.lax.fori_loop(0, k, step, jnp.zeros_like(b))
 
 
-def sample_tile(lam, eta, prior_lam, prior_eta, z, jitter):
+def sample_tile(lam, eta, prior_lam, prior_eta, z, jitter, k=None):
     """Finish one row tile: add the prior, factor, and draw the sample.
 
     Mirrors ``posterior.sample_rows_noise`` exactly — Λ += jitter·I,
     μ = Λ⁻¹η via forward+backward solve, δ = L⁻ᵀ z — with the in-register
-    solvers above.  All f32: bf16 stops at the accumulate."""
+    solvers above, over the true rank ``k`` (default the operands' K).
+    Lanes ≥ k of the sample are exactly zero.  All f32: bf16 stops at the
+    accumulate."""
     K = eta.shape[-1]
     rows, cols = _kk_iota(K)
     eye = (rows == cols).astype(jnp.float32)
     A = lam + prior_lam + jitter * eye[None]
     b = eta + prior_eta
-    L = chol_tile(A)
-    mu = solve_upper_tile(L, solve_lower_tile(L, b))
-    delta = solve_upper_tile(L, z)
+    L = chol_tile(A, k)
+    mu = solve_upper_tile(L, solve_lower_tile(L, b, k), k)
+    delta = solve_upper_tile(L, z, k)
     return mu + delta
 
 
@@ -193,7 +215,7 @@ def sample_tile(lam, eta, prior_lam, prior_eta, z, jitter):
 
 def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
                   z_ref, other_ref, u_ref, lam_ref, eta_ref, vg_ref, sem, *,
-                  tau: float, tm: int, jitter: float, dtype):
+                  tau: float, tm: int, jitter: float, dtype, k: int):
     n = pl.program_id(0)
     m = pl.program_id(1)
 
@@ -240,22 +262,29 @@ def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
     def _solve_and_sample():
         # epilogue: Λ/η never leave VMEM — prior add, in-register Cholesky,
         # triangular solves, and the noise add all happen here, and the only
-        # HBM write of the whole factor step is this (TN, K) sample block
-        u_ref[...] = sample_tile(lam_ref[...], eta_ref[...], plam_ref[...],
-                                 peta_ref[...], z_ref[...], jitter)
+        # HBM write of the whole factor step is this (TN, K) sample block.
+        # It reads only the live (ks, ks) corner; the lanes past it keep the
+        # zeros written at m == 0
+        ks = live_width(k, u_ref.shape[-1])
+        u_ref[:, :ks] = sample_tile(
+            lam_ref[:, :ks, :ks], eta_ref[:, :ks], plam_ref[:, :ks, :ks],
+            peta_ref[:, :ks], z_ref[:, :ks], jitter, k)
 
 
 def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
                        other, tau: float, *, tm: int = TM,
                        jitter: float = 1e-6, dtype=jnp.float32,
-                       interpret: bool = False):
+                       k: int | None = None, interpret: bool = False):
     """idx/val/mask: (N, M) with N % TN == 0, M % tm == 0; ntiles: (N/TN,)
     live-M-tile counts; prior_eta/z: (N, K), prior_lam: (N, K, K) f32 with
     pad lanes carrying an identity diagonal; other: (D, K) f32,
-    HBM-resident; dtype: the Λ matmul operand dtype (``accum_tile``).
-    Returns the sampled factor U (N, K) — no (N, K, K) HBM intermediate."""
+    HBM-resident; dtype: the Λ matmul operand dtype (``accum_tile``);
+    k: the true rank inside the lane padding (default K), which bounds the
+    epilogue's Cholesky and solves.  Returns the sampled factor U (N, K),
+    zero past lane k — no (N, K, K) HBM intermediate."""
     N, M = idx.shape
     D, K = other.shape
+    k = K if k is None else k
     assert N % TN == 0 and M % tm == 0, (N, M, tm)
     grid = (N // TN, M // tm)
 
@@ -287,7 +316,7 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
         ],
     )
     kernel = functools.partial(_sweep_kernel, tau=tau, tm=tm, jitter=jitter,
-                               dtype=dtype)
+                               dtype=dtype, k=k)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

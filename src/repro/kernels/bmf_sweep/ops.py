@@ -15,9 +15,13 @@ planes / priors / noise to tile shapes and routes to
 
 Lane padding follows the backend: K pads to the 128-lane MXU width on
 TPU, to 8 sublanes on hosts (interpret mode has no lane constraint, and
-padding the CPU fallback 16× wide would be pure waste).  Pad lanes carry
-an identity diagonal in the prior Λ, so the padded Cholesky is block
-diagonal and pad-lane samples are exactly zero — trimming is lossless.
+padding the CPU fallback 16× wide would be pure waste).  Both paths get
+the unpadded K as their static ``k``: the Cholesky and solves stop at
+lane K and read only the corner of the padded tile it needs, so pad-lane
+samples are exactly zero and trimming is lossless.  Pad lanes carry an
+identity diagonal in the prior Λ, which keeps the padded Λ positive
+definite for a caller that factors all of it (``k`` left at its
+default); the bounded loops never pivot on it.
 
 ``sample_factor_fused`` is the drop-in for ``bmf.sample_factor``: it
 draws the SAME z = normal(key, (N, K)) that ``posterior.sample_rows``
@@ -119,8 +123,8 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
             pL = prior_lam.astype(jnp.float32)
             pL = _pad_to(_pad_to(_pad_to(pL, Kp, 1), Kp, 2), Np, 0)
             if Kp > K:
-                # identity on the pad diagonal -> block-diagonal factor;
-                # pad-lane η/z are zero, so pad-lane samples are exactly zero
+                # identity on the pad diagonal keeps the padded Λ PD; the
+                # epilogue below stops at lane K and never pivots on it
                 pad_diag = (jnp.arange(Kp) >= K).astype(jnp.float32)
                 pL = pL + jnp.diag(pad_diag)[None]
             zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
@@ -130,7 +134,7 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
         if not use_pallas:
             U = sweep_ref_padded(idxp, valp, maskp, pe, pL, zp, otherp, tau,
                                  tm=tm_eff, jitter=jitter, dtype=mm_dtype,
-                                 n_stripe=ns)
+                                 n_stripe=ns, k=K)
             return U[:N, :K]
 
         def stripe(args):
@@ -138,7 +142,7 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
             return fused_sweep_padded(
                 ix, tile_occupancy(mk, TN, tm_eff), vl, mk, pe1, pL1, zz,
                 otherp, tau, tm=tm_eff, jitter=jitter, dtype=mm_dtype,
-                interpret=interpret)
+                k=K, interpret=interpret)
 
         if Np == ns:
             U = stripe((idxp, valp, maskp, pe, pL, zp))

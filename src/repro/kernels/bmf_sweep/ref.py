@@ -26,17 +26,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bmf_sweep.kernel import accum_tile, sample_tile
+from repro.kernels.bmf_sweep.kernel import accum_tile, live_width, sample_tile
 
 
 def sweep_ref_padded(idx, val, mask, prior_eta, prior_lam, z, other,
                      tau: float, *, tm: int, jitter: float = 1e-6,
-                     dtype=jnp.float32, n_stripe: int):
+                     dtype=jnp.float32, n_stripe: int, k: int | None = None):
     """Same contract as ``kernel.fused_sweep_padded`` (minus the occupancy
     counts — all tiles are processed; dead ones add exact zeros).  N must
     be a multiple of ``n_stripe``; M a multiple of ``tm``."""
     N, M = idx.shape
     K = other.shape[-1]
+    k = K if k is None else k
+    ks = live_width(k, K)
     assert N % n_stripe == 0 and M % tm == 0, (N, M, n_stripe, tm)
 
     def stripe(args):
@@ -54,7 +56,10 @@ def sweep_ref_padded(idx, val, mask, prior_eta, prior_lam, z, other,
         # executors vmap this whole chain and the barrier primitive has
         # no batching rule — the ulp-level fusion drift it would prevent
         # is already inside the parity contract above)
-        return sample_tile(lam, eta, pL, pe, zz, jitter)
+        # the kernel's epilogue: the live corner, zeros past it
+        u = sample_tile(lam[:, :ks, :ks], eta[:, :ks], pL[:, :ks, :ks],
+                        pe[:, :ks], zz[:, :ks], jitter, k)
+        return jnp.pad(u, ((0, 0), (0, K - ks)))
 
     if N == n_stripe:
         return stripe((idx, val, mask, prior_eta, prior_lam, z))

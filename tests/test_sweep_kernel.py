@@ -54,10 +54,17 @@ def _case(rng, N, M, D, K, empty_rows=(), scale=1.0):
 
 # dims shaped like the engine's row buckets: ragged small and a
 # TN-unaligned N, one M-tile each. n_stripe covers all rows => one eager
-# dispatch per path => bitwise.
-@pytest.mark.parametrize("N,M,D,K", [(5, 17, 23, 8), (19, 40, 31, 12)])
+# dispatch per path => bitwise. The lanes128 case lays K=10 out at the
+# TPU's 128-lane width (identity pad diagonal, as ops.py builds it), so
+# both paths bound the epilogue by k=10 inside a 128-wide tile.
+@pytest.mark.parametrize("N,M,D,K,lanes", [
+    pytest.param(5, 17, 23, 8, None, id="5-17-23-8"),
+    pytest.param(19, 40, 31, 12, None, id="19-40-31-12"),
+    pytest.param(19, 40, 31, 10, 128, id="19-40-31-10-lanes128")])
 @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
-def test_fused_vs_ref_bitwise(N, M, D, K, dtype):
+def test_fused_vs_ref_bitwise(N, M, D, K, lanes, dtype, monkeypatch):
+    if lanes is not None:
+        monkeypatch.setattr(SWEEP, "HOST_LANES", lanes)
     rng = np.random.default_rng(3)
     idx, val, mask, pe, pL, z, other = _case(rng, N, M, D, K,
                                              empty_rows=(0, N - 1))
@@ -115,6 +122,49 @@ def test_fused_vs_ref_forced_striping(dtype):
                               n_stripe=40)
     np.testing.assert_allclose(np.asarray(U_one), np.asarray(U_ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def _lane_padded_tile(rng, B, K, Kp):
+    """One row tile's epilogue operands at rank K — the accumulated Λ and
+    η, a PD prior, the noise — and the same operands laid out at Kp lanes
+    as ``ops.fused_sweep`` lays them out: zeros past K, and an identity on
+    the prior Λ's pad diagonal."""
+    V = rng.normal(size=(B, 30, K))
+    lam = np.einsum("bmi,bmj->bij", V, V)
+    A = rng.normal(size=(B, K, K)) * 0.2
+    pL = np.einsum("nij,nkj->nik", A, A) + 1.5 * np.eye(K)[None]
+    eta, pe, z = (rng.normal(size=(B, K)) for _ in range(3))
+    small = (lam, eta, pL, pe, z)
+    pad = lambda x: np.pad(x, [(0, 0)] + [(0, Kp - K)] * (x.ndim - 1))
+    padded = [pad(x) for x in small]
+    padded[2] = padded[2] + np.diag((np.arange(Kp) >= K).astype(float))
+    f32 = lambda xs: [jnp.asarray(x, jnp.float32) for x in xs]
+    return f32(small), f32(padded)
+
+
+@pytest.mark.parametrize("K,Kp", [(10, 128), (12, 16), (3, 8)])
+def test_sample_tile_bounded_by_true_k(K, Kp):
+    """The epilogue's Cholesky and solves bounded by the true K inside a
+    lane-padded tile: the first K lanes are bitwise what the loops over
+    all Kp lanes give on the same operands (the Cholesky is left-looking
+    and the pad block is zero off the diagonal), and the pad lanes are
+    exactly zero. The live corner (``live_width``) the kernel reads, and
+    the unpadded K-wide tile, agree to rounding: XLA's lane reductions sum
+    a narrower row in another order."""
+    from repro.kernels.bmf_sweep.kernel import live_width, sample_tile
+    small, padded = _lane_padded_tile(np.random.default_rng(K), 8, K, Kp)
+    u_all = np.asarray(sample_tile(*padded, 1e-6))
+    u_k = np.asarray(sample_tile(*padded, 1e-6, K))
+    np.testing.assert_array_equal(u_k[:, :K], u_all[:, :K])
+    assert np.all(u_k[:, K:] == 0) and np.all(u_all[:, K:] == 0)
+
+    ks = live_width(K, Kp)
+    corner = [x[:, :ks, :ks] if x.ndim == 3 else x[:, :ks] for x in padded]
+    u_corner = np.asarray(sample_tile(*corner, 1e-6, K))
+    assert u_corner.shape == (8, ks) and np.all(u_corner[:, K:] == 0)
+    u_small = np.asarray(sample_tile(*small, 1e-6))
+    for u in (u_k, u_corner):
+        np.testing.assert_allclose(u[:, :K], u_small, rtol=1e-6, atol=1e-6)
 
 
 def test_empty_rows_reduce_to_prior_sample():

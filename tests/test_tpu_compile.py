@@ -7,6 +7,9 @@ ops wrappers produce for the full-size MovieLens blocks: (8, 6656) is an
 item-side stripe of phase a on an 8x8 grid, (24, 2304) a user-side one,
 (8, 13312) the item side of phase a on the 4x4 grid ``chip_smoke.py``
 runs (the widest index plane), plus (256, 256).  K pads to 128 lanes.
+The sweep kernel also compiles at K=10 on the stripes of the benchmark's
+MovieLens block, where its epilogue reads only the live corner of each
+lane-padded tile.
 The row sampler kernel compiles at the block shapes
 of the benchmark's Netflix K=100 chain (15,006 user rows, 8,885 item
 rows) and at the MovieLens user side of a 4x4 grid at K=10.
@@ -71,13 +74,21 @@ def test_precision_kernel_compiles_for_v5e(one_chip, N, M):
     _assert_kernel(compiled)
 
 
+# the K=10 cases are the stripes of ml20m-k10.chain's block (1,1) of 4x4:
+# the U side gathers its 6,820 item rows, the V side its 34,623 user rows;
+# their epilogue reads only the (16, 16) corner of each 128-lane tile
+SWEEP_CASES = [pytest.param(N, M, D, None, id=f"{N}-{M}") for N, M in STRIPES
+               ] + [pytest.param(128, 512, 6820, 10, id="128-512-k10"),
+                    pytest.param(32, 1792, 34623, 10, id="32-1792-k10")]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["fp32", "bf16"])
-@pytest.mark.parametrize("N,M", STRIPES)
-def test_sweep_kernel_compiles_for_v5e(one_chip, N, M, dtype):
+@pytest.mark.parametrize("N,M,D,k", SWEEP_CASES)
+def test_sweep_kernel_compiles_for_v5e(one_chip, N, M, D, k, dtype):
     S = lambda shape, dt: _sds(one_chip, shape, dt)
     f = jax.jit(lambda ix, nt, vl, mk, pe, pL, z, ot: fused_sweep_padded(
-        ix, nt, vl, mk, pe, pL, z, ot, 2.0, dtype=dtype))
+        ix, nt, vl, mk, pe, pL, z, ot, 2.0, dtype=dtype, k=k))
     compiled = f.lower(S((N, M), jnp.int32), S((N // TN,), jnp.int32),
                        S((N, M), jnp.float32), S((N, M), jnp.float32),
                        S((N, LANES), jnp.float32),
