@@ -7,10 +7,10 @@ matmul elsewhere) back to back so the two hot paths are directly comparable in
 one run; ``--json-out`` additionally writes the records as JSON (the CI
 smoke check uploads them as the BENCH_throughput.json artifact).
 
-``--distributed`` additionally measures the shard_map'd within-block sweep
-(core.distributed.run_gibbs_distributed) in its paper-faithful psum and
-beyond-paper scatter-V variants, crossed with the kernel paths — the
-scatter-V × fused-kernel interaction the ROADMAP flagged unbenchmarked.
+``--distributed`` additionally measures one block's chain data-sharded over
+all devices (core.distributed.run_gibbs_group) with its paper-faithful
+psum and beyond-paper scatter-V item-stat exchanges, crossed with the
+kernel paths.
 Fakes a 4-device CPU mesh via XLA_FLAGS when no multi-device platform is
 present (must happen before the first jax backend touch)."""
 from __future__ import annotations
@@ -89,32 +89,37 @@ def run(dataset: str, n_probe: int = 8, use_kernel: bool = False,
 
 def run_distributed(dataset: str, n_probe: int, use_kernel: bool,
                     scatter_v: bool):
-    """Within-block shard_map sweep throughput: scatter-V × kernel paths."""
+    """Data-sharded chain throughput: scatter-V × kernel paths."""
     from repro.core import distributed as DIST
+    from repro.core.topology import Topology
     coo, p = SYN.generate(dataset, seed=51)
     train, _ = train_test_split(coo, 0.1, seed=52)
     csr_r = coo_to_padded_csr(train)
     csr_c = coo_to_padded_csr(train.transpose())
     K = min(p.K, 16)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    topo = Topology(block=1, data=n_dev)
+    n_rows_pad = -(-train.n_rows // n_dev) * n_dev
+    n_cols_pad = (-(-train.n_cols // n_dev) * n_dev if scatter_v
+                  else train.n_cols)
+    csrt = DIST.shard_transposed_planes(train.row, train.col, train.val,
+                                        n_dev, n_rows_pad, n_cols_pad,
+                                        int(csr_c.idx.shape[1]))
     dummy = np.zeros(1, np.int32)
 
     def chain_secs(n):
         cfg = BMF.BMFConfig(K=K, n_samples=n, burnin=0,
                             use_kernel=use_kernel)
         t0 = time.time()
-        jax.block_until_ready(DIST.run_gibbs_distributed(
-            jax.random.key(0), csr_r, csr_c, dummy, dummy, cfg, mesh,
-            scatter_v=scatter_v).U)
+        jax.block_until_ready(DIST.run_gibbs_group(
+            jax.random.key(0), csr_r, csr_c, dummy, dummy, cfg, topo,
+            comm="scatter" if scatter_v else "psum", csrt=csrt).U)
         return time.time() - t0
 
-    # run_gibbs_distributed re-jits its shard_map sweep and redoes the
-    # host-side shard-CSR prep on EVERY call (no cross-call cache), so a
-    # warmup call can't amortize compile. Instead both a 1-sweep and an
-    # (n_probe+1)-sweep call pay the identical trace+compile+prep cost and
-    # the difference isolates n_probe steady-state sweeps.
-    chain_secs(1)                                  # backend/alloc warmup
+    # the chain length is a traced argument, so every call after the
+    # first reuses one executable: the difference of an (n_probe+1)-sweep
+    # and a 1-sweep call isolates n_probe steady-state sweeps
+    chain_secs(1)                                  # compile + warmup
     t_one = chain_secs(1)
     t_many = chain_secs(n_probe + 1)
     dt = max(t_many - t_one, 1e-9) / n_probe
@@ -145,7 +150,7 @@ def main():
                          "measures ONLY the one-kernel Gibbs sweep "
                          "(kernels/bmf_sweep, fp32 + bf16 rows)")
     ap.add_argument("--distributed", action="store_true",
-                    help="also measure the shard_map'd sweep, psum and "
+                    help="also measure the data-sharded chain, psum and "
                          "scatter-V variants crossed with the kernel paths")
     ap.add_argument("--n-probe", type=int, default=8)
     ap.add_argument("--json-out", default=None,
@@ -174,7 +179,7 @@ def main():
             payload["note"] = (
                 "this run faked a multi-device CPU mesh via XLA_FLAGS "
                 f"host_platform_device_count ({len(jax.devices())} devices); "
-                "dist_* records measure the shard_map'd sweep there, and the "
+                "dist_* records measure the data-sharded chain there, and the "
                 "plain-path records of the same process share that env")
         with open(args.json_out, "w") as f:
             json.dump(payload, f, indent=2)

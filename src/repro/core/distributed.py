@@ -1,35 +1,39 @@
-"""Distributed BMF within one block (ref [16], adapted MPI→shard_map).
+"""Distributed BMF within one block (ref [16]/[17], adapted MPI→shard_map).
 
-The paper's Fig. 2 communication pattern: rows of U are sampled in parallel
-on the workers that own them; the cross-factor dependency is resolved by
-exchanging the freshly sampled factor. Our TPU adaptation:
+One block's Gibbs chain sharded over the 'data' axis of a device group.
+The chain body is ``gibbs._run_gibbs_impl`` — the same code every other
+path runs — with its factor-sampler seams swapped for data-sharded ones:
 
   - the block's users (rows of U) and their ratings are sharded over the
-    'data' mesh axis (padded CSR, rating-count-balanced by partition.py);
-  - U-step: each device samples its local U rows against a REPLICATED V —
-    zero communication;
-  - V-step: each device computes partial per-item sufficient statistics
-    (τ Σ u uᵀ, τ Σ r u) from its local ratings (COO segment-sum), a single
-    psum reduces them, and every device samples the SAME V (same key) —
-    communication is exactly 2·D·(K²+K)·4 bytes per sweep, independent of
-    #ratings: the paper's "limited communication" property, made explicit.
+    'data' mesh axis (padded CSR rows, padded to a multiple of the axis);
+  - U-step: each device samples its local U rows against a replicated V
+    from the SLICE of the reference noise draw, then one all_gather
+    rebuilds the full factor (``_sharded_u_sampler``);
+  - V-step (``COMM_MODES``): 'gather' runs the reference V-step on the
+    gathered factor, so the chain is the single-device chain; 'psum'
+    reduces per-shard partial item statistics (τ Σ u uᵀ, τ Σ r u) with one
+    psum — 2·D·(K²+K)·4 bytes per sweep, independent of #ratings: the
+    paper's "limited communication" property, made explicit; 'scatter'
+    reduce-scatters them and all_gathers the sampled V.
 
-Hyperparameter (NW) sampling similarly reduces O(K²) factor moments.
+Key handling, the NW hyperprior, accumulators and summaries are the
+reference code, so the chain draws the reference chain's numbers in
+'gather' mode (``run_gibbs_group`` matches ``gibbs.run_gibbs`` under the
+same key).
 """
 from __future__ import annotations
 
 import contextlib
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import bmf as BMF
 from repro.core import gibbs as GIBBS
 from repro.core import posterior as POST
-from repro.core.posterior import NormalWishart, RowGaussians
+from repro.core.posterior import RowGaussians
 from repro.core.topology import BLOCK_AXIS, DATA_AXIS
 from repro.data.sparse import PaddedCSR
 
@@ -47,301 +51,19 @@ def make_block_mesh(n_devices: Optional[int] = None) -> Mesh:
     return Topology(block=n, data=1).block_mesh()
 
 
-def _pad_rows(arr, mult):
-    n = arr.shape[0]
-    pad = (-n) % mult
-    if pad:
-        arr = jnp.concatenate([arr, jnp.zeros((pad,) + arr.shape[1:],
-                                              arr.dtype)], 0)
-    return arr
-
-
-def pad_csr_for_mesh(csr: PaddedCSR, n_shards: int) -> PaddedCSR:
-    return PaddedCSR(idx=_pad_rows(csr.idx, n_shards),
-                     val=_pad_rows(csr.val, n_shards),
-                     mask=_pad_rows(csr.mask, n_shards),
-                     n_cols=csr.n_cols)
-
-
 def item_stats_local(U_loc, csr_t_loc: PaddedCSR, tau: float,
                      use_kernel: bool = False):
     """Per-item partial stats from this device's ratings.
 
     U_loc: (N_loc, K); csr_t_loc: this shard's TRANSPOSED padded CSR —
     rows = items, columns = *local* user slots (built host-side by
-    run_gibbs_distributed). Returns (D, K, K), (D, K) partial sums
+    ``shard_transposed_planes``). Returns (D, K, K), (D, K) partial sums
     (pre-reduction). Reuses bmf.sufficient_stats, i.e. the same
     fused-gather hot path (Pallas kernel / chunked scan) as the U-step —
     a segment_sum formulation would materialize an (nnz, K, K) outer
     product tensor (§Perf H6a).
     """
     return BMF.sufficient_stats(csr_t_loc, U_loc, tau, use_kernel)
-
-
-def make_distributed_sweep(mesh: Mesh, cfg: BMF.BMFConfig, N: int, D: int,
-                           n_shards: int,
-                           has_u_prior: bool, has_v_prior: bool,
-                           scatter_v: bool = False):
-    """Build the shard_mapped one-sweep function.
-
-    scatter_v=False — paper-faithful (ref [16] Fig. 2): psum the full
-      (D, K, K) item stats, every device samples the same replicated V.
-    scatter_v=True — beyond-paper (§Perf H6): psum_scatter the stats so
-      each device reduces only its D/P item rows (half the ring bytes of a
-      psum), samples ONLY those rows (V-step Cholesky parallelized too),
-      then all_gathers the sampled V (D·K floats — 2/K² of the stats).
-      Comm per sweep: D(K²+K)/2 + DK floats vs D(K²+K).
-    """
-    K = cfg.K
-    nw = POST.default_nw(K)
-    assert not (scatter_v and D % n_shards), (D, n_shards)
-
-    def sweep(key, U, V, csr_idx, csr_val, csr_mask,
-              csrt_idx, csrt_val, csrt_mask,
-              u_prior_eta, u_prior_lam, v_prior_eta, v_prior_lam):
-        # --- everything here runs per-device on local shards -------------
-        csr_loc = PaddedCSR(idx=csr_idx, val=csr_val, mask=csr_mask, n_cols=D)
-        # transposed shard: (1, D, M_c) with leading shard dim from shard_map
-        csrt_loc = PaddedCSR(idx=csrt_idx[0], val=csrt_val[0],
-                             mask=csrt_mask[0], n_cols=csr_idx.shape[0])
-        key, kh1, kh2, ku, kv = jax.random.split(key, 5)
-
-        # U hyperprior: needs global U moments -> psum of local moments
-        if has_u_prior:
-            u_prior = RowGaussians(eta=u_prior_eta, Lambda=u_prior_lam)
-        else:
-            s1 = jax.lax.psum(U.sum(0), "data")                  # (K,)
-            s2 = jax.lax.psum(jnp.einsum("nk,nl->kl", U, U), "data")
-            muU, LamU = _sample_nw_from_moments(kh1, s1, s2, N, nw)
-            u_prior = POST.broadcast_prior(muU, LamU, U.shape[0])
-
-        # --- U-step: local rows vs replicated V (no communication) -------
-        # fold in the shard index: every device must draw DIFFERENT noise
-        # for its own U rows (the V-step key below is deliberately shared so
-        # all devices sample the identical replicated V).
-        ku_dev = jax.random.fold_in(ku, jax.lax.axis_index("data"))
-        U = BMF.sample_factor(ku_dev, csr_loc, V, cfg.tau, u_prior,
-                              cfg.use_kernel)
-
-        # --- V-step ---------------------------------------------------------
-        Lam_part, eta_part = item_stats_local(U, csrt_loc, cfg.tau,
-                                              cfg.use_kernel)
-        if has_v_prior:
-            v_prior = RowGaussians(eta=v_prior_eta, Lambda=v_prior_lam)
-        else:
-            s1v = V.sum(0)                                        # V replicated
-            s2v = jnp.einsum("dk,dl->kl", V, V)
-            muV, LamV = _sample_nw_from_moments(kh2, s1v, s2v, D, nw)
-            v_prior = POST.broadcast_prior(muV, LamV, D)
-        if scatter_v:
-            # beyond-paper: reduce-scatter stats to D/P local item rows,
-            # sample locally (different noise per shard), gather sampled V
-            Lam_loc = jax.lax.psum_scatter(Lam_part, "data", scatter_dimension=0,
-                                           tiled=True)   # (D/P, K, K)
-            eta_loc = jax.lax.psum_scatter(eta_part, "data", scatter_dimension=0,
-                                           tiled=True)   # (D/P, K)
-            idx = jax.lax.axis_index("data")
-            d_lo = idx * (D // n_shards)
-            pr_eta = jax.lax.dynamic_slice_in_dim(v_prior.eta, d_lo,
-                                                  D // n_shards, 0)
-            pr_lam = jax.lax.dynamic_slice_in_dim(v_prior.Lambda, d_lo,
-                                                  D // n_shards, 0)
-            cond = RowGaussians(eta=pr_eta + eta_loc, Lambda=pr_lam + Lam_loc)
-            kv_dev = jax.random.fold_in(kv, idx)
-            V_loc = POST.sample_rows(kv_dev, cond)
-            V = jax.lax.all_gather(V_loc, "data", tiled=True)     # (D, K)
-        else:
-            # paper-faithful: full psum, replicated sampling (same key)
-            Lam_items = jax.lax.psum(Lam_part, "data")            # (D, K, K)
-            eta_items = jax.lax.psum(eta_part, "data")            # (D, K)
-            cond = RowGaussians(eta=v_prior.eta + eta_items,
-                                Lambda=v_prior.Lambda + Lam_items)
-            V = POST.sample_rows(kv, cond)  # same key everywhere -> same V
-        return key, U, V
-
-    in_specs = (P(), P("data", None), P(None, None),
-                P("data", None), P("data", None), P("data", None),
-                P("data", None, None), P("data", None, None),
-                P("data", None, None),
-                P("data", None) if has_u_prior else P(None),
-                P("data", None, None) if has_u_prior else P(None),
-                P(None, None) if has_v_prior else P(None),
-                P(None, None, None) if has_v_prior else P(None))
-    out_specs = (P(), P("data", None), P(None, None))
-    return jax.shard_map(sweep, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-
-
-def _sample_nw_from_moments(key, s1, s2, n, nw: NormalWishart):
-    """NW posterior sample from psum'd moments (Σx, Σxxᵀ, n)."""
-    xbar = s1 / n
-    S = s2 - n * jnp.outer(xbar, xbar)
-    beta_n = nw.beta0 + n
-    nu_n = nw.nu0 + n
-    mu_n = (nw.beta0 * nw.mu0 + n * xbar) / beta_n
-    d = (xbar - nw.mu0)[:, None]
-    Wn_inv = jnp.linalg.inv(nw.W0) + S + (nw.beta0 * n / beta_n) * (d @ d.T)
-    Wn = jnp.linalg.inv(Wn_inv)
-    return POST.sample_nw(key, NormalWishart(mu0=mu_n, beta0=beta_n,
-                                             W0=Wn, nu0=nu_n))
-
-
-def run_gibbs_distributed(key, csr_rows: PaddedCSR, csr_cols: PaddedCSR,
-                          test_rows, test_cols, cfg: BMF.BMFConfig,
-                          mesh: Mesh,
-                          U_prior: Optional[RowGaussians] = None,
-                          V_prior: Optional[RowGaussians] = None,
-                          scatter_v: bool = False,
-                          U0: Optional[jnp.ndarray] = None,
-                          V0: Optional[jnp.ndarray] = None,
-                          donate: bool = False) -> GIBBS.GibbsResult:
-    """Distributed analogue of gibbs.run_gibbs for one (large) block.
-
-    Note: csr_cols is unused in the distributed path (item stats come from
-    the row-sharded COO via segment_sum) but kept for signature parity.
-
-    ``donate=True`` donates the per-sweep CARRY (key, U, V) to the jitted
-    sweep: each iteration's factor buffers are rewritten in place as the
-    next iteration's outputs instead of allocating a fresh (N, K) + (D, K)
-    pair per sweep — the distributed analogue of the PR-3 chain donation
-    (the CSR planes and priors are reused every sweep and are never
-    donated). ``U0`` / ``V0`` optionally seed the factors (same contract
-    as ``run_gibbs``); with ``donate=True`` the caller's handles are
-    invalidated by the first sweep.
-    """
-    n_shards = mesh.shape["data"]
-    N, D, K = csr_rows.n_rows, csr_rows.n_cols, cfg.K
-    D_orig = D
-    csr = pad_csr_for_mesh(csr_rows, n_shards)
-    N_pad = csr.idx.shape[0]
-    if scatter_v and D % n_shards:
-        # pad item dim so psum_scatter tiles evenly; padded item rows get
-        # prior-only stats and their samples are never read back
-        pad_d = (-D) % n_shards
-        csr = PaddedCSR(idx=csr.idx, val=csr.val, mask=csr.mask,
-                        n_cols=D + pad_d)
-        if V_prior is not None:
-            eye = jnp.broadcast_to(jnp.eye(K), (pad_d, K, K))
-            V_prior = RowGaussians(
-                eta=jnp.concatenate([V_prior.eta, jnp.zeros((pad_d, K))]),
-                Lambda=jnp.concatenate([V_prior.Lambda, eye]))
-        D = D + pad_d
-
-    # host-side: per-shard TRANSPOSED padded CSR (items x local users) for
-    # the V-step partial stats (§Perf H6a — avoids the (nnz,K,K) segment-sum
-    # blow-up of the naive formulation)
-    import numpy as np
-    from repro.data.sparse import COO, coo_to_padded_csr
-    N_loc = N_pad // n_shards
-    idx_h = np.asarray(csr.idx)
-    val_h = np.asarray(csr.val)
-    mask_h = np.asarray(csr.mask)
-    rows_h, slots_h = np.nonzero(mask_h > 0)
-    cols_h = idx_h[rows_h, slots_h]
-    vals_h = val_h[rows_h, slots_h]
-    shard_of = rows_h // N_loc
-    shard_csrts = []
-    m_c = 1
-    for s in range(n_shards):
-        sel = shard_of == s
-        coo_t = COO(row=cols_h[sel].astype(np.int32),
-                    col=(rows_h[sel] - s * N_loc).astype(np.int32),
-                    val=vals_h[sel].astype(np.float32),
-                    n_rows=D, n_cols=N_loc)
-        cnt = np.bincount(coo_t.row, minlength=D)
-        m_c = max(m_c, int(cnt.max()) if cnt.size else 1)
-        shard_csrts.append(coo_t)
-    csrt_parts = [coo_to_padded_csr(c, max_nnz=m_c) for c in shard_csrts]
-    csrt_idx = jnp.stack([c.idx for c in csrt_parts])     # (S, D, M_c)
-    csrt_val = jnp.stack([c.val for c in csrt_parts])
-    csrt_mask = jnp.stack([c.mask for c in csrt_parts])
-
-    k0, key = jax.random.split(key)
-    if U0 is None or V0 is None:
-        U0_, V0_ = BMF.init_factors(k0, N_pad, D, K)
-        U0 = U0 if U0 is not None else U0_
-        V0 = V0 if V0 is not None else V0_
-    U0 = _pad_rows(U0, n_shards)
-    if U0.shape[0] != N_pad:
-        raise ValueError(f"U0 rows {U0.shape[0]} != padded N {N_pad}")
-    if V0.shape[0] != D:
-        V0 = jnp.concatenate([V0, jnp.zeros((D - V0.shape[0], K))])
-
-    has_u = U_prior is not None
-    has_v = V_prior is not None
-    if has_u:
-        U_prior = RowGaussians(eta=_pad_rows(U_prior.eta, n_shards),
-                               Lambda=_pad_rows(U_prior.Lambda, n_shards))
-        # padded rows get identity precision (harmless, never read back)
-        pad = N_pad - N
-        if pad:
-            U_prior = RowGaussians(
-                eta=U_prior.eta,
-                Lambda=U_prior.Lambda.at[N:].set(jnp.eye(K)))
-    dummy_eta = jnp.zeros((1,), jnp.float32)
-
-    sweep = make_distributed_sweep(mesh, cfg, N_pad, D, n_shards, has_u, has_v,
-                                   scatter_v=scatter_v)
-    # donate the carry: (key, U, V) of sweep t alias sweep t+1's outputs,
-    # so the per-sweep loop recycles its factor buffers in place instead of
-    # allocating a fresh pair every iteration (ROADMAP lever: donation for
-    # the distributed per-sweep loop). The plane/prior args are reused
-    # across sweeps and stay un-donated. The initial carry is device_put
-    # to the sweep's exact shardings first — a donated buffer jit has to
-    # reshard is consumed by the transfer, not aliased, and the caller's
-    # U0/V0 handles would silently stay live.
-    sweep = jax.jit(sweep, donate_argnums=(0, 1, 2) if donate else ())
-    if donate:
-        def commit(x, spec):
-            sh = NamedSharding(mesh, spec)
-            return x if getattr(x, "sharding", None) == sh \
-                else jax.device_put(x, sh)
-        key = commit(key, P())
-        U0 = commit(U0, P("data", None))
-        V0 = commit(V0, P(None, None))
-
-    acc = GIBBS.GibbsAccumulators(
-        pred_sum=jnp.zeros_like(test_rows, dtype=jnp.float32),
-        pred_cnt=jnp.zeros((), jnp.float32),
-        U_sum=jnp.zeros((N_pad, K)), U_outer=jnp.zeros((N_pad, K, K)),
-        V_sum=jnp.zeros((D, K)), V_outer=jnp.zeros((D, K, K)))
-
-    U, V = U0, V0
-    predict_j = jax.jit(BMF.predict)
-    # the kept-sample consumers (the test-entry gather, the accumulators
-    # and their summaries) read a replicated copy of U: on an explicit-axis
-    # mesh a 'data'-sharded operand leaves the gather's output sharding
-    # ambiguous and the summaries' batched solves mismatched
-    replicated = NamedSharding(mesh, P())
-    for it in range(cfg.n_samples):
-        key, U, V = sweep(
-            key, U, V, csr.idx, csr.val, csr.mask,
-            csrt_idx, csrt_val, csrt_mask,
-            U_prior.eta if has_u else dummy_eta,
-            U_prior.Lambda if has_u else dummy_eta,
-            V_prior.eta if has_v else dummy_eta,
-            V_prior.Lambda if has_v else dummy_eta)
-        if it >= cfg.burnin:
-            U_rep = jax.device_put(U, replicated)
-            pred = predict_j(U_rep, V, test_rows, test_cols)
-            acc = GIBBS.GibbsAccumulators(
-                pred_sum=acc.pred_sum + pred,
-                pred_cnt=acc.pred_cnt + 1.0,
-                U_sum=acc.U_sum + U_rep,
-                U_outer=acc.U_outer + jnp.einsum("nk,nl->nkl", U_rep, U_rep),
-                V_sum=acc.V_sum + V,
-                V_outer=acc.V_outer + jnp.einsum("dk,dl->dkl", V, V))
-
-    cnt = jnp.maximum(acc.pred_cnt, 1.0)
-    U_post = GIBBS._summarize(acc.U_sum[:N], acc.U_outer[:N], cnt)
-    V_post = GIBBS._summarize(acc.V_sum[:D_orig], acc.V_outer[:D_orig], cnt)
-    # trim padding
-    acc = acc._replace(U_sum=acc.U_sum[:N], U_outer=acc.U_outer[:N],
-                       V_sum=acc.V_sum[:D_orig], V_outer=acc.V_outer[:D_orig])
-    health = jax.jit(GIBBS.chain_health)(
-        U[:N], V[:D_orig], U_post, V_post, acc.pred_sum)
-    return GIBBS.GibbsResult(U=U[:N], V=V[:D_orig], acc=acc, U_post=U_post,
-                             V_post=V_post, health=health)
 
 
 # ---------------------------------------------------------------------------
@@ -383,9 +105,7 @@ def shard_transposed_planes(rows, cols, vals, n_shards: int, n_rows_pad: int,
 
     rows/cols/vals: COO triplets in BLOCK-local coordinates (numpy).
     Returns (idx, val, mask) numpy arrays of shape
-    (n_shards, n_items, max_nnz) — the same per-shard layout
-    ``run_gibbs_distributed`` assembles inline, factored out so the
-    stacked 2-D executor path and the single-block path share it."""
+    (n_shards, n_items, max_nnz)."""
     import numpy as np
     from repro.data.sparse import COO, coo_to_padded_csr
 
@@ -654,7 +374,8 @@ def run_gibbs_group(key, csr_rows: PaddedCSR, csr_cols: PaddedCSR,
                     donate: bool = False, comm: str = "gather",
                     csrt=None) -> GIBBS.GibbsResult:
     """One block's chain data-sharded over a single topology group — the
-    AsyncExecutor's multi-device dispatch unit. Implemented as the B=1
+    serial and async executors' dispatch unit on a group of >1 devices
+    (``engine._group_chain``). Implemented as the B=1
     stacked 2-D executable on the group's (1, data) submesh, so every
     group shares one compilation per (bucket, group) and the chain
     matches ``run_gibbs`` under the same key (the stacked batched key

@@ -11,8 +11,8 @@ and executes it through a pluggable ``Executor``:
 
   SerialExecutor   reference semantics: one jitted Gibbs call per block with
                    a host sync after each — what ``run_pp`` always did.
-                   Composes with an intra-block ``distributed_mesh`` /
-                   ``Topology(block=1, data=S)``.
+                   ``Topology(block=1, data=S)`` shards each block's
+                   chain over S devices (``distributed.run_gibbs_group``).
   StackedExecutor  stacks all blocks of a phase shape bucket along a leading
                    axis and runs ONE jitted vmapped chain per bucket
                    (``gibbs.run_gibbs_stacked``) — the per-block Python
@@ -23,42 +23,42 @@ and executes it through a pluggable ``Executor``:
                    'block' device mesh: same-phase blocks genuinely run
                    concurrently on separate devices with NO collectives
                    inside a phase — the paper's deployment model, on-device.
-  AsyncExecutor    dependency-driven overlap: readiness counters over
-                   ``BlockTask.deps`` dispatch each block's jitted chain the
-                   moment its row/col prior posteriors resolve, riding JAX
-                   async dispatch — phase-c blocks whose phase-b sources
-                   finished early start while the rest of phase b is still
-                   running. No ``block_until_ready`` until the final
-                   aggregation; completion is detected by non-blocking
-                   ``is_ready()`` polls on tiny per-block squared-error
-                   scalars. Posterior summaries stay device-resident
-                   between phases, padded input buffers are donated to XLA
-                   (``gibbs.run_gibbs(donate=True)``), and with >1 local
-                   device each dispatch lands on the next topology GROUP
-                   round-robin: per-group streams instead of one sharded
-                   bucket (groups of 1 device = the legacy per-device
-                   streams; groups of >1 run each chain 'data'-sharded).
-  StreamingExecutor the same ready queue, but blocks stream through a
+  AsyncExecutor    dependency-driven overlap: each block's jitted chain is
+                   dispatched the moment its row/col prior posteriors
+                   resolve, riding JAX async dispatch — phase-c blocks
+                   whose phase-b sources finished early start while the
+                   rest of phase b is still running. Completion is
+                   detected by non-blocking ``is_ready()`` polls on tiny
+                   per-block squared-error scalars; posterior summaries
+                   stay device-resident between phases, padded input
+                   buffers are donated to XLA, and with several topology
+                   GROUPS each block lands on the least-loaded one (groups
+                   of >1 device run each chain 'data'-sharded).
+  StreamingExecutor the same scheduler, but blocks stream through a
                    bounded window of W donated block buffers: host-side
                    chunk assembly + double-buffered ``device_put``
                    prefetch, ``run_gibbs_stacked(donate=True)`` recycling,
-                   live peak ≤ W×(depth+1)×block_bytes per stream — flat
+                   live peak ≤ W×(depth+1)×block_bytes per group — flat
                    in the grid size, for grids whose stacked buckets
-                   exceed HBM. With a multi-group ``Topology`` it keeps
-                   ONE such window per device group (per-stream prefetch).
+                   exceed HBM.
+
+The async and streaming executors share ONE scheduler
+(``_ElasticScheduler``): readiness counters over ``BlockTask.deps``, a
+ready queue popped CRITICAL-PATH-FIRST (``critical_path_priority`` —
+estimated block cost plus the longest estimated successor chain, the same
+dependency-aware list-schedule depth ``PPResult.modeled_parallel_s``
+schedules measured times with; FIFO among ties), and the elastic fault
+layer (watchdog, quarantine, work stealing, speculation). They differ
+only in the unit they schedule (one block, or a chunk of up to W), how a
+unit is staged on a group, how it is dispatched, and how many staged and
+in-flight units a group may hold.
 
 Device placement is unified behind ``core.topology.Topology`` — a single
 2-D ('block', 'data') mesh whose groups run blocks concurrently while the
 'data' axis shards each block's Gibbs sweep (the intra-block distributed
-chain of core.distributed). Executors consume the same object instead of
-ad-hoc device lists; ``topology=Topology(block=2, data=2)`` turns any of
+chain of core.distributed). ``topology=`` is the one placement argument;
+``topology=Topology(block=2, data=2)`` turns any of
 sharded/async/streaming into the paper's combined two-level system.
-
-The async and streaming ready queues dispatch CRITICAL-PATH-FIRST: ready
-blocks pop in descending bottom-level order (``critical_path_priority`` —
-estimated block cost plus the longest estimated successor chain, the same
-dependency-aware list-schedule depth ``PPResult.modeled_parallel_s``
-schedules measured times with), FIFO among ties.
 
 Executor contract
 -----------------
@@ -67,9 +67,9 @@ ordering: it must write each block's posterior summaries into ``ctx.U_posts``
 / ``ctx.V_posts`` before any dependent reads them via ``ctx.priors(task)``.
 Barrier executors get that for free from the default implementation, which
 runs ``run_phase(ctx, phase, tasks) -> {(i, j): BlockOutcome}`` once per
-phase after asserting every dep resolved; the async executor replaces the
-whole loop with its dependency-counting scheduler. Executors never
-aggregate: ``run_phase_graph`` owns RMSE accumulation and the Qin-et-al.
+phase after asserting every dep resolved; the async and streaming
+executors replace the whole loop with the elastic scheduler. Executors
+never aggregate: ``run_phase_graph`` owns RMSE accumulation and the Qin-et-al.
 divide-away aggregation (``pp._aggregate_axis``, one jitted device-resident
 reduction).
 
@@ -77,11 +77,12 @@ Fault tolerance (see core/README.md): every block's chain computes a
 device-resident health scalar (``gibbs.GibbsResult.health``) checked at
 resolve time by ``_commit_guard`` — unhealthy blocks retry through one
 shared single-block runner (re-split key, jittered prior), then degrade to
-their propagated prior or raise per ``FaultPolicy``. The async/streaming
-poll loops are watchdog-policed (cost-model deadlines; timed-out dispatches
-re-dispatch on the next device group), ``checkpoint_dir``/``resume_from``
-persist and restore per-block posteriors bitwise, and ``FaultPlan`` is the
-deterministic injection seam the chaos tests drive every executor with.
+their propagated prior or raise per ``FaultPolicy``. The elastic
+scheduler's poll loop is watchdog-policed (cost-model deadlines; timed-out
+dispatches re-dispatch on the next device group),
+``checkpoint_dir``/``resume_from`` persist and restore per-block
+posteriors bitwise, and ``FaultPlan`` is the deterministic injection seam
+the chaos tests drive every executor with.
 
 Note on timings: SerialExecutor measures true per-block seconds;
 Stacked/Sharded report bucket wall time split evenly across the bucket's
@@ -253,7 +254,7 @@ class FaultPlan:
       poll loop to hang.
     fail_dispatch_at: dispatching the block raises — exercised at every
       executor's dispatch site (serial call, stacked bucket assembly,
-      async dispatch, streaming chunk formation).
+      unit formation in the elastic scheduler of async and streaming).
 
     Group-level injections key on the GROUP and its per-group dispatch
     ordinal (``PhaseContext.next_group_ordinal``) instead of (coord,
@@ -683,7 +684,7 @@ class Executor:
     ``PPResult.group_stats``); always 0 for barrier executors.
     """
     name = "base"
-    devices: Tuple = ()    # AsyncExecutor's per-device streams
+    devices: Tuple = ()    # devices the run's posteriors may land on
 
     def __init__(self, record_trace: bool = False):
         self.record_trace = record_trace
@@ -758,44 +759,39 @@ def _phase_desc(ctx: PhaseContext, tasks: Sequence[BlockTask]) -> str:
 
 class SerialExecutor(Executor):
     """One jitted Gibbs call + host sync per block (reference semantics,
-    bit-for-bit today's ``run_pp`` loop). Composes with an intra-block
-    ``distributed_mesh``: each block's chain is itself shard_map'd.
-    A ``topology`` (block must be 1 — serial runs one block at a time)
-    is the unified way to say the same thing: its single group's 'data'
-    mesh becomes the intra-block mesh."""
+    bit-for-bit today's ``run_pp`` loop). Each block runs the async
+    executor's per-block unit (``_group_chain``) on group 0 of
+    ``topology`` (block must be 1 — serial runs one block at a time):
+    with ``data > 1`` the block's chain is 'data'-sharded over the group
+    (``distributed.run_gibbs_group``, comm 'gather'), which draws the
+    same chain as one device under the same key."""
     name = "serial"
 
-    def __init__(self, distributed_mesh=None, record_trace: bool = False,
+    def __init__(self, record_trace: bool = False,
                  topology: Optional[Topology] = None):
         super().__init__(record_trace=record_trace)
-        if topology is not None:
-            if distributed_mesh is not None:
-                raise ValueError("pass distributed_mesh OR topology, not both")
-            if topology.block != 1:
-                raise ValueError(
-                    f"serial executor runs one block at a time — a topology "
-                    f"with block={topology.block} device groups needs the "
-                    f"sharded/async/streaming executor")
-            if topology.data > 1:
-                distributed_mesh = topology.data_mesh(0)
-        self.distributed_mesh = distributed_mesh
+        topology = topology if topology is not None else Topology(1, 1)
+        if topology.block != 1:
+            raise ValueError(
+                f"serial executor runs one block at a time — a topology "
+                f"with block={topology.block} device groups needs the "
+                f"sharded/async/streaming executor")
+        self.topology = topology
+        if topology.n_devices > 1:
+            self.devices = topology.devices
 
     def run_phase(self, ctx, phase, tasks):
         out: Dict[Coord, BlockOutcome] = {}
         for t in tasks:
-            blk = ctx.part.block(t.i, t.j)
-            up, vp = ctx.priors(t)
             self._record("dispatch", t.coord)
             t0 = time.time()
             try:
                 ctx.check_dispatch(t.coord)
-                res = PP.run_block(ctx.keys[t.i, t.j], blk, ctx.block_cfg(t),
-                                   ctx.test_p, up, vp, self.distributed_mesh,
-                                   shapes=ctx.shapes[t.phase],
-                                   poison_nan=ctx.should_poison(t.coord))
+                res, _, _, _ = _group_chain(ctx, t, self.topology, 0)
                 jax.block_until_ready(res.U)
                 self._record("resolve", t.coord)
-                out[t.coord] = _outcome(res, blk, time.time() - t0)
+                out[t.coord] = _outcome(res, ctx.part.block(t.i, t.j),
+                                        time.time() - t0)
             except _DISPATCH_ERRORS:
                 self._record("resolve", t.coord)
                 out[t.coord] = _commit_guard(ctx, t, None, kind="dispatch")
@@ -804,8 +800,9 @@ class SerialExecutor(Executor):
 
 def _task_leaves(ctx: PhaseContext, task: BlockTask):
     """Device-ready leaves for one block — pp.pad_block_inputs is the
-    single source of truth for bucket padding, shared with run_block, so
-    stacked chains are identical to serial ones by construction."""
+    single source of truth for bucket padding, shared with the serial
+    executor, so stacked chains are identical to serial ones by
+    construction."""
     blk = ctx.part.block(task.i, task.j)
     up, vp = ctx.priors(task)
     csr_r, csr_c, tr, tc, _, _, up, vp = PP.pad_block_inputs(
@@ -1024,13 +1021,12 @@ def _block_cost_estimates(ctx: PhaseContext,
             for c, t in tasks.items()}
 
 
-def _dep_state(ctx: PhaseContext, graph, priority: bool, make_queue=None):
-    """Shared ready-queue scaffolding for the overlapped schedulers
-    (async + streaming): task/phase maps, readiness counters, successor
-    lists, and the priority ready queue seeded with the dep-free blocks.
-    ``make_queue(prio, tasks)`` lets callers substitute a queue type (the
-    streaming executor uses a per-group view). Returns
-    ``(tasks, phase_of, waiting, succ, ready)``."""
+def _dep_state(ctx: PhaseContext, graph, priority: bool, make_queue):
+    """Ready-queue scaffolding of the elastic scheduler: task/phase maps,
+    readiness counters, successor lists, and the priority ready queue
+    ``make_queue(prio, tasks)`` (the executor's queue type) seeded with
+    the dep-free blocks. Returns ``(tasks, phase_of, waiting, succ,
+    ready)``."""
     tasks = {t.coord: t for _, ts in graph for t in ts}
     phase_of = {t.coord: ph for ph, ts in graph for t in ts}
     # a resumed graph is pruned: deps satisfied by restored blocks don't
@@ -1045,7 +1041,7 @@ def _dep_state(ctx: PhaseContext, graph, priority: bool, make_queue=None):
     prio = (critical_path_priority(tasks, _block_cost_estimates(ctx, tasks),
                                    succ=succ)
             if priority else None)
-    ready = make_queue(prio, tasks) if make_queue else _ReadyQueue(prio)
+    ready = make_queue(prio, tasks)
     for c, w in waiting.items():
         if w == 0:
             ready.push(c)
@@ -1053,9 +1049,10 @@ def _dep_state(ctx: PhaseContext, graph, priority: bool, make_queue=None):
 
 
 class _ReadyQueue:
-    """Priority ready queue shared by the async and streaming schedulers:
-    pops in descending critical-path (bottom-level) order, FIFO among ties
-    — with priorities disabled it degenerates to the PR-3 FIFO exactly."""
+    """Priority queue of the elastic scheduler (the async ready queue and
+    every group's staged units): pops in descending critical-path
+    (bottom-level) order, FIFO among ties — with priorities disabled it
+    degenerates to plain FIFO."""
 
     def __init__(self, prio: Optional[Dict[Coord, float]] = None):
         import heapq
@@ -1064,12 +1061,13 @@ class _ReadyQueue:
         self._seq = 0
         self._heap: List[Tuple[float, int, Coord]] = []
 
-    def push(self, c: Coord):
-        self._heapq.heappush(self._heap,
-                             (-self._prio.get(c, 0.0), self._seq, c))
+    def push(self, c: Coord, item=None):
+        """Queue ``item`` (default: ``c`` itself) at ``c``'s priority."""
+        self._heapq.heappush(self._heap, (-self._prio.get(c, 0.0), self._seq,
+                                          c if item is None else item))
         self._seq += 1
 
-    def pop(self) -> Coord:
+    def pop(self):
         return self._heapq.heappop(self._heap)[2]
 
     def __len__(self):
@@ -1096,6 +1094,9 @@ class _GroupedReadyQueue:
         self._n = 0
 
     def push(self, c: Coord):
+        # a coord pushed back after being taken (its staged chunk was
+        # released by a quarantine) is live again
+        self._taken.discard(c)
         self._global.push(c)
         self._groups.setdefault(self._group_of(c),
                                 _ReadyQueue(self._prio)).push(c)
@@ -1198,18 +1199,31 @@ class _GroupHealth:
 
 
 @dataclass
-class _Flight:
-    """One in-flight dispatch attempt on a device group — a single block
-    (async) or a window chunk (streaming). Multiple flights for the same
-    work = a speculative twin pair. ``sup`` is the group-level injection
-    verdict for this dispatch (0 healthy / wall-clock gate / inf dead),
-    applied at the completion-observation seam like ``is_hung``."""
-    sig: object                            # completion scalar/vector
-    out: object                            # BlockOutcome | {coord: outcome}
-    td: float                              # dispatch wall time
+class _Unit:
+    """One dispatch unit staged on device group ``group``: a single block
+    (async) or a window chunk of up to W blocks (streaming). ``staged``
+    is the executor's payload for it — the chunk's device buffers
+    (streaming), or None (async: a block's planes move at dispatch)."""
+    tasks: List[BlockTask]
     group: int
+    staged: object = None
+
+
+@dataclass
+class _Flight:
+    """One in-flight dispatch of a unit. Two flights of the same unit are
+    a speculative twin pair. ``sup`` is the group-level injection verdict
+    for this dispatch (0 healthy / wall-clock gate / inf dead), applied at
+    the completion-observation seam like ``is_hung``."""
+    sig: object                            # completion scalar/vector
+    out: Dict[Coord, BlockOutcome]
+    td: float                              # dispatch wall time
+    unit: _Unit
     sup: float = 0.0
-    tasks: Optional[List[BlockTask]] = None  # streaming chunk members
+
+    @property
+    def group(self) -> int:
+        return self.unit.group
 
 
 def _maybe_degrade_topology(ctx: PhaseContext, health: _GroupHealth):
@@ -1233,7 +1247,501 @@ def _maybe_degrade_topology(ctx: PhaseContext, health: _GroupHealth):
         dead_groups=dead)
 
 
-class AsyncExecutor(Executor):
+class _ElasticScheduler:
+    """The dependency-driven elastic schedule of the overlapped executors
+    (async, streaming) — one instance per ``run_graph`` call.
+
+    Ready blocks (``_dep_state``: readiness counters, critical-path-first
+    queue) are formed into dispatch UNITS and staged on the least-loaded
+    healthy device group; each group dispatches its staged units up to
+    its in-flight cap, and completion is found by polling each flight's
+    signal (``Executor._is_resolved``). Around that loop sit the fault
+    layers (``FaultPolicy``): per-group ``_GroupHealth`` rates, watchdog
+    deadlines over a unit's estimated cost with expiry and re-dispatch
+    under the same keys, quarantine with rebalancing, speculative twins
+    that commit the canonical-group winner, work stealing from the
+    most-loaded group, and the adaptive-sleep poll. Flights are keyed by
+    flight id; ``twin`` links the two flights of a speculative pair both
+    ways, and only ever links two live flights.
+
+    The executor supplies what differs between the two:
+
+      ``_form_unit(ctx, ready, tasks)``  the next unit's tasks off the
+                                          ready queue (async: one block;
+                                          streaming: a chunk of ≤ W);
+      ``_stage(ctx, tasks, group)``      stage a unit on a group — its
+                                          payload (async: None; streaming:
+                                          host assembly + one device_put);
+      ``_dispatch(ctx, unit)``           dispatch a staged unit →
+                                          (completion signal,
+                                          {coord: BlockOutcome});
+      ``stage_cap`` / ``inflight_cap``   staged units a group may hold and
+                                          flights it may have in the air
+                                          (None = unbounded).
+
+    An injected dispatch failure (``FaultPlan.fail_dispatch_at``) is
+    healed when its unit is formed — the block never joins a unit; a
+    runtime failure of the dispatch itself heals the unit's blocks.
+    Every unit dispatch consumes one group ordinal (the unit of the
+    group-level injections)."""
+
+    def __init__(self, ex: "_OverlappedExecutor", ctx: PhaseContext, graph,
+                 verbose: bool):
+        self.ex, self.ctx, self.verbose = ex, ctx, verbose
+        self.pol = ctx.policy
+        (self.tasks, self.phase_of, self.waiting, self.succ,
+         self.ready) = _dep_state(
+            ctx, graph, ex.priority,
+            make_queue=lambda prio, ts: ex._ready_queue(ctx, prio, ts))
+        self.est = _block_cost_estimates(ctx, self.tasks)
+        G = ex.topology.block
+        self.health = _GroupHealth(G, self.pol.quarantine_after)
+        self.elastic = G > 1    # one group: nowhere to rebalance/steal/twin
+        # per-group staged units (priority-ordered — the steal pool)
+        self.staged = [_ReadyQueue(self.ready._prio) for _ in range(G)]
+        self.inflight = [0] * G
+        self.flights: Dict[int, _Flight] = {}
+        self.twin: Dict[int, int] = {}
+        self._next_fid = 0
+        self.outcomes: Dict[Coord, BlockOutcome] = {}
+        self.spans: Dict[Coord, Tuple[float, float]] = {}
+        self.first_d: Dict[str, float] = {}
+        self.last_r: Dict[str, float] = {}
+        self.remaining = {ph: len(ts) for ph, ts in graph}
+        self.t0 = time.time()
+
+    # -- the loop ------------------------------------------------------------
+
+    def run(self):
+        while self.ready or any(self.staged) or self.flights:
+            self.stage_ready()
+            progress = False
+            for g in self.health.healthy():
+                if self.staged[g] and self.has_room(g):
+                    self.launch(self.staged[g].pop(), "dispatch")
+                    progress = True
+            if self.elastic and not progress:
+                progress = self.steal()
+            if progress or not self.flights:
+                continue
+            self.await_progress()
+            self.resolve_ready()
+        # per-phase envelopes: first dispatch → last resolve. Phases
+        # overlap, so these may sum to MORE than the wall time.
+        phase_times = {ph: self.last_r[ph] - self.first_d[ph]
+                       for ph in self.first_d}
+        return self.outcomes, phase_times, self.spans
+
+    # -- group load ----------------------------------------------------------
+
+    def load(self, g: int) -> int:
+        return len(self.staged[g]) + self.inflight[g]
+
+    def has_room(self, g: int) -> bool:
+        cap = self.ex.inflight_cap
+        return cap is None or self.inflight[g] < cap
+
+    def least_loaded(self, groups=None) -> int:
+        return min(self.health.healthy() if groups is None else groups,
+                   key=lambda g: (self.load(g), g))
+
+    def unit_cost(self, unit: _Unit) -> float:
+        return sum(self.est[t.coord] for t in unit.tasks)
+
+    def note_peak(self):
+        live = len(self.flights) + sum(len(q) for q in self.staged)
+        self.ex.peak_units = max(self.ex.peak_units, live)
+
+    # -- staging and dispatch ------------------------------------------------
+
+    def stage_ready(self):
+        """Form units off the ready queue and stage each on the
+        least-loaded healthy group that has staging room."""
+        cap = self.ex.stage_cap
+        while self.ready:
+            room = [g for g in self.health.healthy()
+                    if cap is None or len(self.staged[g]) < cap]
+            if not room:
+                return
+            g = self.least_loaded(room)
+            tasks = []
+            for t in self.ex._form_unit(self.ctx, self.ready, self.tasks):
+                try:
+                    self.ctx.check_dispatch(t.coord)
+                    tasks.append(t)
+                except _InjectedDispatchFailure:
+                    self.ex._record("dispatch", t.coord, g)
+                    now = time.time()
+                    self.first_d.setdefault(self.phase_of[t.coord],
+                                            now - self.t0)
+                    self.retire(t, None, now, kind="dispatch", group=g)
+            if tasks:
+                unit = _Unit(tasks, g, self.ex._stage(self.ctx, tasks, g))
+                self.staged[g].push(tasks[0].coord, unit)
+                self.note_peak()
+
+    def restage(self, unit: _Unit, g: int) -> _Unit:
+        return _Unit(unit.tasks, g, self.ex._stage(self.ctx, unit.tasks, g))
+
+    def dispatch(self, unit: _Unit) -> Optional[_Flight]:
+        """Dispatch a staged unit without waiting for anything. The
+        outcomes' device handles go into the posterior store AT DISPATCH
+        (successors and the final aggregation consume them as dataflow).
+        None when the runtime refused the dispatch."""
+        g = unit.group
+        td = time.time()
+        ordinal = self.ctx.next_group_ordinal(g)
+        sup = self.ctx.group_suppressed_until(g, ordinal, td)
+        try:
+            sig, outs = self.ex._dispatch(self.ctx, unit)
+        except _DISPATCH_ERRORS:
+            return None
+        for c, o in outs.items():
+            self.ctx.U_posts[c], self.ctx.V_posts[c] = o.U_post, o.V_post
+        return _Flight(sig=sig, out=outs, td=td, unit=unit, sup=sup)
+
+    def launch(self, unit: _Unit, event: str):
+        """Dispatch (or re-dispatch) ``unit`` as its group's new flight;
+        a refused dispatch heals the unit's blocks through retire."""
+        td = time.time()
+        for t in unit.tasks:
+            self.ex._record(event, t.coord, unit.group)
+            self.first_d.setdefault(self.phase_of[t.coord], td - self.t0)
+        f = self.dispatch(unit)
+        if f is None:
+            for t in unit.tasks:
+                self.retire(t, None, td, kind="dispatch", group=unit.group)
+            return
+        self.add_flight(f)
+
+    def add_flight(self, f: _Flight) -> int:
+        fid = self._next_fid
+        self._next_fid += 1
+        self.flights[fid] = f
+        self.inflight[f.group] += 1
+        self.note_peak()
+        return fid
+
+    def pop_flight(self, fid: int) -> Tuple[_Flight, Optional[int]]:
+        """Remove a flight; returns it with its live twin's id (if any),
+        unlinking the pair."""
+        f = self.flights.pop(fid)
+        self.inflight[f.group] -= 1
+        tw = self.twin.pop(fid, None)
+        if tw is not None:
+            self.twin.pop(tw, None)
+        return f, tw
+
+    def cancel(self, f: _Flight):
+        for t in f.unit.tasks:
+            self.ex._record("cancel", t.coord, f.group)
+        self.ex.n_cancels += len(f.unit.tasks)
+
+    # -- completion ----------------------------------------------------------
+
+    def flight_ready(self, f: _Flight) -> bool:
+        if any(self.ctx.is_hung(t.coord) for t in f.unit.tasks):
+            return False
+        if f.sup and time.time() < f.sup:
+            return False
+        return self.ex._is_resolved(f.unit.tasks[0].coord, f.sig)
+
+    def await_progress(self):
+        """Adaptive-sleep poll until a flight resolves or the watchdog
+        changes state (expiry/quarantine). ``watchdog=False`` restores the
+        legacy block-on-oldest fallback, which deadlocks if the oldest
+        in-flight unit died — keep it on."""
+        if not self.pol.watchdog:
+            f0 = min(self.flights.values(), key=lambda f: f.td)
+            jax.block_until_ready(f0.sig)
+            return
+        sleep = 5e-5
+        while self.flights:
+            if any(self.flight_ready(f) for f in self.flights.values()):
+                return
+            now = time.time()
+            if self.handle_expiries(now):
+                return
+            self.speculate(now)
+            time.sleep(sleep)
+            sleep = min(sleep * 2, 2e-3)
+
+    def resolve_ready(self):
+        for fid in [i for i, f in self.flights.items()
+                    if self.flight_ready(f)]:
+            if fid not in self.flights:    # its twin already committed
+                continue
+            win = fid
+            tw = self.twin.get(fid)
+            if tw is not None:
+                # deterministic winner: canonical group order among the
+                # READY sides — twins share the attempt-0 keys, so either
+                # is bitwise the fault-free result, and the rule keeps the
+                # committed handles independent of wall-clock order
+                other = self.flights[tw]
+                if (other.group < self.flights[fid].group
+                        and self.flight_ready(other)):
+                    win = tw
+                self.cancel(self.pop_flight(tw if win == fid else fid)[0])
+            f, _ = self.pop_flight(win)
+            # the store may hold the other twin's handles (written at its
+            # dispatch) — successors must consume the winner's
+            for c, o in f.out.items():
+                self.ctx.U_posts[c], self.ctx.V_posts[c] = o.U_post, o.V_post
+            tr = time.time()
+            # per-group EWMA rate; the group's first resolve (compile
+            # span) is dropped inside observe()
+            self.health.observe(f.group, (tr - f.td) / self.unit_cost(f.unit))
+            self.health.note_resolve(f.group)
+            # one executable ran the whole unit: split its wall evenly
+            # across members (mirrors StackedExecutor's bucket split)
+            per = (tr - f.td) / len(f.unit.tasks)
+            for t in f.unit.tasks:
+                self.retire(t, f.out[t.coord], f.td, per, group=f.group)
+
+    def retire(self, t: BlockTask, out: Optional[BlockOutcome], td: float,
+               per: Optional[float] = None, kind: Optional[str] = None,
+               group: Optional[int] = None):
+        """Commit one block: health guard, outcome, checkpoint, successor
+        release."""
+        c = t.coord
+        self.ex._record("resolve", c, group)
+        out = _commit_guard(self.ctx, t, out, kind=kind)
+        tr = time.time()
+        if not out.seconds:
+            out.seconds = per if per is not None else tr - td
+        self.spans[c] = (td - self.t0, tr - self.t0)
+        self.outcomes[c] = out
+        self.ctx.note_resolved(t, out)
+        ph = self.phase_of[c]
+        self.remaining[ph] -= 1
+        self.last_r[ph] = tr - self.t0
+        if self.verbose and self.remaining[ph] == 0:
+            ts = [t2 for t2 in self.tasks.values()
+                  if self.phase_of[t2.coord] == ph]
+            print(f"[pp:{self.ex.name}] phase {ph}: {len(ts)} block(s) "
+                  f"{_phase_desc(self.ctx, ts)} "
+                  f"{self.last_r[ph] - self.first_d[ph]:.2f}s "
+                  f"(dispatch→resolve envelope; phases overlap)",
+                  flush=True)
+        for s in self.succ[c]:
+            self.waiting[s] -= 1
+            if self.waiting[s] == 0:
+                self.ready.push(s)
+
+    # -- the group fault domain ----------------------------------------------
+
+    def deadline(self, f: _Flight) -> float:
+        # per-group watchdog deadline over the unit's total estimated cost:
+        # generous floor + slack × the group's OWN calibrated rate (EWMA
+        # seconds/cost; cold groups inherit the fastest calibrated rate, 0
+        # until any group calibrates — early units get the floor alone). A
+        # false expiry is benign: re-dispatch reuses the keys, so a
+        # slow-but-alive unit still resolves bitwise-identically.
+        return (self.pol.timeout_floor_s + self.pol.timeout_slack
+                * self.health.rate(f.group) * self.unit_cost(f.unit))
+
+    def note_expiry(self, f: _Flight):
+        if self.elastic and self.health.note_expiry(f.group):
+            self.quarantine(f.group, f.unit.tasks[0].coord)
+
+    def handle_expiries(self, now: float) -> bool:
+        """Watchdog sweep: expire overdue flights, count consecutive
+        expiries toward quarantine, re-dispatch or terminally retire.
+        Returns True when any state changed."""
+        changed = False
+        for fid in list(self.flights):
+            f = self.flights.get(fid)
+            if f is None or now - f.td <= self.deadline(f):
+                continue
+            changed = True
+            f, tw = self.pop_flight(fid)
+            if tw is not None:
+                # the twin flies on — the expired side only cancels
+                self.cancel(f)
+                self.note_expiry(f)
+                continue
+            for t in f.unit.tasks:
+                self.ex._record("expire", t.coord, f.group)
+            self.note_expiry(f)
+            tasks = f.unit.tasks
+            if all(self.ctx.cur_attempt(t.coord) < self.pol.max_retries
+                   for t in tasks):
+                for t in tasks:
+                    self.ctx.record_fault(t.coord, "timeout", "redispatched")
+                    self.ctx.attempts[t.coord] = (
+                        self.ctx.cur_attempt(t.coord) + 1)
+                self.launch(self.restage(f.unit, self.least_loaded()),
+                            "redispatch")
+            else:
+                for t in tasks:
+                    self.retire(t, None, f.td, kind="timeout", group=f.group)
+        return changed
+
+    def quarantine(self, g: int, trigger: Coord):
+        """Drain group ``g``: no future dispatch targets it, its staged
+        units return to the ready queue (dropping their staged buffers),
+        and its in-flight units re-stage on healthy groups under the SAME
+        keys (kind="group" — no block retry budget is consumed)."""
+        self.health.quarantine(g)
+        self.ex._record("quarantine", trigger, g)
+        self.ex.n_quarantined += 1
+        self.ctx.record_fault(trigger, "group", "quarantined")
+        _maybe_degrade_topology(self.ctx, self.health)  # may raise (ckpt
+        while self.staged[g]:                           # already flushed)
+            for t in self.staged[g].pop().tasks:
+                self.ready.push(t.coord)
+        for fid in [i for i, f in self.flights.items() if f.group == g]:
+            f, tw = self.pop_flight(fid)
+            if tw is not None:
+                # its healthy twin flies on: this side just cancels
+                self.cancel(f)
+                continue
+            for t in f.unit.tasks:
+                self.ex._record("expire", t.coord, g)
+                self.ctx.record_fault(t.coord, "group", "rebalanced")
+            self.launch(self.restage(f.unit, self.least_loaded()),
+                        "redispatch")
+
+    def speculate(self, now: float):
+        """Straggler hedge: an untwinned flight past ``speculate_at ×``
+        its group's calibrated deadline model is re-staged on an idle
+        healthy group and dispatched with the SAME keys (its twin)."""
+        if not self.elastic or self.pol.speculate_at <= 0.0:
+            return
+        for fid in list(self.flights):
+            f = self.flights.get(fid)
+            if f is None or fid in self.twin:
+                continue
+            r = self.health.rate(f.group)
+            if (r <= 0.0 or now - f.td
+                    <= self.pol.speculate_at * r * self.unit_cost(f.unit)):
+                continue
+            idle = [g for g in self.health.healthy()
+                    if g != f.group and not self.staged[g]
+                    and self.has_room(g)]
+            if not idle:
+                continue
+            f2 = self.dispatch(self.restage(f.unit, self.least_loaded(idle)))
+            if f2 is None:
+                continue    # the primary still flies; skip the twin
+            for t in f.unit.tasks:
+                self.ex._record("speculate", t.coord, f2.group)
+            self.ex.n_speculations += len(f.unit.tasks)
+            fid2 = self.add_flight(f2)
+            self.twin[fid], self.twin[fid2] = fid2, fid
+
+    def steal(self) -> bool:
+        """Work stealing: an idle healthy group takes the highest-priority
+        staged unit of the most-loaded group, re-stages it on itself and
+        dispatches it. Returns True when anything was stolen."""
+        stole = False
+        for g in self.health.healthy():
+            if self.staged[g] or self.ready or not self.has_room(g):
+                continue
+            victims = [h for h in self.health.healthy()
+                       if h != g and self.staged[h]]
+            if not victims:
+                continue
+            v = max(victims, key=lambda h: (self.load(h), -h))
+            unit = self.staged[v].pop()
+            for t in unit.tasks:
+                self.ex._record("steal", t.coord, g)
+            self.ex.n_steals += len(unit.tasks)
+            self.launch(self.restage(unit, g), "dispatch")
+            stole = True
+        return stole
+
+
+def _group_chain(ctx: PhaseContext, task: BlockTask, topo: Topology, g: int,
+                 comm: str = "gather", donate: bool = False):
+    """One block's jitted chain on device group ``g`` of ``topo`` — the
+    per-block dispatch unit of the serial and async executors. With more
+    than one device, the block's padded planes plus its O(K²) prior
+    summaries move to the group (the paper's phase-boundary communication,
+    made explicit); a group of >1 devices runs the chain 'data'-sharded
+    (``distributed.run_gibbs_group``), a single device runs
+    ``gibbs.run_gibbs``. Returns ``(GibbsResult, tv, tmask, n_obs)``."""
+    blk = ctx.part.block(task.i, task.j)
+    s = ctx.shapes[task.phase]
+    up, vp = ctx.priors(task)
+    csr_r, csr_c, tr, tc, tv, tmask, up, vp = PP.pad_block_inputs(
+        blk, s, ctx.cfg.K, ctx.test_p, up, vp,
+        poison_nan=ctx.should_poison(task.coord))
+    n_obs = int(tmask.sum())
+    key = ctx.keys[task.i, task.j]
+    if topo.n_devices > 1:
+        if topo.data == 1:
+            target = topo.group(g)[0]
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec
+            target = NamedSharding(topo.group_mesh_2d(g), PartitionSpec())
+        (ra, ca, tr, tc, up, vp, tv, tmask, key) = jax.device_put(
+            ((csr_r.idx, csr_r.val, csr_r.mask),
+             (csr_c.idx, csr_c.val, csr_c.mask),
+             tr, tc, up, vp, tv, tmask, key), target)
+        csr_r = PaddedCSR(*ra, n_cols=csr_r.n_cols)
+        csr_c = PaddedCSR(*ca, n_cols=csr_c.n_cols)
+    cfg = ctx.block_cfg(task)
+    if topo.data > 1:
+        from repro.core import distributed as DIST
+        csrt = (None if comm == "gather" else
+                tuple(x[0] for x in _stacked_csrt(
+                    ctx, [task], s, topo.data, scatter=(comm == "scatter"))))
+        res = DIST.run_gibbs_group(
+            key, csr_r, csr_c, jnp.asarray(tr), jnp.asarray(tc), cfg, topo,
+            group=g, U_prior=up, V_prior=vp, donate=donate, comm=comm,
+            csrt=csrt)
+    else:
+        res = GIBBS.run_gibbs(key, csr_r, csr_c, jnp.asarray(tr),
+                              jnp.asarray(tc), cfg, U_prior=up, V_prior=vp,
+                              donate=donate)
+    return res, tv, tmask, n_obs
+
+
+class _OverlappedExecutor(Executor):
+    """Base of the executors that run ``_ElasticScheduler``: the shared
+    constructor state, the completion-detection seam, and the one
+    ``run_graph``. Subclasses supply the scheduler's unit hooks and set
+    ``stage_cap`` / ``inflight_cap`` at construction."""
+    stage_cap: Optional[int] = None
+    inflight_cap: Optional[int] = None
+
+    def __init__(self, topology: Topology, donate: bool, record_trace: bool,
+                 priority: bool, depth: int, comm: str):
+        super().__init__(record_trace=record_trace)
+        if int(depth) < 1:
+            raise ValueError(f"depth must be >= 1, got {depth}")
+        self.topology = topology
+        self.donate = donate
+        self.priority = priority
+        self.depth = int(depth)
+        self.comm = comm
+        self.peak_units = 0     # staged + in-flight units, high-water mark
+
+    def run_phase(self, ctx, phase, tasks):
+        raise NotImplementedError(
+            f"{type(self).__name__} overlaps phases — it schedules whole "
+            f"graphs (run_graph), not single phases")
+
+    # -- completion-detection seam (tests fake completion order here) -----
+    def _is_resolved(self, coord: Coord, signal) -> bool:
+        return signal.is_ready()
+
+    def _reset_run_state(self):
+        super()._reset_run_state()
+        self.peak_units = 0
+
+    def _prepare(self, ctx: PhaseContext, verbose: bool):
+        """Per-run set-up before the scheduler starts."""
+
+    def run_graph(self, ctx, graph, verbose: bool = False):
+        self._reset_run_state()
+        self._prepare(ctx, verbose)
+        return _ElasticScheduler(self, ctx, graph, verbose).run()
+
+
+class AsyncExecutor(_OverlappedExecutor):
     """Dependency-driven overlapped schedule riding JAX async dispatch.
 
     Readiness counters over ``BlockTask.deps`` replace the phase barrier:
@@ -1244,9 +1752,7 @@ class AsyncExecutor(Executor):
     bucket is still running. The host never blocks on bulk results:
 
       * completion detection polls ``is_ready()`` on a per-block scalar
-        (masked Σ(pred-val)², doubling as the block's RMSE numerator) and
-        only falls back to blocking on the OLDEST in-flight scalar when
-        nothing has resolved — the device queue keeps draining either way;
+        (masked Σ(pred-val)², doubling as the block's RMSE numerator);
       * posterior summaries (trimmed device slices) go straight into the
         context store and feed successors without touching the host;
       * padded per-block input buffers are donated to XLA
@@ -1255,421 +1761,63 @@ class AsyncExecutor(Executor):
         supports it — and holding ONE block's planes at a time instead of a
         whole stacked bucket is itself the larger live-footprint cut
         (``bench_roofline --gibbs-peak`` measures both);
-      * with >1 device, ready blocks are assigned to the LEAST-LOADED
+      * with >1 device, ready blocks are staged on the LEAST-LOADED
         healthy device group (per-group streams, zero inter-group
-        collectives; priors device_put to the target group are the
-        phase-boundary O(K²) summaries — the paper's whole budget); a
-        group of >1 devices runs the block's chain 'data'-sharded
-        (``distributed.run_gibbs_group``, intra-group collectives only).
-        Each group holds at most ``depth`` blocks in flight; the rest of
-        its share stays STAGED (assigned but undispatched), which is what
-        makes the elastic layer possible: an idle group STEALS the
-        highest-priority staged block from the most-loaded group, a group
-        whose dispatches expire ``quarantine_after`` consecutive times is
-        QUARANTINED (staged share re-queued, in-flight blocks rebalanced
-        onto healthy groups under the same keys), and a straggling
-        dispatch past ``speculate_at ×`` the group's own rate estimate is
-        SPECULATIVELY twinned on an idle group — resolution commits the
-        deterministic canonical-group winner and cancels the twin, so
-        results stay bitwise identical to the fault-free run. With ONE
-        group all of this is inert and dispatch is unbounded (legacy
-        behavior).
+        collectives); a group of >1 devices runs the block's chain
+        'data'-sharded (``distributed.run_gibbs_group``, intra-group
+        collectives only).
+
+    The unit of ``_ElasticScheduler`` is one block; staging is a no-op, so
+    a group's whole assigned share sits staged (the steal pool), and with
+    several groups each holds at most ``depth`` blocks in flight — the
+    room the elastic layer (stealing, quarantine, speculation) works in.
+    With ONE group dispatch is unbounded and the elastic layer is inert.
 
     ``record_trace=True`` appends (event, coord, group) events to
-    ``self.trace`` in real order (see ``Executor`` for the schema); the
-    stress tests use it to assert no block ever dispatches before its
-    dependencies resolved. ``_is_resolved`` is the completion-detection
-    seam tests override to fake arbitrary completion orders.
-
-    ``priority=True`` (default) pops the ready queue critical-path-first:
-    ready blocks are ordered by their bottom-level (estimated cost + the
-    longest estimated chain through their successors,
-    ``critical_path_priority``), so on skewed grids the dense phase-b
-    blocks that gate whole phase-c rows/columns dispatch before the
-    near-empty stragglers. ``priority=False`` restores plain FIFO.
+    ``self.trace`` in real order (see ``Executor`` for the schema).
+    ``priority=True`` (default) pops the ready queue critical-path-first
+    (``critical_path_priority``); ``priority=False`` is plain FIFO.
     """
     name = "async"
 
-    def __init__(self, donate: bool = True, block_mesh=None,
-                 record_trace: bool = False, priority: bool = True,
-                 topology: Optional[Topology] = None, comm: str = "gather",
-                 depth: int = 2):
-        super().__init__(record_trace=record_trace)
-        if topology is None:
-            # legacy spellings: a 1-D 'block' mesh (or None = all local
-            # devices) means single-device streams
-            topology = Topology.from_spec(block_mesh)
-        elif block_mesh is not None:
-            raise ValueError("pass block_mesh OR topology, not both")
-        else:
-            topology = Topology.from_spec(topology)
-        if int(depth) < 1:
-            raise ValueError(f"depth must be >= 1, got {depth}")
-        self.topology = topology
-        self.comm = comm
-        self.donate = donate
+    def __init__(self, donate: bool = True, record_trace: bool = False,
+                 priority: bool = True, topology: Optional[Topology] = None,
+                 comm: str = "gather", depth: int = 2):
+        topology = Topology.from_spec(topology)
+        super().__init__(topology, donate, record_trace, priority, depth,
+                         comm)
         self.devices = topology.devices
-        self.priority = priority
-        self.depth = int(depth)    # per-group in-flight cap (multi-group)
-        self._n_dispatched = 0
+        self.inflight_cap = self.depth if topology.block > 1 else None
 
-    def run_phase(self, ctx, phase, tasks):
-        raise NotImplementedError(
-            "AsyncExecutor overlaps phases — it schedules whole graphs "
-            "(run_graph), not single phases")
+    def _ready_queue(self, ctx, prio, tasks):
+        return _ReadyQueue(prio)
 
-    # -- completion-detection seam (tests fake completion order here) -----
-    def _is_resolved(self, coord: Coord, signal) -> bool:
-        return signal.is_ready()
+    def _form_unit(self, ctx, ready, tasks) -> List[BlockTask]:
+        return [tasks[ready.pop()]]
 
-    def _reset_run_state(self):
-        super()._reset_run_state()
-        self._n_dispatched = 0
+    def _stage(self, ctx, tasks, group):
+        return None
 
-    def run_graph(self, ctx, graph, verbose: bool = False):
-        self._reset_run_state()
-        tasks, phase_of, waiting, succ, ready = _dep_state(
-            ctx, graph, self.priority)
-        est = _block_cost_estimates(ctx, tasks)
-        pol = ctx.policy
-        G = max(1, self.topology.block)
-        health = _GroupHealth(G, pol.quarantine_after)
-        elastic = G > 1    # one group: nowhere to rebalance/steal/twin
-        cap = self.depth if elastic else None   # per-group in-flight cap
-        # per-group staged share (assigned, undispatched — the steal pool)
-        staged = [_ReadyQueue(ready._prio) for _ in range(G)]
-        flights: Dict[Coord, List[_Flight]] = {}  # >1 = speculative twins
-        outcomes: Dict[Coord, BlockOutcome] = {}
-        spans: Dict[Coord, Tuple[float, float]] = {}
-        first_d: Dict[str, float] = {}
-        last_r: Dict[str, float] = {}
-        remaining = {ph: len(ts) for ph, ts in graph}
-        t0 = time.time()
-
-        def n_inflight(g):
-            return sum(1 for fl in flights.values()
-                       for f in fl if f.group == g)
-
-        def n_assigned(g):
-            return len(staged[g]) + n_inflight(g)
-
-        def pick_group():
-            return min(health.healthy(), key=lambda g: (n_assigned(g), g))
-
-        def deadline(c, f):
-            # per-group watchdog deadline: generous floor + slack × the
-            # group's OWN calibrated rate (EWMA seconds/cost; cold groups
-            # inherit the fastest calibrated rate, 0 until any group
-            # calibrates — early blocks get the floor alone). A false
-            # expiry is benign: re-dispatch reuses attempt-0 keys, so a
-            # slow-but-alive block still resolves bitwise-identically.
-            return (pol.timeout_floor_s
-                    + pol.timeout_slack * health.rate(f.group) * est[c])
-
-        def flight_ready(c, f):
-            if ctx.is_hung(c):
-                return False
-            if f.sup and time.time() < f.sup:
-                return False
-            return self._is_resolved(c, f.sig)
-
-        def retire(c, out, td, kind=None, group=None):
-            self._record("resolve", c, group)
-            out = _commit_guard(ctx, tasks[c], out, kind=kind)
-            tr = time.time()
-            if not out.seconds:
-                out.seconds = tr - td
-            if kind is None and group is not None:
-                # per-group EWMA rate; the group's first resolve (compile
-                # span) is dropped inside observe()
-                health.observe(group, out.seconds / est[c])
-            spans[c] = (td - t0, tr - t0)
-            outcomes[c] = out
-            ctx.note_resolved(tasks[c], out)
-            ph = phase_of[c]
-            remaining[ph] -= 1
-            last_r[ph] = tr - t0
-            if verbose and remaining[ph] == 0:
-                ts = [t for t in tasks.values() if phase_of[t.coord] == ph]
-                print(f"[pp:{self.name}] phase {ph}: {len(ts)} block(s) "
-                      f"{_phase_desc(ctx, ts)} "
-                      f"{last_r[ph] - first_d[ph]:.2f}s "
-                      f"(dispatch→resolve envelope; phases overlap)",
-                      flush=True)
-            for s in succ[c]:
-                waiting[s] -= 1
-                if waiting[s] == 0:
-                    ready.push(s)
-
-        def dispatch_on(c, g, event):
-            """Dispatch block ``c`` on group ``g``. Returns False when the
-            dispatch failed (already healed through the retire path)."""
-            self._record(event, c, g)
-            td = time.time()
-            first_d.setdefault(phase_of[c], td - t0)
-            ordinal = ctx.next_group_ordinal(g)
-            sup = ctx.group_suppressed_until(g, ordinal, td)
-            try:
-                sig, out = self._dispatch(ctx, tasks[c], group=g)
-            except _DISPATCH_ERRORS:
-                retire(c, None, td, kind="dispatch", group=g)
-                return False
-            flights.setdefault(c, []).append(
-                _Flight(sig=sig, out=out, td=td, group=g, sup=sup))
-            return True
-
-        def quarantine_group(g, trigger):
-            """Drain group ``g``: no future dispatch targets it, its
-            staged share returns to the global ready queue, and its
-            in-flight blocks rebalance onto healthy groups under the SAME
-            keys (kind="group" — no block retry budget is consumed)."""
-            health.quarantine(g)
-            self._record("quarantine", trigger, g)
-            self.n_quarantined += 1
-            ctx.record_fault(trigger, "group", "quarantined")
-            _maybe_degrade_topology(ctx, health)      # may raise (ckpt
-            while staged[g]:                          # already flushed)
-                ready.push(staged[g].pop())
-            for c2 in list(flights):
-                fl = flights.get(c2, [])
-                mine = [f for f in fl if f.group == g]
-                if not mine:
-                    continue
-                keep = [f for f in fl if f.group != g]
-                if keep:
-                    # its healthy twin flies on: this side just cancels
-                    for f in mine:
-                        self._record("cancel", c2, g)
-                        self.n_cancels += 1
-                    flights[c2] = keep
-                    continue
-                flights.pop(c2)
-                self._record("expire", c2, g)
-                ctx.record_fault(c2, "group", "rebalanced")
-                dispatch_on(c2, pick_group(), "redispatch")
-
-        def handle_expiries(now):
-            """Watchdog sweep: expire overdue flights, count consecutive
-            expiries toward quarantine, re-dispatch or terminally retire.
-            Returns True when any state changed."""
-            changed = False
-            for c in list(flights):
-                fl = flights.get(c)
-                if fl is None:
-                    continue
-                dead = [f for f in fl if now - f.td > deadline(c, f)]
-                if not dead:
-                    continue
-                changed = True
-                live = [f for f in fl if f not in dead]
-                if live:
-                    # the twin flies on — the expired side only cancels
-                    flights[c] = live
-                    for f in dead:
-                        self._record("cancel", c, f.group)
-                        self.n_cancels += 1
-                        if elastic and health.note_expiry(f.group):
-                            quarantine_group(f.group, c)
-                    continue
-                flights.pop(c)
-                self._record("expire", c, dead[0].group)
-                for f in dead[1:]:
-                    self._record("cancel", c, f.group)
-                    self.n_cancels += 1
-                for f in dead:
-                    if elastic and health.note_expiry(f.group):
-                        quarantine_group(f.group, c)
-                if ctx.cur_attempt(c) < pol.max_retries:
-                    ctx.record_fault(c, "timeout", "redispatched")
-                    ctx.attempts[c] = ctx.cur_attempt(c) + 1
-                    dispatch_on(c, pick_group(), "redispatch")
-                else:
-                    retire(c, None, dead[0].td, kind="timeout",
-                           group=dead[0].group)
-            return changed
-
-        def maybe_speculate(now):
-            """Straggler hedge: a sole flight past ``speculate_at ×`` its
-            group's calibrated deadline model is twinned on an idle
-            healthy group with the SAME attempt-0 key."""
-            if not elastic or pol.speculate_at <= 0.0:
-                return
-            for c in list(flights):
-                fl = flights.get(c)
-                if fl is None or len(fl) != 1:
-                    continue
-                f = fl[0]
-                r = health.rate(f.group)
-                if r <= 0.0 or now - f.td <= pol.speculate_at * r * est[c]:
-                    continue
-                idle = [g for g in health.healthy()
-                        if g != f.group and not staged[g]
-                        and (cap is None or n_inflight(g) < cap)]
-                if not idle:
-                    continue
-                g2 = min(idle, key=lambda g: (n_assigned(g), g))
-                td = time.time()
-                ordinal = ctx.next_group_ordinal(g2)
-                sup = ctx.group_suppressed_until(g2, ordinal, td)
-                try:
-                    sig, out = self._dispatch(ctx, tasks[c], group=g2)
-                except _DISPATCH_ERRORS:
-                    continue    # the primary still flies; skip the twin
-                self._record("speculate", c, g2)
-                self.n_speculations += 1
-                fl.append(_Flight(sig=sig, out=out, td=td, group=g2,
-                                  sup=sup))
-
-        def await_progress():
-            """Adaptive-sleep poll until a flight resolves or the watchdog
-            changes state (expiry/quarantine). ``watchdog=False`` restores
-            the legacy block-on-oldest fallback, which deadlocks if the
-            oldest in-flight block died — keep it on."""
-            if not pol.watchdog:
-                c0 = min(flights, key=lambda c: flights[c][0].td)
-                jax.block_until_ready(flights[c0][0].sig)
-                return
-            sleep = 5e-5
-            while flights:
-                if any(flight_ready(c, f) for c, fl in flights.items()
-                       for f in fl):
-                    return
-                now = time.time()
-                if handle_expiries(now):
-                    return
-                maybe_speculate(now)
-                time.sleep(sleep)
-                sleep = min(sleep * 2, 2e-3)
-
-        while ready or any(staged) or flights:
-            # assign fresh ready blocks to the least-loaded healthy group
-            while ready:
-                staged[pick_group()].push(ready.pop())
-            progress = False
-            for g in health.healthy():
-                while staged[g] and (cap is None or n_inflight(g) < cap):
-                    dispatch_on(staged[g].pop(), g, "dispatch")
-                    progress = True
-            if elastic and not progress:
-                # work stealing: an idle healthy group takes the highest-
-                # priority STAGED block from the most-loaded group
-                for g in health.healthy():
-                    if staged[g] or (cap is not None
-                                     and n_inflight(g) >= cap):
-                        continue
-                    victims = [h for h in health.healthy()
-                               if h != g and staged[h]]
-                    if not victims:
-                        continue
-                    v = max(victims, key=lambda h: (n_assigned(h), -h))
-                    c = staged[v].pop()
-                    self._record("steal", c, g)
-                    self.n_steals += 1
-                    dispatch_on(c, g, "dispatch")
-                    progress = True
-            if progress or not flights:
-                continue
-            await_progress()
-            resolved = [c for c, fl in flights.items()
-                        if any(flight_ready(c, f) for f in fl)]
-            for c in resolved:
-                fl = flights.pop(c, None)
-                if fl is None:
-                    continue
-                rd = [f for f in fl if flight_ready(c, f)]
-                if not rd:
-                    flights[c] = fl
-                    continue
-                # deterministic winner: canonical group order among the
-                # READY flights — twins share the attempt-0 key so either
-                # outcome is bitwise the fault-free numbers, and the
-                # canonical rule keeps the committed handles/trace
-                # independent of wall-clock completion order
-                win = min(rd, key=lambda f: f.group)
-                for f in fl:
-                    if f is not win:
-                        self._record("cancel", c, f.group)
-                        self.n_cancels += 1
-                # the store may hold a losing twin's handles (written at
-                # its dispatch) — successors must consume the winner's
-                ctx.U_posts[c] = win.out.U_post
-                ctx.V_posts[c] = win.out.V_post
-                health.note_resolve(win.group)
-                retire(c, win.out, win.td, group=win.group)
-        # per-phase envelopes: first dispatch → last resolve. Phases
-        # overlap, so these may sum to MORE than the wall time.
-        phase_times = {ph: last_r[ph] - first_d[ph] for ph in first_d}
-        return outcomes, phase_times, spans
-
-    def _dispatch(self, ctx: PhaseContext, task: BlockTask,
-                  group: Optional[int] = None):
-        """Dispatch one block's jitted chain without waiting for anything:
-        inputs may still be computing (JAX chains the dataflow) and no
-        output is synced. ``group`` is the scheduler-chosen target device
-        group (None = legacy round-robin). Returns (completion scalar,
-        device outcome)."""
-        ctx.check_dispatch(task.coord)
+    def _dispatch(self, ctx: PhaseContext, unit: _Unit):
+        """Dispatch one block's jitted chain on the unit's group without
+        waiting for anything: inputs may still be computing (JAX chains
+        the dataflow) and no output is synced. Returns (completion scalar,
+        {coord: device outcome})."""
+        (task,) = unit.tasks
+        res, tv, tmask, n_obs = _group_chain(
+            ctx, task, self.topology, unit.group, self.comm, self.donate)
         blk = ctx.part.block(task.i, task.j)
-        s = ctx.shapes[task.phase]
-        up, vp = ctx.priors(task)
-        csr_r, csr_c, tr, tc, tv, tmask, up, vp = PP.pad_block_inputs(
-            blk, s, ctx.cfg.K, ctx.test_p, up, vp,
-            poison_nan=ctx.should_poison(task.coord))
-        n_obs = int(tmask.sum())
-        key = ctx.keys[task.i, task.j]
-        topo = self.topology
-        g = (self._n_dispatched % topo.block) if group is None \
-            else int(group)
-        if topo.n_devices > 1:
-            # per-GROUP streams: the block's padded planes plus the O(K²)
-            # prior summaries move to the target group — the latter IS the
-            # paper's phase-boundary communication, made explicit. With
-            # data == 1 a group is the single device of the legacy
-            # round-robin; with data > 1 the planes are replicated across
-            # the group and the chain shards its sweep over them.
-            if topo.data == 1:
-                target = topo.group(g)[0]
-            else:
-                from jax.sharding import NamedSharding, PartitionSpec
-                target = NamedSharding(topo.group_mesh_2d(g),
-                                       PartitionSpec())
-            (ra, ca, tr, tc, up, vp, tv, tmask, key) = jax.device_put(
-                ((csr_r.idx, csr_r.val, csr_r.mask),
-                 (csr_c.idx, csr_c.val, csr_c.mask),
-                 tr, tc, up, vp, tv, tmask, key), target)
-            csr_r = PaddedCSR(*ra, n_cols=csr_r.n_cols)
-            csr_c = PaddedCSR(*ca, n_cols=csr_c.n_cols)
-        self._n_dispatched += 1
-        if topo.data > 1:
-            from repro.core import distributed as DIST
-            csrt = (None if self.comm == "gather" else
-                    tuple(x[0] for x in _stacked_csrt(
-                        ctx, [task], s, topo.data,
-                        scatter=(self.comm == "scatter"))))
-            res = DIST.run_gibbs_group(
-                key, csr_r, csr_c, jnp.asarray(tr), jnp.asarray(tc),
-                ctx.block_cfg(task), topo, group=g, U_prior=up, V_prior=vp,
-                donate=self.donate, comm=self.comm, csrt=csrt)
-        else:
-            res = GIBBS.run_gibbs(key, csr_r, csr_c,
-                                  jnp.asarray(tr), jnp.asarray(tc),
-                                  ctx.block_cfg(task), U_prior=up,
-                                  V_prior=vp, donate=self.donate)
         nr, nc = len(blk.row_ids), len(blk.col_ids)
-        U_post = RowGaussians(eta=res.U_post.eta[:nr],
-                              Lambda=res.U_post.Lambda[:nr])
-        V_post = RowGaussians(eta=res.V_post.eta[:nc],
-                              Lambda=res.V_post.Lambda[:nc])
         sq = _block_sq_err(res.acc.pred_sum, res.acc.pred_cnt,
                            jnp.asarray(tv), jnp.asarray(tmask))
-        # device-resident store write happens AT DISPATCH: successors (and
-        # the final jitted aggregation) consume these handles as dataflow
-        ctx.U_posts[task.coord] = U_post
-        ctx.V_posts[task.coord] = V_post
-        out = BlockOutcome(U_post=U_post, V_post=V_post,
-                           pred_mean=None, seconds=0.0,
-                           sq_err=sq, n_obs=n_obs, health=res.health)
-        return sq, out
+        out = BlockOutcome(
+            U_post=RowGaussians(eta=res.U_post.eta[:nr],
+                                Lambda=res.U_post.Lambda[:nr]),
+            V_post=RowGaussians(eta=res.V_post.eta[:nc],
+                                Lambda=res.V_post.Lambda[:nc]),
+            pred_mean=None, seconds=0.0, sq_err=sq, n_obs=n_obs,
+            health=res.health)
+        return sq, {task.coord: out}
 
 
 # Per-block masked Σ(pred-val)² over a (W, n_test) window chunk — the SAME
@@ -1691,7 +1839,6 @@ def _dummy_prior(n: int, K: int) -> RowGaussians:
 class _StagedChunk:
     """A window chunk whose host→device transfer has been issued (the
     prefetch): device leaves + per-block metadata, waiting to dispatch."""
-    tasks: List[BlockTask]        # true tasks, ≤ W (repeat-padded to W)
     shape: "PP.BlockShapes"
     cfg: BMF.BMFConfig
     dev: Tuple                    # (ri, rv, rm, ci, cv, cm, tr, tc, tv, tm)
@@ -1701,37 +1848,36 @@ class _StagedChunk:
     u_use: jax.Array              # (W,) {0,1} prior flags
     v_use: jax.Array
     n_obs: List[int]
-    group: int = 0                # topology device group this chunk targets
 
 
-class StreamingExecutor(Executor):
+class StreamingExecutor(_OverlappedExecutor):
     """Bounded-window streaming schedule for out-of-memory block grids.
 
     The stacked executor materializes a whole phase bucket on device at
     once — ``num_blocks_in_bucket × block_bytes`` — which web-scale grids
     (thousands of blocks) cannot co-resident in HBM. This executor runs the
-    SAME dependency-driven ready queue as the async scheduler but moves
-    blocks through a bounded window of ``W`` donated block buffers:
+    SAME elastic scheduler as the async executor but moves blocks through a
+    bounded window of ``W`` donated block buffers:
 
-      * ready blocks are popped critical-path-first (``_ReadyQueue`` over
-        ``critical_path_priority``) and grouped into chunks of up to W
-        blocks sharing one window shape and chain config (short chunks are
-        repeat-padded to exactly W so ONE executable serves every chunk);
-      * each chunk's CSR planes/test entries are assembled on the HOST
-        (``pp.pad_block_inputs_host``) and shipped with one async
-        ``device_put`` — the double-buffered prefetch: the next chunk's
-        H2D transfer runs while the current chunk computes;
+      * the unit is a chunk: the top-priority ready block plus up to W-1
+        more sharing its window shape and chain config
+        (``_GroupedReadyQueue``); short chunks are repeat-padded to exactly
+        W so ONE executable serves every chunk;
+      * staging a chunk assembles its CSR planes/test entries on the HOST
+        (``pp.pad_block_inputs_host``) and ships them with one async
+        ``device_put`` onto the chunk's group. Each group holds ONE staged
+        chunk — the double-buffered prefetch: the next chunk's H2D
+        transfer runs while the current chunk computes;
       * chunks dispatch through ``gibbs.run_gibbs_stacked(donate=True)``:
         XLA recycles the window buffers (U0/V0 alias the U/V outputs, the
-        planes return to the allocator), so the live input footprint is
-        ``≤ W × (depth + 1) × block_bytes`` — flat in the grid size
-        (``peak_window_blocks`` records the realized bound;
+        planes return to the allocator), so with at most ``depth`` chunks
+        in flight per group the live input footprint is
+        ``≤ W × (depth + 1) × block_bytes`` per group — flat in the grid
+        size (``peak_window_blocks`` records the realized bound;
         ``bench_roofline --gibbs-peak`` measures it);
       * completion is detected by non-blocking ``is_ready()`` polls on each
-        chunk's (W,) squared-error vector, falling back to blocking on the
-        OLDEST in-flight chunk only — same contract as the async executor,
-        and the same ``_is_resolved`` seam for the conformance fake-delay
-        stress;
+        chunk's (W,) squared-error vector — the same ``_is_resolved``
+        seam as the async executor;
       * per-phase shape buckets are COALESCED first
         (``pp.BlockShapes.coalesce`` / ``partition.coalesce_shapes``):
         buckets within the waste budget share one window shape, and the
@@ -1739,10 +1885,11 @@ class StreamingExecutor(Executor):
         that single executable serve phase-a/b/c blocks despite their
         different prior structures.
 
-    Per-block chains are the stacked executor's vmapped semantics (same
-    keys, same padding), so RMSE matches serial to batched-fp tolerance
-    and results are bit-identical across runs regardless of how completion
-    timing regroups the chunks.
+    A chunk is the fault domain: its members time out, re-dispatch and
+    are stolen or twinned together. Per-block chains are the stacked
+    executor's vmapped semantics (same keys, same padding), so RMSE
+    matches serial to batched-fp tolerance and results are bit-identical
+    across runs regardless of how completion timing regroups the chunks.
 
     ``max_waste`` defaults to 1.0 — only bit-identical shapes merge, which
     preserves exact chain parity with the serial/stacked reference (the
@@ -1752,23 +1899,14 @@ class StreamingExecutor(Executor):
     results remain valid Gibbs chains, just not the reference's draws.
     """
     name = "streaming"
+    stage_cap = 1             # one prefetched chunk per group
 
     def __init__(self, window: int = 4, donate: bool = True,
                  max_waste: float = 1.0, priority: bool = True,
                  depth: int = 2, record_trace: bool = False,
                  topology: Optional[Topology] = None, comm: str = "gather"):
-        super().__init__(record_trace=record_trace)
         if int(window) < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-        if int(depth) < 1:
-            raise ValueError(f"depth must be >= 1, got {depth}")
-        self.window = int(window)
-        self.donate = donate
-        self.max_waste = max_waste
-        self.priority = priority
-        self.depth = int(depth)               # in-flight chunks before block
-        self.topology = Topology.from_spec(topology) if topology is not None \
-            else Topology(block=1, data=1)
         if comm != "gather":
             # window chunks use prior_use-flagged executables; only the
             # 'gather' intra-group exchange composes with them (and at
@@ -1776,27 +1914,46 @@ class StreamingExecutor(Executor):
             raise ValueError(
                 f"streaming executor supports comm='gather' only, "
                 f"got {comm!r}")
-        self.comm = comm
-        if self.topology.n_devices > 1:
-            self.devices = self.topology.devices
-        self.peak_window_blocks = 0           # realized live-buffer bound
+        topology = (Topology.from_spec(topology) if topology is not None
+                    else Topology(block=1, data=1))
+        super().__init__(topology, donate, record_trace, priority, depth,
+                         comm)
+        self.window = int(window)
+        self.max_waste = max_waste
+        self.inflight_cap = self.depth
+        if topology.n_devices > 1:
+            self.devices = topology.devices
         self.window_shapes: Optional[Dict[str, "PP.BlockShapes"]] = None
 
-    def run_phase(self, ctx, phase, tasks):
-        raise NotImplementedError(
-            "StreamingExecutor streams whole graphs through its window "
-            "(run_graph), not single phases")
+    @property
+    def peak_window_blocks(self) -> int:
+        """Realized live-buffer bound of the last run, in blocks: staged
+        plus in-flight chunks, W blocks each."""
+        return self.window * self.peak_units
 
-    # -- completion-detection seam (tests fake completion order here) -----
-    def _is_resolved(self, coord: Coord, signal) -> bool:
-        return signal.is_ready()
+    def _reset_run_state(self):
+        super()._reset_run_state()
+        self.window_shapes = None
 
-    def _group_key(self, ctx, task, shapes):
-        cfg = ctx.block_cfg(task)
-        return (id(shapes[task.phase]), cfg.n_samples, cfg.burnin)
+    def _prepare(self, ctx, verbose):
+        self.window_shapes = PP.BlockShapes.coalesce(ctx.shapes, ctx.cfg.K,
+                                                     self.max_waste)
+        if verbose:
+            n_buckets = len({id(s) for s in self.window_shapes.values()})
+            print(f"[pp:{self.name}] window={self.window} depth={self.depth} "
+                  f"{n_buckets} coalesced bucket(s) over "
+                  f"{len(self.window_shapes)} phase tag(s), "
+                  f"{self.topology.block} stream group(s) x "
+                  f"{self.topology.data} device(s)", flush=True)
 
-    def _pop_chunk(self, ctx, ready: _GroupedReadyQueue,
-                   tasks) -> List[BlockTask]:
+    def _ready_queue(self, ctx, prio, tasks):
+        def chunk_key(c):
+            cfg = ctx.block_cfg(tasks[c])
+            return (id(self.window_shapes[tasks[c].phase]), cfg.n_samples,
+                    cfg.burnin)
+        return _GroupedReadyQueue(prio, chunk_key)
+
+    def _form_unit(self, ctx, ready, tasks) -> List[BlockTask]:
         """Up to W ready blocks sharing the top-priority block's window
         shape and chain config — priority order within the group."""
         return [tasks[c] for c in ready.pop_chunk(self.window)]
@@ -1815,13 +1972,13 @@ class StreamingExecutor(Executor):
                              PartitionSpec())
 
     def _stage(self, ctx: PhaseContext, chunk: List[BlockTask],
-               shapes, group: int = 0) -> _StagedChunk:
+               group: int) -> _StagedChunk:
         """Assemble one chunk on the host and issue its (async) H2D
         transfer to the target group. Deps are resolved (the chunk came
         off the ready queue), so the device-resident priors are read here
         too — moving them to the group is the phase-boundary O(K²)
         communication, made explicit."""
-        s = shapes[chunk[0].phase]
+        s = self.window_shapes[chunk[0].phase]
         K = ctx.cfg.K
         W = self.window
         sel = list(range(len(chunk))) + [len(chunk) - 1] * (W - len(chunk))
@@ -1864,16 +2021,17 @@ class StreamingExecutor(Executor):
             # summaries and keys with the chunk's window buffers
             U_pri, V_pri, keys = jax.device_put((U_pri, V_pri, keys), target)
         return _StagedChunk(
-            tasks=chunk, shape=s, cfg=ctx.block_cfg(chunk[0]), dev=dev,
+            shape=s, cfg=ctx.block_cfg(chunk[0]), dev=dev,
             keys=keys, U_prior=U_pri, V_prior=V_pri,
             u_use=jnp.asarray([uf[i] for i in sel], jnp.float32),
             v_use=jnp.asarray([vf[i] for i in sel], jnp.float32),
-            n_obs=[int(h[5].sum()) for h in host], group=group)
+            n_obs=[int(h[5].sum()) for h in host])
 
-    def _dispatch(self, ctx: PhaseContext, st: _StagedChunk):
+    def _dispatch(self, ctx: PhaseContext, unit: _Unit):
         """Dispatch one staged chunk; returns (signal, outcomes). The
         window buffers are donated — after this call nothing holds them
         and XLA recycles their storage for the next chunk."""
+        st = unit.staged
         ri, rv, rm, ci, cv, cm, tr, tc, tv, tmask = st.dev
         csr_r = PaddedCSR(ri, rv, rm, n_cols=st.shape.n_cols)
         csr_c = PaddedCSR(ci, cv, cm, n_cols=st.shape.n_rows)
@@ -1884,7 +2042,7 @@ class StreamingExecutor(Executor):
                 U_prior=st.U_prior, V_prior=st.V_prior,
                 prior_use=(st.u_use, st.v_use), donate=self.donate,
                 comm=self.comm,
-                mesh=self.topology.group_mesh_2d(st.group))
+                mesh=self.topology.group_mesh_2d(unit.group))
         else:
             res = GIBBS.run_gibbs_stacked(
                 st.keys, csr_r, csr_c, tr, tc, st.cfg,
@@ -1892,369 +2050,18 @@ class StreamingExecutor(Executor):
                 prior_use=(st.u_use, st.v_use), donate=self.donate)
         sq = _chunk_sq_err(res.acc.pred_sum, res.acc.pred_cnt, tv, tmask)
         outs: Dict[Coord, BlockOutcome] = {}
-        for b, t in enumerate(st.tasks):      # padded duplicates dropped
+        for b, t in enumerate(unit.tasks):      # padded duplicates dropped
             blk = ctx.part.block(t.i, t.j)
             nr, nc = len(blk.row_ids), len(blk.col_ids)
-            U_post = RowGaussians(eta=res.U_post.eta[b, :nr],
-                                  Lambda=res.U_post.Lambda[b, :nr])
-            V_post = RowGaussians(eta=res.V_post.eta[b, :nc],
-                                  Lambda=res.V_post.Lambda[b, :nc])
-            ctx.U_posts[t.coord] = U_post
-            ctx.V_posts[t.coord] = V_post
             outs[t.coord] = BlockOutcome(
-                U_post=U_post, V_post=V_post, pred_mean=None, seconds=0.0,
+                U_post=RowGaussians(eta=res.U_post.eta[b, :nr],
+                                    Lambda=res.U_post.Lambda[b, :nr]),
+                V_post=RowGaussians(eta=res.V_post.eta[b, :nc],
+                                    Lambda=res.V_post.Lambda[b, :nc]),
+                pred_mean=None, seconds=0.0,
                 sq_err=sq[b], n_obs=st.n_obs[b],
                 health=(res.health[b] if res.health is not None else None))
         return sq, outs
-
-    def _reset_run_state(self):
-        super()._reset_run_state()
-        self.peak_window_blocks = 0
-        self.window_shapes = None
-
-    def run_graph(self, ctx, graph, verbose: bool = False):
-        self._reset_run_state()
-        shapes = PP.BlockShapes.coalesce(ctx.shapes, ctx.cfg.K,
-                                         self.max_waste)
-        tasks, phase_of, waiting, succ, ready = _dep_state(
-            ctx, graph, self.priority,
-            make_queue=lambda prio, ts: _GroupedReadyQueue(
-                prio, lambda c: self._group_key(ctx, ts[c], shapes)))
-        self.window_shapes = shapes
-        G = self.topology.block
-        pol = ctx.policy
-        health = _GroupHealth(G, pol.quarantine_after)
-        elastic = G > 1    # one group: nowhere to rebalance/steal/twin
-        if verbose:
-            n_buckets = len({id(s) for s in shapes.values()})
-            print(f"[pp:{self.name}] window={self.window} depth={self.depth} "
-                  f"{n_buckets} coalesced bucket(s) over {len(shapes)} phase "
-                  f"tag(s), {G} stream group(s) x {self.topology.data} "
-                  f"device(s)", flush=True)
-
-        # one W-bounded donated window PER DEVICE GROUP: each group runs
-        # its own stream of chunks (own prefetch slot + its share of the
-        # in-flight chunk flights, capped at ``depth``)
-        staged: List[Optional[_StagedChunk]] = [None] * G
-        flights: Dict[int, _Flight] = {}    # flight id -> chunk flight
-        twin: Dict[int, int] = {}           # speculative twin links (both ways)
-        fid_next = [0]
-        outcomes: Dict[Coord, BlockOutcome] = {}
-        spans: Dict[Coord, Tuple[float, float]] = {}
-        first_d: Dict[str, float] = {}
-        last_r: Dict[str, float] = {}
-        remaining = {ph: len(ts) for ph, ts in graph}
-        t0 = time.time()
-
-        def n_inflight(g):
-            return sum(1 for f in flights.values() if f.group == g)
-
-        def note_peak():
-            live = self.window * (len(flights)
-                                  + sum(st is not None for st in staged))
-            self.peak_window_blocks = max(self.peak_window_blocks, live)
-
-        est = _block_cost_estimates(ctx, tasks)
-
-        def chunk_cost(ts_):
-            return sum(est[t.coord] for t in ts_)
-
-        def deadline(f):
-            # per-group watchdog deadline over the chunk's total estimated
-            # cost (one executable runs all its members); the group's OWN
-            # EWMA rate, cold groups inherit the fastest calibrated one
-            return (pol.timeout_floor_s + pol.timeout_slack
-                    * health.rate(f.group) * chunk_cost(f.tasks))
-
-        def flight_ready(f):
-            if any(ctx.is_hung(t.coord) for t in f.tasks):
-                return False
-            if f.sup and time.time() < f.sup:
-                return False
-            return self._is_resolved(f.tasks[0].coord, f.sig)
-
-        def retire(t, out, td, tr_, per, kind=None, group=None):
-            c = t.coord
-            self._record("resolve", c, group)
-            out = _commit_guard(ctx, tasks[c], out, kind=kind)
-            if not out.seconds:
-                out.seconds = per
-            spans[c] = (td - t0, tr_ - t0)
-            outcomes[c] = out
-            ctx.note_resolved(tasks[c], out)
-            ph = phase_of[c]
-            remaining[ph] -= 1
-            last_r[ph] = tr_ - t0
-            if verbose and remaining[ph] == 0:
-                ts2 = [t2 for t2 in tasks.values()
-                       if phase_of[t2.coord] == ph]
-                print(f"[pp:{self.name}] phase {ph}: {len(ts2)} "
-                      f"block(s) {_phase_desc(ctx, ts2)} "
-                      f"{last_r[ph] - first_d[ph]:.2f}s "
-                      f"(dispatch→resolve envelope; phases overlap)",
-                      flush=True)
-            for s2 in succ[c]:
-                waiting[s2] -= 1
-                if waiting[s2] == 0:
-                    ready.push(s2)
-
-        def launch(ch: _StagedChunk, event: str) -> int:
-            """Dispatch a staged chunk on its group; returns the flight
-            id. The chunk's dispatch consumes one group ordinal (the
-            group-level injection unit)."""
-            g = ch.group
-            td = time.time()
-            for t in ch.tasks:
-                self._record(event, t.coord, g)
-                first_d.setdefault(phase_of[t.coord], td - t0)
-            ordinal = ctx.next_group_ordinal(g)
-            sup = ctx.group_suppressed_until(g, ordinal, td)
-            sig, outs = self._dispatch(ctx, ch)
-            fid = fid_next[0]
-            fid_next[0] += 1
-            flights[fid] = _Flight(sig=sig, out=outs, td=td, group=g,
-                                   sup=sup, tasks=ch.tasks)
-            note_peak()
-            return fid
-
-        def least_loaded():
-            return min(health.healthy(), key=lambda g: (n_inflight(g), g))
-
-        def stage_next(g) -> Optional[_StagedChunk]:
-            """Pop + stage the group's next chunk, healing dispatch-failure
-            injections at chunk formation (the flagged block never joins
-            the window; the rest of the chunk is unaffected)."""
-            while ready:
-                chunk = self._pop_chunk(ctx, ready, tasks)
-                good = []
-                for t in chunk:
-                    try:
-                        ctx.check_dispatch(t.coord)
-                        good.append(t)
-                    except _DISPATCH_ERRORS:
-                        self._record("dispatch", t.coord, g)
-                        now = time.time()
-                        first_d.setdefault(phase_of[t.coord], now - t0)
-                        retire(t, None, now, time.time(), 0.0,
-                               kind="dispatch", group=g)
-                if good:
-                    return self._stage(ctx, good, shapes, group=g)
-            return None
-
-        def quarantine_group(g, trigger):
-            """Drain group ``g``: its staged window buffers are RELEASED
-            (the chunk's blocks return to the ready queue, dropping the
-            device leaves), and its in-flight chunks re-stage on healthy
-            groups under the same keys (kind="group" — no block retry
-            budget consumed)."""
-            health.quarantine(g)
-            self._record("quarantine", trigger, g)
-            self.n_quarantined += 1
-            ctx.record_fault(trigger, "group", "quarantined")
-            _maybe_degrade_topology(ctx, health)      # may raise (ckpt
-            if staged[g] is not None:                 # already flushed)
-                for t in staged[g].tasks:
-                    ready.push(t.coord)
-                staged[g] = None
-            for fid in [i for i, f in flights.items() if f.group == g]:
-                f = flights.pop(fid)
-                tw = twin.pop(fid, None)
-                if tw is not None:
-                    # its healthy twin flies on: this side just cancels
-                    twin.pop(tw, None)
-                    for t in f.tasks:
-                        self._record("cancel", t.coord, g)
-                    self.n_cancels += len(f.tasks)
-                    continue
-                for t in f.tasks:
-                    self._record("expire", t.coord, g)
-                    ctx.record_fault(t.coord, "group", "rebalanced")
-                st2 = self._stage(ctx, f.tasks, shapes,
-                                  group=least_loaded())
-                launch(st2, "redispatch")
-
-        def handle_expiries(now):
-            """Watchdog sweep over the chunk flights; True on any state
-            change (expiry, quarantine, redispatch, terminal retire)."""
-            changed = False
-            for fid in list(flights):
-                f = flights.get(fid)
-                if f is None or now - f.td <= deadline(f):
-                    continue
-                changed = True
-                flights.pop(fid)
-                tw = twin.pop(fid, None)
-                if tw is not None and tw in flights:
-                    # the twin flies on — the expired side only cancels
-                    twin.pop(tw, None)
-                    for t in f.tasks:
-                        self._record("cancel", t.coord, f.group)
-                    self.n_cancels += len(f.tasks)
-                    if elastic and health.note_expiry(f.group):
-                        quarantine_group(f.group, f.tasks[0].coord)
-                    continue
-                for t in f.tasks:
-                    self._record("expire", t.coord, f.group)
-                if elastic and health.note_expiry(f.group):
-                    quarantine_group(f.group, f.tasks[0].coord)
-                if all(ctx.cur_attempt(t.coord) < pol.max_retries
-                       for t in f.tasks):
-                    # re-stage on the least-loaded healthy group with the
-                    # same keys — a slow-but-alive chunk re-resolves to
-                    # bitwise-identical numbers
-                    for t in f.tasks:
-                        ctx.record_fault(t.coord, "timeout", "redispatched")
-                        ctx.attempts[t.coord] = ctx.cur_attempt(t.coord) + 1
-                    st2 = self._stage(ctx, f.tasks, shapes,
-                                      group=least_loaded())
-                    launch(st2, "redispatch")
-                else:
-                    for t in f.tasks:
-                        retire(t, None, f.td, now, 0.0, kind="timeout",
-                               group=f.group)
-            return changed
-
-        def maybe_speculate(now):
-            """Straggler hedge: an untwinned chunk past ``speculate_at ×``
-            its group's calibrated deadline model re-stages on an idle
-            healthy group with the SAME keys."""
-            if not elastic or pol.speculate_at <= 0.0:
-                return
-            for fid in list(flights):
-                f = flights.get(fid)
-                if f is None or fid in twin:
-                    continue
-                r = health.rate(f.group)
-                if (r <= 0.0 or now - f.td
-                        <= pol.speculate_at * r * chunk_cost(f.tasks)):
-                    continue
-                idle = [g for g in health.healthy()
-                        if g != f.group and staged[g] is None
-                        and n_inflight(g) < self.depth]
-                if not idle:
-                    continue
-                g2 = min(idle, key=lambda g: (n_inflight(g), g))
-                for t in f.tasks:
-                    self._record("speculate", t.coord, g2)
-                self.n_speculations += len(f.tasks)
-                try:
-                    st2 = self._stage(ctx, f.tasks, shapes, group=g2)
-                    td = time.time()
-                    ordinal = ctx.next_group_ordinal(g2)
-                    sup = ctx.group_suppressed_until(g2, ordinal, td)
-                    sig, outs = self._dispatch(ctx, st2)
-                except _DISPATCH_ERRORS:
-                    for t in f.tasks:
-                        self._record("cancel", t.coord, g2)
-                    self.n_cancels += len(f.tasks)
-                    continue    # the primary still flies; skip the twin
-                fid2 = fid_next[0]
-                fid_next[0] += 1
-                flights[fid2] = _Flight(sig=sig, out=outs, td=td, group=g2,
-                                        sup=sup, tasks=f.tasks)
-                twin[fid] = fid2
-                twin[fid2] = fid
-                note_peak()
-
-        def await_flights():
-            """Adaptive poll until a chunk resolves or the watchdog
-            changes state; ``watchdog=False`` restores the legacy
-            block-on-oldest-chunk fallback."""
-            if not pol.watchdog:
-                f0 = min(flights.values(), key=lambda f: f.td)
-                jax.block_until_ready(f0.sig)
-                return
-            sleep = 5e-5
-            while flights:
-                if any(flight_ready(f) for f in flights.values()):
-                    return
-                now = time.time()
-                if handle_expiries(now):
-                    return
-                maybe_speculate(now)
-                time.sleep(sleep)
-                sleep = min(sleep * 2, 2e-3)
-
-        while (ready or any(st is not None for st in staged) or flights):
-            progress = False
-            for g in health.healthy():
-                # fair staging: every idle group stages ONE chunk before
-                # any group prefetches a second — a greedy first group
-                # would starve the rest of the mesh whenever the DAG
-                # releases blocks a few at a time
-                if staged[g] is None and ready:
-                    staged[g] = stage_next(g)
-                    note_peak()
-            for g in health.healthy():
-                if staged[g] is not None and n_inflight(g) < self.depth:
-                    ch, staged[g] = staged[g], None
-                    launch(ch, "dispatch")
-                    # per-stream double-buffered prefetch: the group's NEXT
-                    # chunk's H2D transfer overlaps this chunk's compute
-                    if ready:
-                        staged[g] = stage_next(g)
-                        note_peak()
-                    progress = True
-            if elastic and not progress:
-                # work stealing: an idle healthy group re-stages the
-                # staged chunk of the most-loaded group onto itself
-                for g in health.healthy():
-                    if (staged[g] is not None or ready
-                            or n_inflight(g) >= self.depth):
-                        continue
-                    victims = [h for h in health.healthy()
-                               if h != g and staged[h] is not None]
-                    if not victims:
-                        continue
-                    v = max(victims, key=lambda h: (n_inflight(h), -h))
-                    ch, staged[v] = staged[v], None
-                    for t in ch.tasks:
-                        self._record("steal", t.coord, g)
-                    self.n_steals += len(ch.tasks)
-                    st2 = self._stage(ctx, ch.tasks, shapes, group=g)
-                    launch(st2, "dispatch")
-                    progress = True
-            if progress or not flights:
-                continue
-            await_flights()
-            for fid in [i for i, f in flights.items() if flight_ready(f)]:
-                f = flights.get(fid)
-                if f is None:       # its twin already committed this work
-                    continue
-                tw = twin.pop(fid, None)
-                if tw is not None and tw in flights:
-                    twin.pop(tw, None)
-                    # deterministic winner: canonical group order among
-                    # the READY sides (twins share keys, so either is the
-                    # fault-free bitwise result)
-                    cand = [x for x in (fid, tw)
-                            if flights.get(x) is not None
-                            and flight_ready(flights[x])]
-                    win_id = min(cand, key=lambda x: flights[x].group)
-                    lose_id = tw if win_id == fid else fid
-                    loser = flights.pop(lose_id)
-                    for t in loser.tasks:
-                        self._record("cancel", t.coord, loser.group)
-                    self.n_cancels += len(loser.tasks)
-                    f = flights.pop(win_id)
-                    # successors must consume the winner's dataflow, not
-                    # whichever twin wrote the store last
-                    for t in f.tasks:
-                        ctx.U_posts[t.coord] = f.out[t.coord].U_post
-                        ctx.V_posts[t.coord] = f.out[t.coord].V_post
-                else:
-                    flights.pop(fid)
-                tr_ = time.time()
-                # one executable ran the whole chunk: split its wall evenly
-                # across members (mirrors StackedExecutor's bucket split)
-                per = (tr_ - f.td) / len(f.tasks)
-                health.observe(f.group, (tr_ - f.td) / chunk_cost(f.tasks))
-                health.note_resolve(f.group)
-                for t in f.tasks:
-                    retire(t, f.out[t.coord], f.td, tr_, per,
-                           group=f.group)
-        phase_times = {ph: last_r[ph] - first_d[ph] for ph in first_d}
-        return outcomes, phase_times, spans
 
 
 EXECUTORS: Dict[str, type] = {
@@ -2273,19 +2080,15 @@ must accept ``record_trace=`` and report dispatch/resolve events honestly.
 """
 
 
-def make_executor(spec, distributed_mesh=None, block_mesh=None,
-                  window=None, topology=None) -> Executor:
+def make_executor(spec, window=None, topology=None) -> Executor:
     """Resolve run_pp's ``executor=`` argument: a registry name or an
-    instance. ``topology`` is the unified 2-D ('block', 'data') placement
-    (core.topology.Topology, an ``(block, data)`` pair, or a legacy 1-D
-    mesh) consumed by the serial (block must be 1), sharded, async, and
-    streaming executors. An intra-block ``distributed_mesh`` is the legacy
-    spelling of ``topology=Topology(block=1, data=S)`` and forces the
-    serial executor. ``window`` is the streaming executor's window size
-    (ignored by the others)."""
+    instance. ``topology`` is the 2-D ('block', 'data') placement
+    (core.topology.Topology, an ``(block, data)`` pair, or a 1-D mesh)
+    consumed by the serial (block must be 1), sharded, async, and
+    streaming executors. ``window`` is the streaming executor's window
+    size (ignored by the others)."""
     if isinstance(spec, Executor):
-        for arg, name in ((distributed_mesh, "distributed_mesh"),
-                          (window, "window"), (topology, "topology")):
+        for arg, name in ((window, "window"), (topology, "topology")):
             if arg is not None:
                 raise ValueError(
                     f"{name} with an Executor instance is ambiguous — "
@@ -2294,10 +2097,6 @@ def make_executor(spec, distributed_mesh=None, block_mesh=None,
         return spec
     if window is not None and int(window) < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    if distributed_mesh is not None:
-        if topology is not None:
-            raise ValueError("pass distributed_mesh OR topology, not both")
-        spec = "serial"
     if spec not in EXECUTORS:
         raise ValueError(f"unknown executor {spec!r} "
                          f"(expected {' | '.join(EXECUTORS)})")
@@ -2307,12 +2106,10 @@ def make_executor(spec, distributed_mesh=None, block_mesh=None,
             "the stacked executor is single-executable (no device "
             "placement) — use executor='sharded' with a topology")
     factories = {
-        "serial": lambda: SerialExecutor(distributed_mesh, topology=topo),
+        "serial": lambda: SerialExecutor(topology=topo),
         "stacked": lambda: StackedExecutor(),
-        "sharded": lambda: ShardedExecutor(
-            topo if topo is not None else block_mesh),
-        "async": lambda: AsyncExecutor(block_mesh=block_mesh,
-                                       topology=topo),
+        "sharded": lambda: ShardedExecutor(topo),
+        "async": lambda: AsyncExecutor(topology=topo),
         "streaming": lambda: StreamingExecutor(
             topology=topo,
             **({} if window is None else {"window": int(window)})),

@@ -307,8 +307,8 @@ def pad_block_inputs(block: Block, shapes: BlockShapes, K: int,
                      poison_nan: bool = False):
     """Pad one block's CSR planes, priors, and test entries to its phase
     shape bucket — the single source of truth for bucketed padding.
-    ``run_block`` (serial executor), ``engine._task_leaves`` (stacked/
-    sharded executors), ``engine.AsyncExecutor._dispatch``, and the
+    ``engine._group_chain`` (serial and async executors),
+    ``engine._task_leaves`` (stacked/sharded executors), and the
     streaming executor's chunk assembly (via ``pad_block_inputs_host``)
     all go through the same numpy fill; the executors' chain-identical
     parity depends on them never diverging.
@@ -333,46 +333,8 @@ def pad_block_inputs(block: Block, shapes: BlockShapes, K: int,
     return (csr_rows, csr_cols, tr, tc, tv, tmask, U_prior, V_prior)
 
 
-def run_block(key, block: Block, cfg: BMF.BMFConfig,
-              test: Optional[COO],
-              U_prior: Optional[RowGaussians],
-              V_prior: Optional[RowGaussians],
-              distributed_mesh=None,
-              shapes: Optional[BlockShapes] = None,
-              poison_nan: bool = False) -> GIBBS.GibbsResult:
-    """Gibbs on one block (optionally internally distributed)."""
-    if shapes is None:
-        csr_rows = coo_to_padded_csr(block.coo)
-        csr_cols = coo_to_padded_csr(block.coo.transpose())
-        if poison_nan:
-            csr_rows = PaddedCSR(idx=csr_rows.idx,
-                                 val=jnp.full_like(csr_rows.val, jnp.nan),
-                                 mask=csr_rows.mask, n_cols=csr_rows.n_cols)
-            csr_cols = PaddedCSR(idx=csr_cols.idx,
-                                 val=jnp.full_like(csr_cols.val, jnp.nan),
-                                 mask=csr_cols.mask, n_cols=csr_cols.n_cols)
-        if test is not None:
-            tr, tc, _ = _block_test(test, block)
-        else:
-            tr = np.zeros((1,), np.int32)
-            tc = np.zeros((1,), np.int32)
-    else:
-        csr_rows, csr_cols, tr, tc, _, _, U_prior, V_prior = \
-            pad_block_inputs(block, shapes, cfg.K, test, U_prior, V_prior,
-                             poison_nan=poison_nan)
-    if distributed_mesh is not None:
-        from repro.core import distributed as DIST
-        return DIST.run_gibbs_distributed(
-            key, csr_rows, csr_cols, jnp.asarray(tr), jnp.asarray(tc), cfg,
-            distributed_mesh, U_prior=U_prior, V_prior=V_prior)
-    return GIBBS.run_gibbs(key, csr_rows, csr_cols,
-                           jnp.asarray(tr), jnp.asarray(tc), cfg,
-                           U_prior=U_prior, V_prior=V_prior)
-
-
 def run_pp(key, part: Partition, cfg: BMF.BMFConfig, test: COO,
-           distributed_mesh=None, verbose: bool = False,
-           executor="serial", block_mesh=None,
+           verbose: bool = False, executor="serial",
            window: Optional[int] = None, topology=None,
            on_fault: str = "raise", max_retries: int = 2,
            fault_policy=None, fault_plan=None,
@@ -400,10 +362,8 @@ def run_pp(key, part: Partition, cfg: BMF.BMFConfig, test: COO,
       ``run_pp(..., executor="sharded", topology=Topology(block=2, data=2))``
       on 4 devices runs 2 blocks at a time, each chain sharded 2-way —
       the paper's combined system. Consumed by serial (block must be 1),
-      sharded, async (group streams), and streaming (per-group windows).
-    distributed_mesh: legacy spelling of ``topology=Topology(1, S)`` —
-      intra-block sharding only, forces the serial executor; ``block_mesh``
-      is the legacy 1-D inter-block mesh for executor="sharded".
+      sharded, async (group streams), and streaming (per-group windows);
+      the only way to place blocks on devices.
     window: streaming executor's window size W (blocks per chunk); ignored
       by the other executors.
     verbose: per-phase progress lines (block count, shape buckets, wall time).
@@ -439,9 +399,7 @@ def run_pp(key, part: Partition, cfg: BMF.BMFConfig, test: COO,
     if fault_policy is None:
         fault_policy = ENG.FaultPolicy(on_fault=on_fault,
                                        max_retries=int(max_retries))
-    ex = ENG.make_executor(executor, distributed_mesh=distributed_mesh,
-                           block_mesh=block_mesh, window=window,
-                           topology=topology)
+    ex = ENG.make_executor(executor, window=window, topology=topology)
     return ENG.run_phase_graph(key, part, cfg, test, ex, verbose=verbose,
                                policy=fault_policy, fault_plan=fault_plan,
                                checkpoint_dir=checkpoint_dir,

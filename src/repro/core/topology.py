@@ -8,13 +8,10 @@ The paper's headline system composes TWO levels of parallelism:
     over several workers (ref [16]/[17]: rows of U sharded, item stats
     reduced or factors exchanged each sweep).
 
-Historically each executor owned its own ad-hoc device logic — a 1-D
-'block' mesh (sharded), a flat round-robin device list (async), the
-default device (streaming) — and only the serial executor could compose
-with an intra-block 'data' mesh.  ``Topology`` replaces all of that with
-ONE placement object: a single 2-D ``('block', 'data')`` mesh whose
-major axis counts *device groups* (block-level parallelism) and whose
-minor axis counts *devices per group* (intra-block parallelism).
+``Topology`` is the ONE placement object every executor consumes: a
+single 2-D ``('block', 'data')`` mesh whose major axis counts *device
+groups* (block-level parallelism) and whose minor axis counts *devices
+per group* (intra-block parallelism).
 
     Topology(block=2, data=2)      # 4 devices: 2 groups of 2
       group 0: devices[0:2]  — runs blocks, each chain sharded 2-way
@@ -26,13 +23,13 @@ Every executor consumes the same object:
     'block' axis while each block's chain runs the intra-block
     distributed sweep over the 'data' axis
     (``distributed.run_gibbs_stacked_2d``);
-  * ``AsyncExecutor``     round-robins ready blocks over ``groups()``
-    instead of single devices — a dispatch lands on a whole group and
-    the chain is 'data'-sharded inside it;
+  * ``AsyncExecutor``     stages each ready block on the least-loaded
+    group — a dispatch lands on a whole group and the chain is
+    'data'-sharded inside it;
   * ``StreamingExecutor`` keeps one W-bounded donated window per group
     (per-stream prefetch), dispatching each chunk onto its group;
-  * ``SerialExecutor``    uses ``data_mesh()`` as its intra-block mesh
-    (the historical ``distributed_mesh``), requiring ``block == 1``.
+  * ``SerialExecutor``    runs one block at a time on the single group
+    (``block == 1``), its chain 'data'-sharded like an async dispatch.
 
 Multi-host block placement then becomes a config change — a Topology
 over ``jax.devices()`` spanning hosts — rather than a new executor.
@@ -97,9 +94,8 @@ class Topology:
     def from_spec(spec) -> "Topology":
         """Coerce run_pp-style specs: a Topology, None (all devices,
         data=1), an ``(block, data)`` pair, an explicit device sequence
-        (one single-device group per device — the legacy per-device
-        stream spelling), or a 1-D 'block' Mesh (legacy
-        ``block_mesh=``)."""
+        (one single-device group per device), or a Mesh with axes
+        ('block',), ('data',) or ('block', 'data')."""
         if spec is None:
             return Topology.default()
         if isinstance(spec, Topology):
@@ -138,8 +134,9 @@ class Topology:
         return Mesh(grid, (BLOCK_AXIS, DATA_AXIS))
 
     def block_mesh(self) -> Mesh:
-        """1-D 'block' mesh over group leads — the legacy inter-block mesh
-        (``distributed.make_block_mesh``); only meaningful at data == 1."""
+        """1-D 'block' mesh over the devices — the sharded executor's
+        inter-block mesh (``distributed.make_block_mesh``); only meaningful
+        at data == 1."""
         if self.data != 1:
             raise ValueError(
                 f"block_mesh() is the data==1 degenerate form; this "
@@ -153,12 +150,6 @@ class Topology:
     def groups(self) -> Tuple[Tuple, ...]:
         """All device groups, in block-axis order."""
         return tuple(self.group(g) for g in range(self.block))
-
-    def data_mesh(self, g: int = 0) -> Mesh:
-        """1-D 'data' mesh over group ``g`` — the intra-block mesh one
-        block's distributed Gibbs chain shard_maps over (what
-        ``run_pp(distributed_mesh=...)`` historically took)."""
-        return Mesh(np.asarray(self.group(g), dtype=object), (DATA_AXIS,))
 
     def group_mesh_2d(self, g: int = 0) -> Mesh:
         """(1, data) submesh of group ``g`` with BOTH axis names — lets the

@@ -1,13 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-"""Production-mesh dry-run for the paper's OWN workload: the distributed
-BMF Gibbs sweep at real-Netflix scale, lowered on the 256-chip 'data' ring
-(one PP block spanning a pod's worth of chips).
+"""Production-mesh dry-run for the paper's OWN workload: one block's
+data-sharded Gibbs chain at real-Netflix scale, lowered on the 256-chip
+'data' ring (one PP block spanning a pod's worth of chips).
 
 Records roofline terms for the paper-faithful (psum) and beyond-paper
-(scatter-V, §Perf H6) variants — the artifact behind the EXPERIMENTS
-§Scaling saturation analysis.
+(scatter-V, §Perf H6) item-stat exchanges — the artifact behind the
+EXPERIMENTS §Scaling saturation analysis.
 
 --pp-engine additionally lowers the phase-graph engine's sharded phase-c
 bucket (core.engine.ShardedExecutor: one batched Gibbs chain shard_map'd
@@ -45,43 +45,20 @@ from repro.roofline import jaxpr_cost as JCOST
 OUT = Path(__file__).resolve().parents[3] / "benchmarks" / "bmf_dryrun_results.json"
 
 
-def lower_sweep(n_shards: int, N: int, D: int, M: int, K: int,
-                scatter_v: bool):
-    mesh = jax.make_mesh((n_shards,), ("data",))
-    cfg = BMF.BMFConfig(K=K)
+def lower_sweep(n_shards: int, N: int, D: int, M: int, K: int, comm: str):
+    """Lower one block's chain data-sharded over a 'data' ring of
+    ``n_shards`` devices — the one chain body (``gibbs._run_gibbs_impl``
+    with the data-sharded samplers), as the B=1 composed executable
+    ``distributed.run_gibbs_group`` dispatches — with the paper-faithful
+    'psum' or the beyond-paper 'scatter' (§Perf H6) item-stat exchange.
+    Flop terms count the sweep loop's body once (chain_len=1)."""
+    rec = lower_pp_phase_2d(1, n_shards, N, D, M, K, chain_len=1, comm=comm)
     D_pad = ((D + n_shards - 1) // n_shards) * n_shards
-    N_pad = ((N + n_shards - 1) // n_shards) * n_shards
-    M_c = max(8, (M * N // D // 8) * 8)  # transposed-side padded nnz
-
-    sweep = DIST.make_distributed_sweep(mesh, cfg, N_pad, D_pad, n_shards,
-                                        has_u_prior=False, has_v_prior=False,
-                                        scatter_v=scatter_v)
-    S = jax.ShapeDtypeStruct
-    args = (
-        jax.eval_shape(lambda: jax.random.key(0)),
-        S((N_pad, K), jnp.float32), S((D_pad, K), jnp.float32),
-        S((N_pad, M), jnp.int32), S((N_pad, M), jnp.float32),
-        S((N_pad, M), jnp.float32),
-        S((n_shards, D_pad, M_c), jnp.int32),
-        S((n_shards, D_pad, M_c), jnp.float32),
-        S((n_shards, D_pad, M_c), jnp.float32),
-        S((1,), jnp.float32), S((1,), jnp.float32),
-        S((1,), jnp.float32), S((1,), jnp.float32),
-    )
-    jitted = jax.jit(sweep)
-    traced = jitted.trace(*args)
-    jcost = JCOST.jaxpr_cost(traced.jaxpr)
-    compiled = traced.lower().compile()
-    terms = ROOF.terms_from(jcost, compiled.as_text(), n_shards)
-    analytic = (DIST.sweep_comm_bytes_scatter if scatter_v
-                else DIST.sweep_comm_bytes)(D_pad, K)
-    return {
-        "variant": "scatter_v" if scatter_v else "paper_psum",
-        "n_shards": n_shards, "N": N, "D": D, "M": M, "K": K,
-        "roofline": terms.as_dict(),
-        "analytic_comm_bytes": analytic,
-        "collectives": ROOF.collective_bytes(compiled.as_text()),
-    }
+    rec.update(variant=f"data_sharded_chain[{comm}]", n_shards=n_shards,
+               analytic_comm_bytes=(DIST.sweep_comm_bytes_scatter
+                                    if comm == "scatter"
+                                    else DIST.sweep_comm_bytes)(D_pad, K))
+    return rec
 
 
 def lower_pp_phase(n_blocks: int, N: int, D: int, M: int, K: int,
@@ -363,11 +340,11 @@ def main():
     args = ap.parse_args()
 
     results = []
-    for sv in (False, True):
-        rec = lower_sweep(args.shards, args.n, args.d, args.m, args.k, sv)
+    for comm in ("psum", "scatter"):
+        rec = lower_sweep(args.shards, args.n, args.d, args.m, args.k, comm)
         results.append(rec)
         rf = rec["roofline"]
-        print(f"{rec['variant']:12s} compute={rf['compute_s']:.3e}s "
+        print(f"{rec['variant']} compute={rf['compute_s']:.3e}s "
               f"memory={rf['memory_s']:.3e}s collective={rf['collective_s']:.3e}s "
               f"dominant={rf['dominant']} "
               f"(analytic comm {rec['analytic_comm_bytes']/1e6:.0f} MB)")

@@ -3,7 +3,7 @@
 Usage:
   PYTHONPATH=src python -m repro.launch.bmf_train \
       --dataset movielens --blocks 4 --samples 60 \
-      [--executor serial|stacked|sharded] [--distributed]
+      [--executor serial|stacked|sharded|async|streaming] [--topology B D]
 
 --executor picks the phase-graph engine executor (core.engine): 'stacked'
 (default) runs each PP phase's shape bucket as ONE vmapped Gibbs call;
@@ -16,15 +16,14 @@ to a window of --window donated block buffers (prefetched host planes,
 critical-path-first dispatch) for grids whose stacked buckets don't fit
 device memory; 'serial' is the reference per-block loop.
 
---distributed shards each block's Gibbs loop INTERNALLY over all local
-devices (core.distributed shard_map) — this forces the serial executor.
-
 --topology B D places the run on the unified 2-D ('block','data') mesh
 (core.topology.Topology): B device groups run blocks concurrently while
 each block's Gibbs sweep is sharded over the D devices of its group —
 the paper's combined system (block-parallel PP x intra-block distributed
 BMF). Composes with --executor sharded (2-D shard_map), async (group
-streams), streaming (one donated window per group), and serial (B=1).
+streams), streaming (one donated window per group), and serial (B=1:
+``--executor serial --topology 1 N`` shards each block's chain over N
+devices, one block at a time).
 
 Fault tolerance: --on-fault/--max-retries set the engine's chain-health
 policy (core/README.md "Fault tolerance"); --ckpt-dir persists each
@@ -62,13 +61,12 @@ def main():
                     help="phase-graph engine executor (core.engine)")
     ap.add_argument("--window", type=int, default=0,
                     help="streaming executor window size W (0 = default)")
-    ap.add_argument("--distributed", action="store_true",
-                    help="intra-block shard_map (forces --executor serial)")
     ap.add_argument("--topology", type=int, nargs=2, default=None,
                     metavar=("BLOCK", "DATA"),
                     help="2-D ('block','data') placement: BLOCK device "
                          "groups x DATA devices per group (unified "
-                         "core.topology mesh)")
+                         "core.topology mesh); '1 N' shards each block's "
+                         "chain over N devices")
     ap.add_argument("--phase-bc-samples", type=int, default=0)
     ap.add_argument("--fused-sweep", action="store_true",
                     help="one-kernel Gibbs sweep (kernels/bmf_sweep): the "
@@ -122,20 +120,12 @@ def main():
           f"nnz={train.nnz} grid={I}x{J} K={K}")
     print("block nnz balance:", nnz_balance_stats(part))
 
-    mesh = None
     topology = None
     if args.topology:
         from repro.core.topology import Topology
-        if args.distributed:
-            raise SystemExit("--topology and --distributed are exclusive "
-                             "(--distributed is Topology(1, n_devices))")
         topology = Topology(block=args.topology[0], data=args.topology[1])
         print(topology.describe())
-    if args.distributed:
-        n = len(jax.devices())
-        mesh = jax.make_mesh((n,), ("data",))
-        print(f"distributed: {n}-way shard_map per block (serial executor)")
-    elif args.executor == "sharded":
+    if args.executor == "sharded":
         print(f"sharded executor: {len(jax.devices())}-way block mesh")
     elif args.executor == "async":
         print(f"async executor: dependency-driven overlap, "
@@ -146,8 +136,7 @@ def main():
               f"critical-path-first dispatch")
 
     res = PP.run_pp(jax.random.key(args.seed), part, cfg, test,
-                    distributed_mesh=mesh, verbose=True,
-                    executor=args.executor, window=args.window or None,
+                    verbose=True, executor=args.executor, window=args.window or None,
                     topology=topology, on_fault=args.on_fault,
                     max_retries=args.max_retries,
                     checkpoint_dir=args.ckpt_dir or None,

@@ -1,5 +1,6 @@
 """Phase-graph PP engine: graph structure, executor parity, aggregation
 algebra, verbose reporting, and the occupancy-sorted partition wiring."""
+import json
 import os
 import subprocess
 import sys
@@ -185,9 +186,54 @@ def test_executor_instance_and_unknown(mini_run):
         PP.run_pp(jax.random.key(0), part, fast, test, executor="warp")
 
 
-def test_distributed_mesh_forces_serial():
-    ex = ENG.make_executor("stacked", distributed_mesh=object())
-    assert isinstance(ex, ENG.SerialExecutor)
+SERIAL_GROUP_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import json
+    import jax
+    import numpy as np
+    from repro.core import bmf as BMF, pp as PP
+    from repro.core.partition import partition
+    from repro.core.topology import Topology
+    from repro.data import synthetic as SYN
+    from repro.data.sparse import train_test_split
+
+    coo, p = SYN.generate("mini", seed=3)
+    train, test = train_test_split(coo, 0.15, seed=4)
+    cfg = BMF.BMFConfig(K=p.K, n_samples=6, burnin=2)
+    part = partition(train, 2, 2)
+    key = jax.random.key(1)
+    topo = Topology(block=1, data=2)
+    r_ser = PP.run_pp(key, part, cfg, test, executor="serial",
+                      topology=topo)
+    r_asy = PP.run_pp(key, part, cfg, test, executor="async",
+                      topology=topo)
+    eq = {f"{f}.{leaf}": bool(np.array_equal(
+              np.asarray(getattr(getattr(r_ser, f), leaf)),
+              np.asarray(getattr(getattr(r_asy, f), leaf))))
+          for f in ("U_agg", "V_agg") for leaf in ("eta", "Lambda")}
+    print(json.dumps({"n_devices": len(jax.devices()),
+                      "executors": [r_ser.executor, r_asy.executor],
+                      "equal": eq, "rmse": [r_ser.rmse, r_asy.rmse]}))
+""")
+
+
+def test_serial_data_sharded_matches_async():
+    """``executor="serial"`` on ``Topology(1, 2)`` runs each block through
+    the one chain body, data-sharded exactly like an async group dispatch
+    (``distributed.run_gibbs_group``, comm 'gather', same keys and
+    shapes): its aggregated posteriors are bitwise the async executor's
+    on the same topology."""
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
+    out = subprocess.run([sys.executable, "-c", SERIAL_GROUP_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=500)
+    assert out.returncode == 0, out.stderr[-3000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["n_devices"] == 2
+    assert rec["executors"] == ["serial", "async"]
+    assert all(rec["equal"].values()), rec
+    assert abs(rec["rmse"][0] - rec["rmse"][1]) < 1e-5, rec
 
 
 def test_window_with_instance_rejected():

@@ -1,6 +1,6 @@
 """Unified 2-D ('block', 'data') topology layer: Topology object
 semantics, the composed stacked 2-D chain, group dispatch, and donation
-through the distributed per-sweep loop.
+through the data-sharded group chain.
 
 Multi-device behavior runs in subprocesses with
 XLA_FLAGS=--xla_force_host_platform_device_count so faked meshes never
@@ -69,8 +69,6 @@ def test_topology_meshes_unify_block_mesh():
     assert tuple(bm.axis_names) == ("block",)
     assert bm == make_block_mesh(1)
     assert tuple(t.mesh.axis_names) == ("block", "data")
-    dm = t.data_mesh(0)
-    assert tuple(dm.axis_names) == ("data",)
     g2 = t.group_mesh_2d(0)
     assert g2.devices.shape == (1, 1)
     assert tuple(g2.axis_names) == ("block", "data")
@@ -81,9 +79,6 @@ def test_topology_executor_wiring_errors():
         ENG.make_executor("stacked", topology=Topology(1, 1))
     with pytest.raises(ValueError):
         ENG.make_executor(ENG.StackedExecutor(), topology=Topology(1, 1))
-    with pytest.raises(ValueError):
-        ENG.SerialExecutor(distributed_mesh=object(),
-                           topology=Topology(1, 1))
     # serial with a block>1 topology is meaningless
     with pytest.raises(ValueError):
         ENG.make_executor("serial", topology=(2, 1))
@@ -93,7 +88,7 @@ def test_executors_consume_topology_single_device():
     """On one device every executor accepts the degenerate topology and
     keeps its legacy behavior."""
     t = Topology(block=1, data=1)
-    assert ENG.make_executor("serial", topology=t).distributed_mesh is None
+    assert ENG.make_executor("serial", topology=t).topology is t
     sh = ENG.make_executor("sharded", topology=t)
     assert sh.topology is t and sh.block_mesh is not None
     asy = ENG.make_executor("async", topology=t)
@@ -194,7 +189,7 @@ def test_stacked_2d_chain_parity_and_modes():
 
 
 # ---------------------------------------------------------------------------
-# donation through the distributed per-sweep loop (subprocess, 8 devices)
+# donation through the data-sharded group chain (subprocess, 8 devices)
 # ---------------------------------------------------------------------------
 
 DONATE_SCRIPT = textwrap.dedent("""
@@ -202,11 +197,13 @@ DONATE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp, numpy as np
+    from repro import analysis as LINT
     from repro.core import bmf as BMF, gibbs as GIBBS, distributed as DIST
+    from repro.core.topology import Topology
     from repro.data import synthetic as SYN
     from repro.data.sparse import train_test_split, coo_to_padded_csr
 
-    mesh = jax.make_mesh((8,), ("data",))
+    topo = Topology(block=1, data=8)
     coo, p = SYN.generate("mini", seed=3)
     train, test = train_test_split(coo, 0.15, seed=4)
     csr_r = coo_to_padded_csr(train)
@@ -214,28 +211,33 @@ DONATE_SCRIPT = textwrap.dedent("""
     cfg = BMF.BMFConfig(K=p.K, n_samples=6, burnin=2)
     tr, tc = jnp.asarray(test.row), jnp.asarray(test.col)
 
-    U0, V0 = BMF.init_factors(jax.random.key(4), csr_r.n_rows,
-                              csr_c.n_rows, cfg.K)
-    ref = DIST.run_gibbs_distributed(jax.random.key(0), csr_r, csr_c,
-                                     tr, tc, cfg, mesh, U0=U0, V0=V0,
-                                     donate=False)
-    assert not U0.is_deleted()
+    ref = DIST.run_gibbs_group(jax.random.key(0), csr_r, csr_c, tr, tc,
+                               cfg, topo, donate=False)
+    don = DIST.run_gibbs_group(jax.random.key(0), csr_r, csr_c, tr, tc,
+                               cfg, topo, donate=True)
 
-    # pre-commit the carry to the sweep's shardings: a donated buffer jit
-    # would have to reshard is consumed by the transfer, not aliased —
-    # committed buffers are donated directly and the handles invalidate
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    U0d, V0d = BMF.init_factors(jax.random.key(4), csr_r.n_rows,
-                                csr_c.n_rows, cfg.K)
-    U0d = jax.device_put(U0d, NamedSharding(mesh, P("data", None)))
-    V0d = jax.device_put(V0d, NamedSharding(mesh, P(None, None)))
-    don = DIST.run_gibbs_distributed(jax.random.key(0), csr_r, csr_c,
-                                     tr, tc, cfg, mesh, U0=U0d, V0=V0d,
-                                     donate=True)
+    # the donated executable run_gibbs_group dispatches at these shapes,
+    # through the analyzer's donation contract: V0 must alias the V
+    # output in place, the other donated leaves are released at dispatch
+    tchain = DIST.trace_chain_2d(
+        cfg, topo, csr_r.n_rows, csr_c.n_rows, csr_r.idx.shape[1],
+        csr_c.idx.shape[1], len(test.row), batch=1, comm="gather",
+        donate=True, u_prior=False, v_prior=False)
+    with GIBBS._quiet_donation():
+        hlo = tchain.traced.lower().compile().as_text()
+    donated = tuple(tchain.donated_labels)
+    vs = LINT.analyze(LINT.HLOArtifact(
+        label="group_chain_donated", hlo_text=hlo, comm="gather",
+        allowed_groups=[list(range(8))],
+        param_labels=tchain.param_labels, donated=donated,
+        must_alias=tchain.must_alias,
+        release_only=tuple(lb for lb in donated
+                           if lb not in tchain.must_alias)))
     print(json.dumps({
         "n_devices": len(jax.devices()),
-        "u0_deleted": bool(U0d.is_deleted()),
-        "v0_deleted": bool(V0d.is_deleted()),
+        "must_alias": list(tchain.must_alias),
+        "has_alias": "input_output_alias=" in hlo,
+        "violations": [str(v) for v in vs],
         "U_equal": bool(np.array_equal(np.asarray(ref.U),
                                        np.asarray(don.U))),
         "post_equal": bool(np.array_equal(np.asarray(ref.U_post.eta),
@@ -250,12 +252,14 @@ DONATE_SCRIPT = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_distributed_sweep_donation_alias_and_invalidate():
-    """Mirrors the PR-3 gibbs donation tests for the distributed per-sweep
-    loop: donate=True must not change the chain, and the donated carry
-    (U0/V0) must be invalidated at the first sweep — XLA recycles the
-    factor buffers in place across iterations."""
+    """Mirrors the gibbs donation tests for the data-sharded group chain
+    (``distributed.run_gibbs_group``): donate=True must not change the
+    chain, and its executable must alias the donated V0 onto the V
+    output in place — XLA recycles the factor buffer across sweeps —
+    while the other donated leaves are released at dispatch."""
     rec = _run(DONATE_SCRIPT)
     assert rec["n_devices"] == 8
-    assert rec["u0_deleted"] and rec["v0_deleted"], rec
+    assert rec["has_alias"] and rec["must_alias"] == ["V0"], rec
+    assert not rec["violations"], rec
     assert rec["U_equal"] and rec["post_equal"], rec
     assert rec["rmse_ref"] == rec["rmse_don"], rec
