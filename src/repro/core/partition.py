@@ -81,7 +81,7 @@ def _occupancy_refine(pc: COO, perm: np.ndarray, splits: np.ndarray,
     ``occupancy_permutation``) then sorts each stripe's rows by descending
     rating count WITHIN it, so the padded-CSR slot planes of every block in
     the stripe are occupancy-coherent: the fused kernel's nnz-aware M-tile
-    skip (data.sparse.tile_occupancy) sees long runs of equally-full rows,
+    skip (data.sparse.row_extent) sees long runs of equally-full rows,
     and stacked same-phase buckets waste fewer padded tiles. Stripe
     membership is untouched, so block nnz balance and the per-phase
     BlockShapes buckets are identical either way."""

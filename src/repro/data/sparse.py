@@ -105,20 +105,28 @@ def coo_to_padded_csr(coo: COO, max_nnz: Optional[int] = None,
                      mask=jnp.asarray(mask), n_cols=n_cols)
 
 
-def tile_occupancy(mask, tn: int, tm: int):
-    """Per-row-tile count of live M-tiles for the fused-gather kernel's
-    nnz-aware grid: ``ntiles[t]`` = number of tm-wide slot tiles that
-    contain any unmasked entry among rows [t·tn, (t+1)·tn).  CSR padding
-    fills slots from the left, so a tile's occupancy is determined by its
-    last live slot; the kernel skips M-tiles >= ntiles (no DMA, no matmul).
+def row_extent(mask):
+    """Per-row live extent: the last unmasked slot + 1, 0 for an empty row
+    — the plane the fused-gather kernels scalar-prefetch.  Their DMA pump
+    copies slots [0, extent) of a row and none past it, and their grid
+    skips an M-tile that starts at or past every extent of its row tile.
+    CSR padding fills slots from the left, so the extent is the row's
+    degree; a mask with holes (a masked slot before a live one) gets its
+    last live slot + 1, and the pump copies the hole and masks it.
 
-    mask: (N, M) with N % tn == 0 and M % tm == 0 (np or jnp; traceable)."""
-    N, M = mask.shape
-    assert N % tn == 0 and M % tm == 0, (N, M, tn, tm)
-    arange = jnp.arange(M, dtype=jnp.float32) + 1.0
-    last_live = jnp.max(mask.astype(jnp.float32) * arange, axis=1)   # (N,)
-    last_live = last_live.reshape(N // tn, tn).max(axis=1)
-    return jnp.ceil(last_live / tm).astype(jnp.int32)
+    mask: (N, M) (np or jnp; traceable).  Returns (N,) int32."""
+    M = mask.shape[1]
+    slot = jnp.arange(1, M + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(jnp.asarray(mask) != 0, slot, 0), axis=1)
+
+
+def pump_copies(mask) -> int:
+    """Row copies the kernels' DMA pump issues for one (N, M) plane: one
+    per slot below each row's ``row_extent``, whatever the tiling — so on
+    the left-filled planes of ``coo_to_padded_csr`` exactly one per live
+    rating (``mask.sum()``), and more only for holes.  Host helper, for
+    counting; not traceable."""
+    return int(np.asarray(row_extent(mask), np.int64).sum())
 
 
 def occupancy_rank(counts: np.ndarray) -> np.ndarray:

@@ -26,7 +26,7 @@ from repro.kernels.bmf_precision.kernel import (
     LANES, TM, TN, precision_accum_fused_padded)
 from repro.kernels.bmf_precision.ref import precision_accum_ref
 from repro.kernels.route import check_lane_width, pallas_route
-from repro.data.sparse import tile_occupancy
+from repro.data.sparse import row_extent
 
 
 def _on_tpu() -> bool:
@@ -69,9 +69,9 @@ def precision_accum_fused(idx, val, mask, other, tau: float, *,
                           tm: int = TM, interpret=None,
                           smem_idx_budget: int = SMEM_IDX_BUDGET):
     """Fused-gather Pallas path: pads (N, M) to tile multiples and K to the
-    LANES width, computes per-row-tile occupancy, and dispatches.  The
-    gather happens INSIDE the kernel — peak live memory here is O(N·M) CSR
-    planes + O(D·K) factors + O(N·K²) outputs.
+    LANES width, computes each row's live extent (the pump's copy bound),
+    and dispatches.  The gather happens INSIDE the kernel — peak live
+    memory here is O(N·M) CSR planes + O(D·K) factors + O(N·K²) outputs.
 
     The scalar-prefetched index plane sits in SMEM, so the N axis is
     striped such that each pallas_call's (n_stripe, M) int32 plane stays
@@ -95,18 +95,19 @@ def precision_accum_fused(idx, val, mask, other, tau: float, *,
     valp = _pad_to(_pad_to(val, Mp, 1), Np, 0)
     maskp = _pad_to(_pad_to(mask, Mp, 1), Np, 0)   # ... but are masked out
     otherp = _pad_to(other, Kp, 1)
+    extp = row_extent(maskp)                       # the pump's live extents
 
     def stripe(args):
-        ix, vl, mk = args
-        return precision_accum_fused_padded(
-            ix, tile_occupancy(mk, TN, tm), vl, mk, otherp, tau,
-            tm=tm, interpret=interpret)
+        ix, ex, vl, mk = args
+        return precision_accum_fused_padded(ix, ex, vl, mk, otherp, tau,
+                                            tm=tm, interpret=interpret)
 
     if Np == ns:
-        Lam, eta = stripe((idxp, valp, maskp))
+        Lam, eta = stripe((idxp, extp, valp, maskp))
     else:
         nsp = Np // ns
         Lam, eta = jax.lax.map(stripe, (idxp.reshape(nsp, ns, Mp),
+                                        extp.reshape(nsp, ns),
                                         valp.reshape(nsp, ns, Mp),
                                         maskp.reshape(nsp, ns, Mp)))
         Lam = Lam.reshape(Np, Kp, Kp)

@@ -10,10 +10,12 @@ factor step.  This kernel chains the whole per-row conditional
 
 inside one pallas_call: the (TN, K, K) precision block lives ONLY in VMEM
 scratch, and the single HBM-resident output is the sampled factor block
-(TN, K).  The grid, scalar-prefetched CSR planes, DMA row pump, and
-nnz-aware tile skip are bmf_precision's exactly (imported constants);
-what is new is the ``m == last`` epilogue that factors and samples in
-registers instead of writing Λ/η out.
+(TN, K).  The grid, the scalar-prefetched CSR index plane and per-row
+live extents, the nnz-aware tile skip and the DMA row pump are
+bmf_precision's — the pump is imported (``gather_live_rows``: one copy
+per live slot, the slots past a row's extent never copied and read as
+zero); what is new is the ``m == last`` epilogue that factors and
+samples in registers instead of writing Λ/η out.
 
 Small-K linear algebra without dynamic lane indexing: TPU vector layouts
 forbid addressing individual lanes, so the Cholesky and the triangular
@@ -54,11 +56,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bmf_precision.kernel import DMA_LOOKAHEAD, LANES, TM, TN
+from repro.kernels.bmf_precision.kernel import (
+    LANES, TM, TN, gather_live_rows, live_block, tile_extent)
 
 __all__ = ["accum_tile", "sample_tile", "chol_tile", "solve_lower_tile",
            "solve_upper_tile", "live_width", "fused_sweep_padded",
-           "TN", "TM", "LANES", "DMA_LOOKAHEAD"]
+           "TN", "TM", "LANES"]
 
 
 # ---------------------------------------------------------------------------
@@ -213,7 +216,7 @@ def sample_tile(lam, eta, prior_lam, prior_eta, z, jitter, k=None):
 # ---------------------------------------------------------------------------
 
 
-def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
+def _sweep_kernel(idx_ref, ext_ref, val_ref, mask_ref, peta_ref, plam_ref,
                   z_ref, other_ref, u_ref, lam_ref, eta_ref, vg_ref, sem, *,
                   tau: float, tm: int, jitter: float, dtype, k: int):
     n = pl.program_id(0)
@@ -225,34 +228,10 @@ def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
         eta_ref[...] = jnp.zeros_like(eta_ref)
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    @pl.when(m < ntiles_ref[n])
+    @pl.when(m * tm < tile_extent(ext_ref, n))
     def _accumulate():
-        G = TN * tm
-
-        def row_copy(s):
-            # slot s of this tile gathers factor row idx[r, c]
-            r = n * TN + s // tm
-            c = m * tm + s % tm
-            row = idx_ref[r, c]
-            return pltpu.make_async_copy(other_ref.at[pl.ds(row, 1)],
-                                         vg_ref.at[pl.ds(s, 1)], sem)
-
-        def warmup(s, carry):
-            row_copy(s).start()
-            return carry
-
-        jax.lax.fori_loop(0, DMA_LOOKAHEAD, warmup, None)
-
-        def pump(s, carry):
-            @pl.when(s + DMA_LOOKAHEAD < G)
-            def _():
-                row_copy(s + DMA_LOOKAHEAD).start()
-            row_copy(s).wait()
-            return carry
-
-        jax.lax.fori_loop(0, G, pump, None)
-
-        v = vg_ref[...].reshape(TN, tm, -1)
+        v = gather_live_rows(idx_ref, ext_ref, other_ref, vg_ref, sem,
+                             mask_ref[...], n, m, tm=tm)
         lam, eta = accum_tile(lam_ref[...], eta_ref[...], v,
                               mask_ref[...], val_ref[...], tau, dtype)
         lam_ref[...] = lam
@@ -271,14 +250,15 @@ def _sweep_kernel(idx_ref, ntiles_ref, val_ref, mask_ref, peta_ref, plam_ref,
             peta_ref[:, :ks], z_ref[:, :ks], jitter, k)
 
 
-def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
+def fused_sweep_padded(idx, ext, val, mask, prior_eta, prior_lam, z,
                        other, tau: float, *, tm: int = TM,
                        jitter: float = 1e-6, dtype=jnp.float32,
                        k: int | None = None, interpret: bool = False):
-    """idx/val/mask: (N, M) with N % TN == 0, M % tm == 0; ntiles: (N/TN,)
-    live-M-tile counts; prior_eta/z: (N, K), prior_lam: (N, K, K) f32 with
-    pad lanes carrying an identity diagonal; other: (D, K) f32,
-    HBM-resident; dtype: the Λ matmul operand dtype (``accum_tile``);
+    """idx/val/mask: (N, M) with N % TN == 0, M % tm == 0; ext: (N,) int32
+    per-row live extents (``data.sparse.row_extent``); prior_eta/z:
+    (N, K), prior_lam: (N, K, K) f32 with pad lanes carrying an identity
+    diagonal; other: (D, K) f32, HBM-resident; dtype: the Λ matmul
+    operand dtype (``accum_tile``);
     k: the true rank inside the lane padding (default K), which bounds the
     epilogue's Cholesky and solves.  Returns the sampled factor U (N, K),
     zero past lane k — no (N, K, K) HBM intermediate."""
@@ -288,11 +268,6 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
     assert N % TN == 0 and M % tm == 0, (N, M, tm)
     grid = (N // TN, M // tm)
 
-    def live_block(n, m, idx_ref, ntiles_ref):
-        # skipped steps re-point at the tile's last live block: the pipeline
-        # sees the same block index and elides the copy entirely
-        return (n, jnp.minimum(m, jnp.maximum(ntiles_ref[n], 1) - 1))
-
     def row_block(n, m, *_):
         return (n, 0)
 
@@ -300,8 +275,8 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((TN, tm), live_block),             # val
-            pl.BlockSpec((TN, tm), live_block),             # mask
+            pl.BlockSpec((TN, tm), live_block(tm)),         # val
+            pl.BlockSpec((TN, tm), live_block(tm)),         # mask
             pl.BlockSpec((TN, K), row_block),               # prior eta
             pl.BlockSpec((TN, K, K), lambda n, m, *_: (n, 0, 0)),
             pl.BlockSpec((TN, K), row_block),               # noise z
@@ -322,4 +297,4 @@ def fused_sweep_padded(idx, ntiles, val, mask, prior_eta, prior_lam, z,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, K), jnp.float32),
         interpret=interpret,
-    )(idx, ntiles, val, mask, prior_eta, prior_lam, z, other)
+    )(idx, ext, val, mask, prior_eta, prior_lam, z, other)

@@ -36,7 +36,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.data.sparse import tile_occupancy
+from repro.data.sparse import row_extent
 from repro.kernels.bmf_precision.ops import SMEM_IDX_BUDGET, _on_tpu, _pad_to
 from repro.kernels.bmf_sweep.kernel import (
     LANES, TM, TN, fused_sweep_padded)
@@ -129,6 +129,9 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
                 pL = pL + jnp.diag(pad_diag)[None]
             zp = _pad_to(_pad_to(z.astype(jnp.float32), Kp, 1), Np, 0)
             otherp = _pad_to(other.astype(jnp.float32), Kp, 1)
+            if use_pallas:
+                # the pump's per-row live extents, scalar-prefetched
+                extp = row_extent(maskp)
         mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.float32
 
         if not use_pallas:
@@ -138,17 +141,17 @@ def fused_sweep(z, idx, val, mask, prior_eta, prior_lam, other, tau: float, *,
             return U[:N, :K]
 
         def stripe(args):
-            ix, vl, mk, pe1, pL1, zz = args
+            ix, ex, vl, mk, pe1, pL1, zz = args
             return fused_sweep_padded(
-                ix, tile_occupancy(mk, TN, tm_eff), vl, mk, pe1, pL1, zz,
-                otherp, tau, tm=tm_eff, jitter=jitter, dtype=mm_dtype,
-                k=K, interpret=interpret)
+                ix, ex, vl, mk, pe1, pL1, zz, otherp, tau, tm=tm_eff,
+                jitter=jitter, dtype=mm_dtype, k=K, interpret=interpret)
 
         if Np == ns:
-            U = stripe((idxp, valp, maskp, pe, pL, zp))
+            U = stripe((idxp, extp, valp, maskp, pe, pL, zp))
         else:
             nsp = Np // ns
             U = jax.lax.map(stripe, (idxp.reshape(nsp, ns, Mp),
+                                     extp.reshape(nsp, ns),
                                      valp.reshape(nsp, ns, Mp),
                                      maskp.reshape(nsp, ns, Mp),
                                      pe.reshape(nsp, ns, Kp),

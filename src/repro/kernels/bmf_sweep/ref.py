@@ -10,9 +10,10 @@ equality there.  Once the N axis stripes under ``lax.map``, XLA compiles
 the stripe body as one fused computation and CPU fast-math contraction
 (FMA / add reassociation across fusion boundaries) can shift results by
 a few ulps relative to the op-by-op interpreter — same math, tighter
-rounding, asserted at 1e-5.  (Dead M-tiles the kernel's occupancy counts
-skip are processed here — their masked contribution is exactly zero,
-which the parity suite pins down.)
+rounding, asserted at 1e-5.  (The dead M-tiles the kernel skips, and the
+slots past each row's extent its pump never copies, are gathered and
+processed here — their masked contribution is exactly zero, which the
+parity suite pins down.)
 
 Zero-materialization shape discipline matches bmf_precision's fallback:
 the N axis is striped under ``lax.map`` (one program regardless of N) and
@@ -32,9 +33,10 @@ from repro.kernels.bmf_sweep.kernel import accum_tile, live_width, sample_tile
 def sweep_ref_padded(idx, val, mask, prior_eta, prior_lam, z, other,
                      tau: float, *, tm: int, jitter: float = 1e-6,
                      dtype=jnp.float32, n_stripe: int, k: int | None = None):
-    """Same contract as ``kernel.fused_sweep_padded`` (minus the occupancy
-    counts — all tiles are processed; dead ones add exact zeros).  N must
-    be a multiple of ``n_stripe``; M a multiple of ``tm``."""
+    """Same contract as ``kernel.fused_sweep_padded`` (minus the extent
+    plane — every slot of every tile is processed; dead ones add exact
+    zeros).  N must be a multiple of ``n_stripe``; M a multiple of
+    ``tm``."""
     N, M = idx.shape
     K = other.shape[-1]
     k = K if k is None else k

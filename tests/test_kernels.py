@@ -67,6 +67,53 @@ def test_bmf_precision_fused_parity(N, M, K):
     assert float(jnp.abs(eta[-1]).max()) == 0.0
 
 
+def _live_slot_case(rng, tm, D, K, dtype):
+    """A plane for the row pump's live-slot walk: 4 row tiles over
+    2·tm + 3 slots. Tile 0 is heavy (degrees 0, 1, tm−1, tm, tm+1 and
+    2·tm+3), tile 1 light, tile 2 empty, and tile 3 has holes (row 24: a
+    mask 0 before a live slot, extent 9 for degree 7; row 25: three holes
+    across the first M-tile seam). Factor rows are multiples of 1/4 and
+    ratings integers, so every sum is exact in any order and the kernel
+    equals the one-einsum oracle bitwise. Factor row D−1 is NaN and only
+    the live slot (6, 2·tm+1) of the heavy tile gathers it: the NaN it
+    leaves in the gather scratch must reach no later tile. Returns idx,
+    val, mask, other and the one row whose result is NaN."""
+    deg = np.array([0, 1, tm - 1, tm, tm + 1, 2 * tm + 3, 2 * tm + 3, 5,
+                    1, 0, 2, 3, 0, 1, 1, 2,
+                    0, 0, 0, 0, 0, 0, 0, 0,
+                    9, tm + 2, 3, 0, 0, 1, 0, 0])
+    M = 2 * tm + 3
+    mask = (np.arange(M)[None, :] < deg[:, None]).astype(np.float32)
+    mask[24, [2, 5]] = 0.0
+    mask[25, tm - 2:tm + 1] = 0.0
+    idx = rng.integers(0, D - 1, (len(deg), M)).astype(np.int32)
+    idx[6, 2 * tm + 1] = D - 1
+    other = rng.integers(-4, 5, (D, K)) / 4.0
+    other[D - 1] = np.nan
+    val = rng.integers(1, 6, (len(deg), M)).astype(np.float32)
+    return (jnp.asarray(idx), jnp.asarray(val), jnp.asarray(mask),
+            jnp.asarray(other, dtype), 6)
+
+
+@pytest.mark.parametrize("K", [8, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_bmf_precision_fused_live_slots_bitwise(K, dtype):
+    """The pump copies only the slots below each row's extent; the slots it
+    skips read as zero. Interpret-mode kernel vs the dense oracle,
+    bitwise, on ragged degrees, an empty row tile, holes and a heavy tile
+    followed by light ones."""
+    rng = np.random.default_rng(29)
+    idx, val, mask, other, nan_row = _live_slot_case(rng, 128, 41, K, dtype)
+    Lam, eta = BMFK.precision_accum_fused(idx, val, mask, other, 2.0, tm=128)
+    Lam_r, eta_r = BMFK.precision_accum_reference(idx, val, mask, other, 2.0)
+    np.testing.assert_array_equal(np.asarray(Lam), np.asarray(Lam_r))
+    np.testing.assert_array_equal(np.asarray(eta), np.asarray(eta_r))
+    finite = np.arange(idx.shape[0]) != nan_row
+    assert np.isfinite(np.asarray(Lam)[finite]).all()
+    assert np.isnan(np.asarray(eta)[nan_row]).all()
+    assert float(jnp.abs(Lam[16:24]).max()) == 0.0      # the empty tile
+
+
 def test_bmf_precision_fused_n_striping():
     """A tiny SMEM budget forces the wrapper to stripe the N axis into
     several pallas_calls; parity with the oracle must hold across the
@@ -126,15 +173,22 @@ def test_bmf_precision_no_gather_materialization():
     assert peak(lambda *a: BMFK.precision_accum_reference(*a, 2.0)) >= budget
 
 
-def test_tile_occupancy_counts():
-    from repro.data.sparse import tile_occupancy
-    mask = np.zeros((16, 512), np.float32)
-    mask[0, :300] = 1.0      # row tile 0: occupancy 300 -> 2 tiles of 256
-    mask[9, :1] = 1.0        # row tile 1: single slot -> 1 tile
-    nt = np.asarray(tile_occupancy(jnp.asarray(mask), 8, 256))
-    np.testing.assert_array_equal(nt, [2, 1])
-    nt0 = np.asarray(tile_occupancy(jnp.zeros((8, 256)), 8, 256))
-    np.testing.assert_array_equal(nt0, [0])
+def test_row_extent_and_pump_copies():
+    """The pump's extent plane and its copy counter against a hand count:
+    a left-filled row copies its degree, a row with holes its last live
+    slot + 1, an empty row nothing."""
+    from repro.data.sparse import pump_copies, row_extent
+    mask = np.zeros((16, 12), np.float32)
+    mask[0, :5] = 1.0                  # degree 5: slots 0-4
+    mask[1, [0, 3, 9]] = 1.0           # degree 3, holes: slots 0-9
+    mask[2, :1] = 1.0                  # degree 1
+    mask[8, :12] = 1.0                 # a full row; rows 3-7, 9-15 empty
+    ext = np.asarray(row_extent(jnp.asarray(mask)))
+    np.testing.assert_array_equal(ext[:3], [5, 10, 1])
+    assert ext[8] == 12 and ext[3:8].sum() == 0 and ext[9:].sum() == 0
+    assert pump_copies(mask) == 5 + 10 + 1 + 12 == 28
+    assert pump_copies(mask) - int(mask.sum()) == 10 - 3     # the holes
+    assert pump_copies(np.zeros((8, 4), np.float32)) == 0
 
 
 # ---------------------------------------------------------------------------

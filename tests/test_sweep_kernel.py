@@ -78,6 +78,59 @@ def test_fused_vs_ref_bitwise(N, M, D, K, lanes, dtype, monkeypatch):
     np.testing.assert_array_equal(np.asarray(U_pal), np.asarray(U_ref))
 
 
+def _live_slot_case(rng, tm, D, K):
+    """``_case``'s operands on a plane for the row pump's live-slot walk:
+    4 row tiles over 2·tm + 3 slots — a heavy tile (degrees 0, 1, tm−1,
+    tm, tm+1, 2·tm+3), a light one, an empty one, and one with holes (row
+    24 extent 9 for degree 7; row 25 three holes across the first M-tile
+    seam). Factor rows are multiples of 1/4 and ratings integers, so Λ
+    and η are exact in any summation order and the multi-M-tile
+    comparison is bitwise. Factor row D−1 is NaN and only live slot
+    (6, 2·tm+1) gathers it; its NaN, left in the gather scratch, must
+    reach no later tile. Returns the operands and the one NaN row."""
+    deg = np.array([0, 1, tm - 1, tm, tm + 1, 2 * tm + 3, 2 * tm + 3, 5,
+                    1, 0, 2, 3, 0, 1, 1, 2,
+                    0, 0, 0, 0, 0, 0, 0, 0,
+                    9, tm + 2, 3, 0, 0, 1, 0, 0])
+    N, M = len(deg), 2 * tm + 3
+    idx, _, _, pe, pL, z, _ = _case(rng, N, M, D - 1, K)
+    idx = np.asarray(idx).copy()
+    idx[6, 2 * tm + 1] = D - 1
+    mask = (np.arange(M)[None, :] < deg[:, None]).astype(np.float32)
+    mask[24, [2, 5]] = 0.0
+    mask[25, tm - 2:tm + 1] = 0.0
+    val = rng.integers(1, 6, (N, M)).astype(np.float32)
+    other = rng.integers(-4, 5, (D, K)) / 4.0
+    other[D - 1] = np.nan
+    return (jnp.asarray(idx), jnp.asarray(val), jnp.asarray(mask), pe, pL,
+            z, jnp.asarray(other, jnp.float32)), 6
+
+
+@pytest.mark.parametrize("lanes", [None, 128], ids=["host", "lanes128"])
+@pytest.mark.parametrize("dtype", ["fp32", "bf16"])
+def test_fused_vs_ref_live_slots_bitwise(lanes, dtype, monkeypatch):
+    """The pump copies only the slots below each row's extent, and the
+    slots it skips read as zero: interpret-mode Pallas vs the fallback
+    (which gathers every slot), bitwise across three M-tiles, with ragged
+    degrees, an empty row tile, holes, and a heavy tile's scratch left
+    for the light tiles after it."""
+    if lanes is not None:
+        monkeypatch.setattr(SWEEP, "HOST_LANES", lanes)
+    rng = np.random.default_rng(13)
+    (idx, val, mask, pe, pL, z, other), nan_row = _live_slot_case(
+        rng, 128, 37, 10)
+    N = idx.shape[0]
+    kw = dict(dtype=dtype, tau=2.0, tm=128, n_stripe=N)
+    U_pal = SWEEP.fused_sweep(z, idx, val, mask, pe, pL, other,
+                              force="pallas", interpret=True, **kw)
+    U_ref = SWEEP.fused_sweep(z, idx, val, mask, pe, pL, other,
+                              force="ref", **kw)
+    np.testing.assert_array_equal(np.asarray(U_pal), np.asarray(U_ref))
+    finite = np.arange(N) != nan_row
+    assert np.isfinite(np.asarray(U_pal)[finite]).all()
+    assert np.isnan(np.asarray(U_pal)[nan_row]).all()
+
+
 @pytest.mark.parametrize("dtype", ["fp32", "bf16"])
 def test_fused_vs_ref_multi_m_tile(dtype):
     """M=300 pads to two tm=256 tiles: the kernel's scratch-accumulate

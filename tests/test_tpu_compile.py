@@ -7,6 +7,9 @@ ops wrappers produce for the full-size MovieLens blocks: (8, 6656) is an
 item-side stripe of phase a on an 8x8 grid, (24, 2304) a user-side one,
 (8, 13312) the item side of phase a on the 4x4 grid ``chip_smoke.py``
 runs (the widest index plane), plus (256, 256).  K pads to 128 lanes.
+Both gather kernels get the (N,) per-row extent plane beside the index
+plane: their shared row pump runs copy loops whose trip counts it reads
+from SMEM, which interpret mode lowers without Mosaic's checks.
 The sweep kernel also compiles at K=10 on the stripes of the benchmark's
 MovieLens block, where its epilogue reads only the live corner of each
 lane-padded tile.
@@ -24,7 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bmf_precision.kernel import (
-    LANES, TN, precision_accum_fused_padded)
+    LANES, precision_accum_fused_padded)
 from repro.kernels.bmf_sample.kernel import sample_rows_kernel
 from repro.kernels.bmf_sweep.kernel import fused_sweep_padded
 
@@ -66,9 +69,9 @@ def _assert_kernel(compiled):
 @pytest.mark.parametrize("N,M", STRIPES)
 def test_precision_kernel_compiles_for_v5e(one_chip, N, M):
     S = lambda shape, dt: _sds(one_chip, shape, dt)
-    f = jax.jit(lambda ix, nt, vl, mk, ot: precision_accum_fused_padded(
-        ix, nt, vl, mk, ot, 2.0))
-    compiled = f.lower(S((N, M), jnp.int32), S((N // TN,), jnp.int32),
+    f = jax.jit(lambda ix, ex, vl, mk, ot: precision_accum_fused_padded(
+        ix, ex, vl, mk, ot, 2.0))
+    compiled = f.lower(S((N, M), jnp.int32), S((N,), jnp.int32),
                        S((N, M), jnp.float32), S((N, M), jnp.float32),
                        S((D, LANES), jnp.float32)).compile()
     _assert_kernel(compiled)
@@ -87,9 +90,9 @@ SWEEP_CASES = [pytest.param(N, M, D, None, id=f"{N}-{M}") for N, M in STRIPES
 @pytest.mark.parametrize("N,M,D,k", SWEEP_CASES)
 def test_sweep_kernel_compiles_for_v5e(one_chip, N, M, D, k, dtype):
     S = lambda shape, dt: _sds(one_chip, shape, dt)
-    f = jax.jit(lambda ix, nt, vl, mk, pe, pL, z, ot: fused_sweep_padded(
-        ix, nt, vl, mk, pe, pL, z, ot, 2.0, dtype=dtype, k=k))
-    compiled = f.lower(S((N, M), jnp.int32), S((N // TN,), jnp.int32),
+    f = jax.jit(lambda ix, ex, vl, mk, pe, pL, z, ot: fused_sweep_padded(
+        ix, ex, vl, mk, pe, pL, z, ot, 2.0, dtype=dtype, k=k))
+    compiled = f.lower(S((N, M), jnp.int32), S((N,), jnp.int32),
                        S((N, M), jnp.float32), S((N, M), jnp.float32),
                        S((N, LANES), jnp.float32),
                        S((N, LANES, LANES), jnp.float32),
